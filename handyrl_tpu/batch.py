@@ -29,17 +29,12 @@ import pickle
 import random
 from collections import OrderedDict
 
+import ml_dtypes
 import numpy as np
-
-try:
-    import ml_dtypes
-
-    BF16 = np.dtype(ml_dtypes.bfloat16)
-except ImportError:  # pragma: no cover - ml_dtypes ships with jax
-    BF16 = None
 
 from .utils.tree import tree_map, tree_stack, stack_time_player
 
+BF16 = np.dtype(ml_dtypes.bfloat16)
 ILLEGAL = np.float32(1e32)
 
 
@@ -352,7 +347,7 @@ def _encode_obs(obs, transfer_dtype):
     device).  ``uint8`` is opt-in for envs whose observations are
     integer-valued planes (binary boards): it quarters transfer bytes
     and is verified exact here, off the learner's critical path."""
-    if transfer_dtype == "bfloat16" and BF16 is not None:
+    if transfer_dtype == "bfloat16":
         return tree_map(
             lambda a: a.astype(BF16)
             if np.issubdtype(a.dtype, np.floating) else a,

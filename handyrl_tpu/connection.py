@@ -222,8 +222,11 @@ _mp = mp.get_context("spawn")
 
 
 def force_cpu_jax():
-    """Pin this process's JAX to CPU (actor/batcher processes must not
-    touch the learner's TPU).  Call before any jax usage in a child."""
+    """A spawned child must choose the CPU before its first JAX call:
+    the chip belongs to ONE process, the learner, and an actor/batcher
+    child that let JAX pick would fail or hang reaching for it.  The
+    variable covers a jax not yet imported (and this child's own
+    children), the config update one already imported."""
     import os
 
     os.environ["JAX_PLATFORMS"] = "cpu"

@@ -327,7 +327,8 @@ def _match_series_child(agents, critic, env_args, index, in_queue,
 def evaluate_mp(env, agents, critic, env_args, args_patterns, num_process,
                 num_games, seed):
     """Offline evaluation farm: ``num_process`` processes play
-    ``num_games`` per pattern; outcomes land in a ResultTable."""
+    ``num_games`` per pattern; outcomes land in the returned (and
+    printed) ResultTable."""
     from .connection import _mp
 
     in_queue, out_queue = _mp.Queue(), _mp.Queue()
@@ -372,6 +373,7 @@ def evaluate_mp(env, agents, critic, env_args, args_patterns, num_process,
         if outcome is not None:
             table.add(env.players(), agent_ids, pattern, outcome)
     table.report()
+    return table
 
 
 def network_match_acception(n, env_args, num_agents, port):
@@ -457,8 +459,8 @@ def eval_main(args, argv):
         build_agent(opponent, env) or RandomAgent()
         for _ in range(len(env.players()) - 1)
     ]
-    evaluate_mp(env, agents, None, env_args, {"default": {}},
-                num_process, num_games, seed)
+    return evaluate_mp(env, agents, None, env_args, {"default": {}},
+                       num_process, num_games, seed)
 
 
 def eval_server_main(args, argv):

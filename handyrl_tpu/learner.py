@@ -36,11 +36,7 @@ from collections import deque
 
 import jax
 import numpy as np
-
-try:
-    import psutil
-except ImportError:  # pragma: no cover
-    psutil = None
+import psutil
 
 from . import telemetry
 from .analysis.guards import (
@@ -291,8 +287,7 @@ def _stage_batch(batch, sharding, obs_float="bfloat16"):
       * on a single device, the dozen small non-observation leaves are
         packed into ONE (B, C) float32 array and re-sliced by a jitted
         unpack — per-transfer latency (not bandwidth) dominates small
-        copies, especially on tunneled hosts, so 12 round trips become
-        2 (packed + observation).  Exact: every small leaf is float32
+        copies, so 12 round trips become 2 (packed + observation).  Exact: every small leaf is float32
         or a small-integer tensor that round-trips through f32.
     """
     if jax.process_count() > 1:
@@ -489,7 +484,10 @@ class Trainer:
         if self.num_params > 0:
             self.optimizer = make_optimizer(
                 self.default_lr * self.data_cnt_ema)
-            self.params = model.params
+            # a private copy: the update step DONATES its params, and
+            # the learner still serves ``model`` (epoch 0) to workers
+            # that ask for it after the first step
+            self.params = jax.tree.map(jax.numpy.array, model.params)
             self.opt_state = self.optimizer.init(self.params)
             if self.impact:
                 self.target_params = jax.tree.map(np.asarray, self.params)
@@ -1480,7 +1478,7 @@ class ReplayBuffer:
         self._trim()
 
     def _cap(self):
-        mem_percent = psutil.virtual_memory().percent if psutil else 0.0
+        mem_percent = psutil.virtual_memory().percent
         if mem_percent <= 95:
             return self.maximum_episodes
         if not self.warned:
@@ -2961,6 +2959,14 @@ class Learner:
             if self.wal is not None:
                 self.wal.close()  # final fsync of the append tail
             telemetry.flush()  # ship the span-log tail before exit
+        if self.trainer.failure is not None:
+            # the in-run degradation (serve the last model, keep the
+            # epoch cadence) kept the fleet alive; the RUN still
+            # failed, and its exit code must say so — a supervisor or
+            # a shell sees non-zero, never a green run with steps: 0
+            raise RuntimeError(
+                "training ended with a dead trainer thread"
+            ) from self.trainer.failure
 
 
 def _maybe_init_distributed(args):
@@ -2978,11 +2984,13 @@ def _maybe_init_distributed(args):
 
 def _train_local(args):
     """One learner incarnation (the supervised-child entry point —
-    module-level so the spawn context can pickle it)."""
+    module-level so the spawn context can pickle it).  Returns the
+    finished learner: in-process callers read its counters."""
     _maybe_init_distributed(args)
     prepare_env(args["env_args"])
     learner = Learner(args=args)
     learner.run()
+    return learner
 
 
 def _train_remote(args):
@@ -3008,8 +3016,10 @@ def _maybe_supervised(args, target):
 
 
 def train_main(args):
+    """``main.py --train``.  Returns the finished learner, or None
+    when a supervised child ran it."""
     if not _maybe_supervised(args, _train_local):
-        _train_local(args)
+        return _train_local(args)
 
 
 def train_server_main(args):

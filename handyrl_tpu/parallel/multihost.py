@@ -73,10 +73,7 @@ def init_distributed(cfg: Optional[Dict[str, Any]]) -> bool:
     # BEFORE any backend probe — even ``jax.default_backend()`` would
     # initialize the client, and distributed init must come first.
     # The knob only affects the cpu platform, so it is harmless on TPU.
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # pragma: no cover - older jaxlib: best effort
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     kwargs = {}
     for key in ("coordinator_address", "num_processes", "process_id",
                 "local_device_ids"):

@@ -24,11 +24,13 @@ Three pieces:
     live call's arguments (``fn.lower(*args).compile().cost_analysis()``
     — abstract tracing, safe before the donated buffers die) and
     records XLA's own flops/bytes for that program.  The AOT compile is
-    NOT shared with the jit's call cache on all JAX versions, so a
-    harvest can pay one extra XLA compile per program per run; that is
-    a once-per-run startup cost (and dedups under XLA's persistent
-    compilation cache on TPU), switchable off via
-    ``perf.cost_analysis: false``.
+    NOT shared with the jit's call cache, so a harvest pays one extra
+    XLA compile per program per run — unless the persistent
+    compilation cache (utils/compile_cache.py) holds it: the harvest
+    runs first and writes the entry, the call's own compile then reads
+    it back.  Switchable off via ``perf.cost_analysis: false``.  A
+    harvest that fails leaves the perf keys None and says why, once
+    per program, on stderr.
 
   * **The epoch reduction** — :meth:`CostModel.epoch_metrics` turns
     (steps this epoch, seconds inside the device step) into the
@@ -47,6 +49,7 @@ peak table and the ledger math without dragging a jax runtime in.
 """
 
 import queue
+import sys
 import threading
 
 # bf16 peak TFLOP/s and peak HBM GB/s per chip by device kind (public
@@ -140,12 +143,7 @@ def _sig(value, digits=4):
 
 
 def _normalize_cost(analysis):
-    """``cost_analysis()`` returns a dict on some JAX versions and a
-    per-partition list of dicts on others; fold to (flops, bytes)."""
-    if isinstance(analysis, (list, tuple)):
-        analysis = analysis[0] if analysis else {}
-    if not isinstance(analysis, dict):
-        return 0.0, 0.0
+    """Fold ``cost_analysis()``'s dict to (flops, bytes)."""
     flops = float(analysis.get("flops", 0.0) or 0.0)
     hbm_bytes = float(analysis.get("bytes accessed", 0.0) or 0.0)
     return flops, hbm_bytes
@@ -196,6 +194,7 @@ class CostModel:
         self._lock = threading.Lock()
         self._programs = {}        # label -> {flops, bytes, harvests}
         self.harvest_failures = 0
+        self._reported = set()     # labels whose failure was printed
         self._queue = queue.Queue()  # deferred (label, fn, args, kwargs)
         self._worker = None          # lazy daemon drain thread
 
@@ -241,9 +240,8 @@ class CostModel:
                 return
         try:
             s_args, s_kwargs = _abstractify(args, kwargs)
-        except Exception:
-            with self._lock:
-                self.harvest_failures += 1
+        except Exception as exc:
+            self._note_failure(label, exc)
             return
         self._queue.put((label, fn, s_args, s_kwargs))
         with self._lock:
@@ -273,9 +271,8 @@ class CostModel:
             lower = getattr(fn, "lower")
             analysis = lower(*args, **kwargs).compile().cost_analysis()
             flops, hbm_bytes = _normalize_cost(analysis)
-        except Exception:
-            with self._lock:
-                self.harvest_failures += 1
+        except Exception as exc:
+            self._note_failure(label, exc)
             return
         with self._lock:
             prog = self._programs.setdefault(
@@ -286,6 +283,20 @@ class CostModel:
             prog["flops"] = flops
             prog["bytes"] = hbm_bytes
             prog["harvests"] += 1
+
+    def _note_failure(self, label, exc):
+        """Count a failed harvest and say why ONCE per program: the
+        perf keys then read None, and without this line nothing else
+        in the run explains it.  A print, never a raise — the caller
+        is a step site or the service's drain worker."""
+        with self._lock:
+            self.harvest_failures += 1
+            first = label not in self._reported
+            self._reported.add(label)
+        if first:
+            print(f"WARNING: cost harvest failed for {label!r} "
+                  f"({exc!r}); mfu/achieved_tflops for this program "
+                  "will read None", file=sys.stderr)
 
     def program(self, label):
         with self._lock:

@@ -1,5 +1,5 @@
-"""Multichip GSPMD inference dry run (ROADMAP item 2, the
-MULTICHIP_r05 pattern on the INFERENCE plane).
+"""Multichip GSPMD inference dry run (the training-plane dry run's
+pattern, ``__graft_entry__.dryrun_multichip``, on the INFERENCE plane).
 
 Eight fake CPU devices host the dp4 x tp2 (+ fsdp) meshes and the
 batched ``inference_batch`` dispatch runs as one GSPMD program through

@@ -524,7 +524,7 @@ def test_anakin_trainer_death_shuts_the_learner_down(
     """A dead fused loop can never advance the anakin epoch clock, so
     the server must exit loudly instead of spinning forever serving a
     frozen model (the IMPALA path instead degrades via its intake-
-    driven cadence)."""
+    driven cadence) — and ``run()`` must raise, not return."""
     import threading
 
     monkeypatch.chdir(tmp_path)
@@ -559,13 +559,26 @@ def test_anakin_trainer_death_shuts_the_learner_down(
         return real_step(*a, **kw)
 
     learner.trainer._anakin_step = dying_step
-    runner = threading.Thread(target=learner.run, daemon=True)
+    raised = []
+
+    def run():
+        try:
+            learner.run()
+        except RuntimeError as exc:
+            raised.append(exc)
+
+    runner = threading.Thread(target=run, daemon=True)
     runner.start()
     runner.join(timeout=120)
     assert not runner.is_alive(), (
         "learner.run() hung after the fused loop died")
     assert learner.trainer.failure is not None
     assert learner.shutdown_flag
+    # the shutdown is not a clean finish: run() raises after teardown,
+    # chained to the step's own exception, so the process exits non-zero
+    assert len(raised) == 1
+    assert raised[0].__cause__ is learner.trainer.failure
+    assert "injected device failure" in str(raised[0].__cause__)
 
 
 def test_anakin_training_e2e(tmp_path, monkeypatch):

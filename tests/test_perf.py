@@ -186,11 +186,17 @@ def test_costmodel_async_harvest_failure_counts_never_raises():
     assert cm.program("infer") is None
 
 
-def test_costmodel_harvest_failure_counts_never_raises():
+def test_costmodel_harvest_failure_counts_never_raises(capsys):
     cm = CostModel(PerfConfig(), kind="cpu-test")
     cm.on_compile("step", object(), (), {})    # no .lower at all
     assert cm.program("step") is None
     assert cm.harvest_failures == 1
+    # ... and says why, with the exception, once per program
+    cm.on_compile("step", object(), (), {})
+    assert cm.harvest_failures == 2
+    err = capsys.readouterr().err
+    assert err.count("cost harvest failed for 'step'") == 1
+    assert "AttributeError" in err
 
 
 def test_costmodel_harvest_off_by_config():

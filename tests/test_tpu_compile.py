@@ -1,0 +1,337 @@
+"""Ask the TPU's own compiler, with no chip attached.
+
+The compiler for the v5e is installed wherever jax[tpu] is; it compiles
+for a chip that is DESCRIBED (``get_topology_desc``), not attached.
+Nothing runs, so these say nothing about results or times — they say
+whether the main path's programs are accepted at the flagship shapes
+(HungryGeese / GeeseNet 32f x 12, batch 256 x 8 steps, bf16 compute,
+uint8 wire, the ring at the capacity the learner picks under the
+default ``device_replay_mb``), whether they fit the chip's 16 GB, and
+whether the ring's own byte estimate matches what the compiler lays
+out — the estimate sizes the ring, and tile padding is exactly what it
+exists to get right (staging.py docstring).
+
+Also here, on the CPU: a run whose trainer thread died must not end
+green, and the persistent compile cache must stay in one place.
+"""
+
+import json
+import os
+import pathlib
+import subprocess
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
+
+import numpy as np  # noqa: E402
+import pytest  # noqa: E402
+
+REPO = pathlib.Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(REPO))
+
+BATCH = 256
+HBM_BYTES = 16 * 2**30          # one v5e chip
+RING_MB = 4096                  # config.py: device_replay_mb default
+MAX_EPISODES = 20000            # runs/hungry_geese/config.yaml
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four described devices of a v5e 2x2 host."""
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as exc:   # no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e topology here: {exc!r}")
+    # a compile for a described device is written to the persistent
+    # cache but cannot be read back without the chip: the next one
+    # would warn and compile again, so the cache is off around these
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield list(topo.devices)
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def flagship():
+    """The flagship's pieces as the learner builds them, with the ring
+    PLANNED at full capacity and nothing allocated."""
+    import jax
+
+    from __graft_entry__ import _build_model_and_batch
+    from handyrl_tpu.envs.kaggle.hungry_geese import EPISODE_STEPS
+    from handyrl_tpu.ops.losses import LossConfig
+    from handyrl_tpu.ops.update import DEFAULT_LR, make_optimizer
+    from handyrl_tpu.staging import DeviceReplay, _decompress_episode
+
+    model, _, cfg, episodes = _build_model_and_batch(
+        batch_size=1, return_episodes=True)
+    col = _decompress_episode(episodes[0])
+    replay = DeviceReplay(
+        {"turn_based_training": False, "observation": False,
+         "forward_steps": cfg["forward_steps"], "burn_in_steps": 0,
+         "transfer_dtype": "uint8", "compute_dtype": "bfloat16"},
+        MAX_EPISODES, RING_MB << 20, max_steps_hint=EPISODE_STEPS)
+    buffers = replay._plan_buffers(col)
+    optimizer = make_optimizer(
+        DEFAULT_LR * BATCH * cfg["forward_steps"])
+    params = jax.eval_shape(lambda: model.params)
+    return {
+        "model": model, "col": col, "replay": replay,
+        "buffers": buffers, "optimizer": optimizer, "params": params,
+        "opt_state": jax.eval_shape(optimizer.init, params),
+        "loss_cfg": LossConfig.from_config(cfg),
+        "estimate": replay.capacity * replay._per_slot_bytes(col),
+    }
+
+
+def _on(tree, sharding):
+    import jax
+
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=sharding), tree)
+
+
+def _footprint(mem):
+    """Bytes one program holds on its device while it runs."""
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+
+
+def _compile_replay_step(v5e, f):
+    """The device-replay fused step (draw + gather + update)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from handyrl_tpu.staging import make_replay_update_step
+
+    chip = SingleDeviceSharding(v5e[0])
+    step = make_replay_update_step(
+        f["replay"], f["model"], f["loss_cfg"], f["optimizer"],
+        "bfloat16", batch_size=BATCH)
+    compiled = step.lower(
+        *_on((f["params"], f["opt_state"], f["buffers"],
+              jax.ShapeDtypeStruct((3,), jnp.int32)), chip)).compile()
+    mem = compiled.memory_analysis()
+    # the ring is all but ~6 MB (params + Adam moments) of the arguments
+    assert abs(mem.argument_size_in_bytes - f["estimate"]) \
+        <= 0.03 * f["estimate"], (mem.argument_size_in_bytes, f["estimate"])
+    assert _footprint(mem) < HBM_BYTES
+    # the gather reads the ring IN PLACE: temporaries stay a step's
+    # worth.  (Stored at its logical width the observation buffer was
+    # re-laid whole inside every step — temp ~= the ring itself; see
+    # staging._stored_width)
+    assert mem.temp_size_in_bytes < 0.15 * f["estimate"], (
+        mem.temp_size_in_bytes, f["estimate"])
+
+
+def _compile_ring_append(v5e, f):
+    """One full ingest batch (8 episodes) scattered into the ring: the
+    output IS the ring as the compiler lays it out — held against the
+    ring's own estimate, which sized it."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    from handyrl_tpu.staging import _MAX_RUN
+
+    replay = f["replay"]
+    replay._build_jits()
+    append = replay._append_fn
+    replay.buffers = f["buffers"]
+    replay.ep_len = np.zeros(replay.capacity, np.int32)
+    seen = {}
+    # _append_run builds the host-side run; catch what it would upload
+    replay._append_fn = lambda buffers, *run: (seen.update(run=run)
+                                               or buffers)
+    try:
+        replay._append_run([f["col"]] * _MAX_RUN)
+    finally:
+        replay._append_fn = append
+    chip = SingleDeviceSharding(v5e[0])
+    run = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), seen["run"])
+    compiled = append.lower(*_on((f["buffers"],) + run, chip)).compile()
+    mem = compiled.memory_analysis()
+    ring = mem.output_size_in_bytes
+    assert abs(ring - f["estimate"]) <= 0.03 * f["estimate"], (
+        ring, f["estimate"])
+    assert ring <= (RING_MB << 20) * 1.03
+    # donated: the scatter updates the ring in place
+    assert mem.alias_size_in_bytes >= 0.999 * ring
+    assert _footprint(mem) < HBM_BYTES
+
+
+def _compile_service_forward(v5e, f):
+    """The inference service's own jitted forward at its largest batch
+    bucket (pipeline.max_batch rows)."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    from handyrl_tpu.pipeline import InferenceService, PipelineConfig
+
+    cfg = PipelineConfig()
+    service = InferenceService(f["model"], cfg)
+    try:
+        forward = service._ensure_forward(f["model"])
+        obs = f["col"]["obs"][0, 0]          # one seat's planes
+        chip = SingleDeviceSharding(v5e[0])
+        compiled = forward.lower(*_on(
+            (f["params"], jax.ShapeDtypeStruct(
+                (cfg.max_batch,) + obs.shape, obs.dtype)), chip)).compile()
+    finally:
+        service.close()
+    assert _footprint(compiled.memory_analysis()) < HBM_BYTES
+
+
+def _compile_dp4_step(v5e, f):
+    """The dp=4 sharded update step over the four described chips:
+    gradients all-reduce, and each chip is handed a quarter of the
+    batch beside its replica of the state."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from handyrl_tpu.parallel import (
+        MeshSpec, batch_sharding, make_mesh, make_sharded_update_step,
+        replicated)
+
+    mesh = make_mesh(MeshSpec(dp=4), devices=v5e)
+    rows = jax.ShapeDtypeStruct((BATCH,), jnp.int32)
+    batch = jax.eval_shape(
+        f["replay"]._gather_batch, f["buffers"], rows, rows, rows)
+    state = (f["params"], f["opt_state"])
+    step = make_sharded_update_step(
+        f["model"], f["loss_cfg"], f["optimizer"], mesh, f["params"],
+        compute_dtype="bfloat16")
+    compiled = step.lower(
+        *_on(state, replicated(mesh)),
+        _on(batch, batch_sharding(mesh))).compile()
+    assert "all-reduce" in compiled.as_text()
+    # each chip is handed a quarter of every batch leaf's rows ...
+    batch_in = compiled.input_shardings[0][2]
+    for leaf, sharding in zip(jax.tree.leaves(batch),
+                              jax.tree.leaves(batch_in)):
+        assert sharding.shard_shape(leaf.shape)[0] == BATCH // 4, leaf
+    # ... so its arguments weigh well under the whole batch beside its
+    # replica of the state (not a clean quarter: the compiler pads the
+    # smaller shards' tiles)
+    chip = SingleDeviceSharding(v5e[0])
+
+    def arg_bytes(*trees):
+        # (every leaf is read: jit drops an argument nothing uses)
+        return jax.jit(lambda *t: jax.tree.map(lambda a: a.ravel()[0], t)
+                       ).lower(*_on(trees, chip)).compile(
+            ).memory_analysis().argument_size_in_bytes
+
+    state_bytes = arg_bytes(*state)
+    batch_bytes = arg_bytes(*state, batch) - state_bytes
+    per_chip = compiled.memory_analysis().argument_size_in_bytes
+    assert per_chip - state_bytes <= 0.6 * batch_bytes, (
+        per_chip, state_bytes, batch_bytes)
+    assert _footprint(compiled.memory_analysis()) < HBM_BYTES
+
+
+@pytest.mark.parametrize("program", [
+    _compile_replay_step, _compile_ring_append,
+    _compile_service_forward, _compile_dp4_step,
+], ids=lambda fn: fn.__name__.replace("_compile_", ""))
+def test_main_path_compiles_for_a_described_v5e(program, v5e, flagship):
+    program(v5e, flagship)
+
+
+# -- a dead trainer is a failed run ------------------------------------
+
+TINY_TRAIN = {
+    "turn_based_training": True, "observation": False, "gamma": 0.8,
+    "forward_steps": 4, "burn_in_steps": 0, "compress_steps": 4,
+    "entropy_regularization": 0.1, "entropy_regularization_decay": 0.1,
+    "update_episodes": 15, "batch_size": 4, "minimum_episodes": 10,
+    "maximum_episodes": 200, "epochs": 2, "num_batchers": 1,
+    "eval_rate": 0.1, "worker": {"num_parallel": 1}, "lambda": 0.7,
+    "policy_target": "TD", "value_target": "TD", "seed": 5,
+    "telemetry": False, "metrics_path": "metrics.jsonl",
+}
+
+
+def test_train_exits_nonzero_when_the_trainer_thread_died(
+        tmp_path, monkeypatch, capsys):
+    """``main.py --train`` on the default (IMPALA) path with a fused
+    step that fails as a refused compile or an OOM would: the learner
+    keeps its epoch cadence on the last model, as designed — and then
+    the run RAISES instead of returning, so the process exits non-zero
+    (before, every record read ``steps: 0`` and the exit code was 0)."""
+    import yaml
+
+    import main
+    from handyrl_tpu import staging
+
+    def refused(*args, **kwargs):
+        def step(*a):
+            raise RuntimeError("injected: the compiler refused the step")
+        return step
+
+    monkeypatch.setattr(staging, "make_replay_update_step", refused)
+    monkeypatch.chdir(tmp_path)
+    monkeypatch.setattr(sys, "argv", ["main.py", "--train"])
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                       str(tmp_path / "cache"))
+    (tmp_path / "config.yaml").write_text(yaml.safe_dump({
+        "env_args": {"env": "TicTacToe"}, "train_args": TINY_TRAIN,
+        "worker_args": {"server_address": "", "num_parallel": 1}}))
+
+    with pytest.raises(RuntimeError, match="dead trainer") as err:
+        main.main()
+    assert "compiler refused" in str(err.value.__cause__)
+    records = [json.loads(line) for line in
+               (tmp_path / "metrics.jsonl").read_text().splitlines()]
+    assert [r["steps"] for r in records] == [0, 0]   # the old symptom
+    assert "serving the last model unchanged" in capsys.readouterr().out
+
+
+# -- the compile cache stays put ---------------------------------------
+
+_REPORT = """
+import json, os, subprocess, sys
+from handyrl_tpu.utils.compile_cache import configure_compile_cache
+chosen = configure_compile_cache()
+import jax
+child = subprocess.run(   # a fresh interpreter reads it at import jax
+    [sys.executable, "-c", "import os; "
+     "print(os.environ.get('JAX_COMPILATION_CACHE_DIR'))"],
+    capture_output=True, text=True, check=True).stdout.strip()
+print(json.dumps({"chosen": chosen, "child": child,
+                  "jax": jax.config.jax_compilation_cache_dir}))
+"""
+
+
+def _cache_report(env_dir):
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    out = subprocess.run(
+        [sys.executable, "-c", _REPORT], cwd=REPO, env=env,
+        capture_output=True, text=True, check=True, timeout=120)
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("env_dir", [None, "/somewhere/else/jax_cache"],
+                         ids=["unset", "set"])
+def test_compile_cache_is_one_fixed_place(env_dir):
+    """JAX_COMPILATION_CACHE_DIR set: that directory and no other, in
+    this process and in a fresh interpreter it starts.  Unset: the same
+    in-checkout path from two separate processes — never a temp name —
+    and their children land on it too."""
+    first, second = _cache_report(env_dir), _cache_report(env_dir)
+    expect = env_dir or str(REPO / ".jax_cache")
+    for report in (first, second):
+        assert report == {"chosen": expect, "child": expect,
+                          "jax": expect}
