@@ -61,6 +61,8 @@ from .durability import (
 from .environment import make_env, prepare_env
 from .models import TPUModel, snapshot_params
 from .resilience import FleetRegistry
+from .parallel.mesh import OrderedLaunch
+from .utils.compile_cache import metadata_in_key
 from .utils.profiling import SectionTimers, TraceWindow
 from .ops.losses import LossConfig
 from .ops.update import (
@@ -431,7 +433,10 @@ class Trainer:
         self._replicate_jit = None
         self.prefetcher = None
         self.timers = SectionTimers()
-        self.trace = TraceWindow(self.args.get("profile_dir") or "")
+        self.trace = TraceWindow(self.args.get("profile_dir") or "",
+                                 hlo_text=self._step_hlo_text)
+        self._run_thread = None       # the thread inside run(), if any
+        self._step_profile = None     # step_profile()'s cached answer
         # compile accounting for the hot-path programs: the update step
         # must compile once per run (per mesh shape); anything more is
         # shape churn.  max_update_compiles > 0 turns the count into a
@@ -491,10 +496,8 @@ class Trainer:
             self.opt_state = self.optimizer.init(self.params)
             if self.impact:
                 self.target_params = jax.tree.map(np.asarray, self.params)
-            self.update_step = self.retrace_guard.wrap(
-                self._wrap_sharding(self._wrap_numerics(
-                    self._build_update_step())),
-                label="update_step")
+            self.update_step = self._guarded(
+                self._build_update_step(), "update_step")
             self._maybe_restore_train_state()
             if self.multihost:
                 self._sync_initial_state()
@@ -519,22 +522,12 @@ class Trainer:
                               else self._maybe_device_replay())
         self._replay_step = None
         if self.device_replay is not None and not self.multihost:
-            from .staging import make_replay_update_step
-
             # ONE jitted program per step: draw + gather + loss + grad
             # + Adam — the host passes three scalars (multi-host
             # instead assembles global batches from the local rings
             # and runs the global update_step)
-            self._replay_step = self.retrace_guard.wrap(
-                self._wrap_sharding(self._wrap_numerics(
-                    make_replay_update_step(
-                    self.device_replay, self.model, self.loss_cfg,
-                    self.optimizer, self.compute_dtype,
-                    batch_size=self.args["batch_size"],
-                    mesh=self.train_mesh, params=self.params,
-                    fsdp=self.train_fsdp,
-                    seed=self.args.get("seed", 0)))),
-                label="replay_step")
+            self._replay_step = self._guarded(
+                self._make_replay_step(), "replay_step")
             self._step_label = "replay_step"
         # the host batcher farm exists only when the device-resident
         # path is off: skipping it frees host cores for actors
@@ -597,10 +590,8 @@ class Trainer:
             print(f"WARNING: anakin unavailable ({exc}); falling "
                   "back to the IMPALA worker path")
             return
-        self._anakin_step = self.retrace_guard.wrap(
-            self._wrap_sharding(self._wrap_numerics(
-                self.anakin.make_fused_step())),
-            label="anakin_step")
+        self._anakin_step = self._guarded(
+            self.anakin.make_fused_step(), "anakin_step")
         self._step_label = "anakin_step"
         # the carry folds the resumed step count into its PRNG stream,
         # so a restart continues on fresh data deterministically
@@ -611,15 +602,16 @@ class Trainer:
               + (f", opponent pool {self.anakin.K}"
                  if self.anakin.K else " (pure self-play)"))
 
-    def _wrap_sharding(self, step):
-        if self.shard_guard is None:
-            return step
-        return self.shard_guard.wrap(step)
-
-    def _wrap_numerics(self, step):
-        if self.num_guard is None:
-            return step
-        return self.num_guard.wrap(step)
+    def _guarded(self, step, label):
+        """A jitted step as the hot loops call it: its launches ordered
+        with the inference service's over the training mesh, inside the
+        numerics, sharding and retrace guards."""
+        step = OrderedLaunch(step, self.train_mesh)
+        if self.num_guard is not None:
+            step = self.num_guard.wrap(step)
+        if self.shard_guard is not None:
+            step = self.shard_guard.wrap(step)
+        return self.retrace_guard.wrap(step, label=label)
 
     def _maybe_device_replay(self):
         """Build the HBM-resident replay (staging.DeviceReplay) when
@@ -1020,9 +1012,11 @@ class Trainer:
             if self.shutdown_flag:
                 return None
             self._maybe_emergency_save()
-            with self.timers.section("ingest"):
+            with self.timers.section("ingest", span=False):
                 # drain arrivals even when idling at the cap, so the
-                # pending queue can't overflow and shed episodes
+                # pending queue can't overflow and shed episodes; the
+                # seconds of every call feed profile_ingest_sec, the
+                # span is ingest's own (none for an empty call)
                 replay.ingest(max_episodes=8)
             # ring growth re-lays the buffers (new shapes): those
             # recompiles are designed, so they widen the retrace
@@ -1038,21 +1032,94 @@ class Trainer:
                 # draw state lives on device and rides the jit
                 state = replay.device_state(self.steps)
             with self.timers.section("update"):
-                if self.target_params is not None:
-                    (self.params, self.opt_state, metrics, state,
-                     self.target_params) = self._replay_step(
-                        self.params, self.opt_state, replay.buffers,
-                        state, self.target_params)
-                else:
-                    (self.params, self.opt_state,
-                     metrics, state) = self._replay_step(
-                        self.params, self.opt_state, replay.buffers,
-                        state)
+                metrics, state = self._fused_step(state)
             self.trace.tick()
-            self.steps += 1
             metric_acc.append(metrics)
             batch_cnt += 1
         return batch_cnt, metric_acc
+
+    def _fused_step(self, state):
+        """Dispatch ONE fused replay step on the live params and ring;
+        returns ``(metrics, state)`` with the draw state advanced."""
+        replay = self.device_replay
+        # the step's scopes are read back from its compiled text: its
+        # cache entry is keyed with them (a compile, if this call is one)
+        with metadata_in_key():
+            if self.target_params is not None:
+                (self.params, self.opt_state, metrics, state,
+                 self.target_params) = self._replay_step(
+                    self.params, self.opt_state, replay.buffers,
+                    state, self.target_params)
+            else:
+                (self.params, self.opt_state,
+                 metrics, state) = self._replay_step(
+                    self.params, self.opt_state, replay.buffers, state)
+        self.steps += 1
+        return metrics, state
+
+    def _make_replay_step(self):
+        from .staging import make_replay_update_step
+
+        return make_replay_update_step(
+            self.device_replay, self.model, self.loss_cfg,
+            self.optimizer, self.compute_dtype,
+            batch_size=self.args["batch_size"],
+            mesh=self.train_mesh, params=self.params,
+            fsdp=self.train_fsdp, seed=self.args.get("seed", 0))
+
+    def _step_hlo_text(self):
+        """HLO text of the compiled step program, from the cost model's
+        harvest: where a device trace's op events find their
+        ``jax.named_scope`` (telemetry/devtrace.py)."""
+        return self.costmodel.hlo_text(self._step_label)
+
+    def step_profile(self, steps=16):
+        """The fused replay step's device time by phase: ``steps`` steps
+        on the live params and ring under a private profiler session,
+        reduced by ``telemetry.devtrace.step_phases`` to ``{steps,
+        step_ms, phases: {gather, forward, targets, backward, optimizer,
+        unscoped}}`` (ms per step); the trace is deleted, the answer
+        cached.  Only once the trainer thread has ended, or from it: the
+        step donates the state that thread owns.  Never raises: a
+        failure (no fused step, no TPU plane in the trace, a profiler
+        session already open) prints one line and returns None."""
+        if self._step_profile is None:
+            try:
+                self._step_profile = self._capture_step_profile(steps)
+            except Exception as exc:
+                print(f"step profile not taken ({exc!r})")
+                self._step_profile = False
+        return self._step_profile or None
+
+    def _capture_step_profile(self, steps):
+        import shutil
+        import tempfile
+
+        from .telemetry import devtrace
+        from .utils.profiling import profiler_options
+
+        replay = self.device_replay
+        if (self._replay_step is None or replay is None
+                or replay.buffers is None):
+            raise RuntimeError("no fused replay step on a filled ring")
+        if self._run_thread not in (None, threading.current_thread()):
+            raise RuntimeError("the trainer thread is running")
+        hlo = self._step_hlo_text()
+        state = replay.device_state(self.steps)
+        trace_dir = tempfile.mkdtemp(prefix="hrl-step-profile-")
+        try:
+            jax.profiler.start_trace(
+                trace_dir, profiler_options=profiler_options())
+            try:
+                for _ in range(steps):
+                    _, state = self._fused_step(state)
+                jax.block_until_ready(state)
+            finally:
+                jax.profiler.stop_trace()
+            trace = devtrace.load(devtrace.find_xplane(trace_dir), hlo)
+        finally:
+            shutil.rmtree(trace_dir, ignore_errors=True)
+        return devtrace.step_phases(trace)
 
     def _epoch_loop_anakin(self):
         """Anakin epoch: self-play rollout, batch assembly, and the
@@ -1109,7 +1176,7 @@ class Trainer:
         """One committed step's batch: device replay (local ring ->
         global assembly) or the host prefetcher."""
         if self.device_replay is not None:
-            with self.timers.section("ingest"):
+            with self.timers.section("ingest", span=False):
                 self.device_replay.ingest(max_episodes=8)
             # growth recompiles are designed: widen the retrace budget
             self.retrace_guard.allowance = self.device_replay.growths
@@ -1176,13 +1243,22 @@ class Trainer:
             result = self._epoch_loop_local()
         if result is None:
             return None
-        batch_cnt, metric_acc = result
+        # the epoch boundary: from the loop's return to train()'s, the
+        # stretch in which this thread dispatches no step
+        with telemetry.trace_span("trainer.boundary"):
+            return self._finish_epoch(*result)
 
+    def _finish_epoch(self, batch_cnt, metric_acc):
         # ONE device->host fetch for the whole epoch's metrics: each
         # per-step dict holds device scalars, and float()-ing them one
         # by one would block on a separate transfer per value per step
-        # (jaxlint host-sync)
-        metric_acc = jax.device_get(metric_acc)
+        # (jaxlint host-sync).  It waits for every step still queued.
+        with telemetry.trace_span("boundary.drain"):
+            device_metrics, metric_acc = \
+                metric_acc, jax.device_get(metric_acc)
+            # thousands of device scalars: releasing them is part of
+            # the drain, not of whoever drops the list last
+            del device_metrics[:]
         data_cnt = sum(float(m["dcnt"]) for m in metric_acc)
         loss_sum = {}
         for m in metric_acc:
@@ -1207,15 +1283,17 @@ class Trainer:
         # _to_host is a collective for cross-process-sharded state, so
         # every process computes both copies, not just process 0.
         snapshot = TPUModel(self.model.module)
-        snapshot.params = self._to_host(self.params)
-        host_opt = self._to_host(self.opt_state) if self.multihost \
-            else None
-        # _to_host is a collective for cross-process-sharded leaves, so
-        # the target copy must also be fetched by EVERY process here,
-        # not inside the primary-only save below
-        host_tgt = (self._to_host(self.target_params)
-                    if self.multihost and self.target_params is not None
-                    else None)
+        with telemetry.trace_span("boundary.snapshot"):
+            snapshot.params = self._to_host(self.params)
+            host_opt = self._to_host(self.opt_state) if self.multihost \
+                else None
+            # _to_host is a collective for cross-process-sharded leaves,
+            # so the target copy must also be fetched by EVERY process
+            # here, not inside the primary-only save below
+            host_tgt = (self._to_host(self.target_params)
+                        if self.multihost
+                        and self.target_params is not None
+                        else None)
         self.last_metrics = {k: l / data_cnt for k, l in loss_sum.items()}
         for name, v in prof.items():
             self.last_metrics[f"profile_{name}_sec"] = v["sec"]
@@ -1306,11 +1384,12 @@ class Trainer:
                 self.anakin_pool, self.params)
         self.epoch += 1
         if self.primary:  # process 0 owns the (shared) checkpoint dir
-            try:
-                os.makedirs(_models_dir(), exist_ok=True)
-                self.save_train_state(self.epoch, host_opt, host_tgt)
-            except OSError:
-                pass
+            with telemetry.trace_span("boundary.checkpoint"):
+                try:
+                    os.makedirs(_models_dir(), exist_ok=True)
+                    self.save_train_state(self.epoch, host_opt, host_tgt)
+                except OSError:
+                    pass
         return snapshot
 
     def _queue_depth(self):
@@ -1353,6 +1432,7 @@ class Trainer:
 
     def run(self):
         print("waiting training")
+        self._run_thread = threading.current_thread()
         if self.transfer_guard is not None:
             # armed for the trainer's whole life: transfer counts are
             # reported per epoch from train() via snapshot()
@@ -1404,16 +1484,18 @@ class Trainer:
                 if model is None:
                     break
                 self.update_flag = False
-                while not self.shutdown_flag:
-                    # a SIGTERM can land while the learner thread is
-                    # busy (it will never drain this queue mid-handler)
-                    self._maybe_emergency_save()
-                    try:
-                        self.update_queue.put(
-                            (model, self.steps), timeout=0.3)
-                        break
-                    except queue.Full:
-                        continue
+                with telemetry.trace_span("trainer.handoff"):
+                    while not self.shutdown_flag:
+                        # a SIGTERM can land while the learner thread
+                        # is busy (it will never drain this queue
+                        # mid-handler)
+                        self._maybe_emergency_save()
+                        try:
+                            self.update_queue.put(
+                                (model, self.steps), timeout=0.3)
+                            break
+                        except queue.Full:
+                            continue
         except Exception as exc:
             # record before dying so Learner.update() can't deadlock
             # waiting on a snapshot this thread will never produce
@@ -1432,6 +1514,7 @@ class Trainer:
             if self.transfer_guard is not None:
                 self.transfer_guard.__exit__(None, None, None)
             self.trace.close()  # this thread owns the profiler trace
+            self._run_thread = None
 
 
 class RunningScore:
@@ -1566,7 +1649,10 @@ class Learner:
         # (trainer warmup, worker bring-up) land in this run's log
         telemetry.configure_from_args(
             self.args, role="learner",
-            primary=jax.process_index() == 0)
+            primary=jax.process_index() == 0,
+            # this process has JAX: its live spans also lie on the
+            # profiler's clock, as hrl:<name> (children get none)
+            annotate=jax.profiler.TraceAnnotation)
         # SIGTERM = preemption notice: durable state first (emergency
         # checkpoint + WAL seal inside the grace window), THEN the
         # flight-recorder dump and exit
@@ -2339,6 +2425,13 @@ class Learner:
                 for e, s in self.league_stats.items()}
 
     def update(self):
+        """One epoch boundary on the server thread, which takes no
+        episode in meanwhile: the whole of it is ``learner.update``."""
+        with telemetry.trace_span("learner.update"):
+            self._update()
+        telemetry.flush()              # epoch boundary: spans to disk
+
+    def _update(self):
         print()
         print("epoch %d" % self.model_epoch)
         # NOTE the epoch field is stamped at epoch START (before
@@ -2453,7 +2546,6 @@ class Learner:
             with open(self.metrics_path, "a") as f:
                 f.write(json.dumps(record) + "\n")
         self._last_record = record     # status endpoint reads this
-        telemetry.flush()              # epoch boundary: spans to disk
         self.replay.warned = False
 
     # -- fleet health -----------------------------------------------

@@ -251,14 +251,20 @@ def compute_loss(apply_fn: Callable, params, batch, hidden, cfg: LossConfig,
     surrogate of current/target, and the reported ``clip_frac`` is the
     fraction of acting steps whose surrogate ratio hit the clip."""
     impact = cfg.update_algorithm == "impact" and target_params is not None
-    outputs = forward_prediction(apply_fn, params, hidden, batch, cfg)
+    # the named scopes are HLO metadata only: the update step's phases
+    # on a device trace (telemetry/devtrace.py).  ``loss.targets`` is
+    # the math on detached values, ``loss.terms`` what the gradient
+    # flows through outside the net.
+    with jax.named_scope("net.forward"):
+        outputs = forward_prediction(apply_fn, params, hidden, batch, cfg)
     tgt_outputs = None
     if impact:
         # gradients only flow w.r.t. `params` (grad argnums in the
         # update core), but stop_gradient keeps the trace honest even
         # if a caller differentiates more broadly
-        tgt_outputs = forward_prediction(
-            apply_fn, target_params, hidden, batch, cfg)
+        with jax.named_scope("net.forward"):
+            tgt_outputs = forward_prediction(
+                apply_fn, target_params, hidden, batch, cfg)
         tgt_outputs = {k: lax.stop_gradient(v)
                        for k, v in tgt_outputs.items()}
     if cfg.burn_in_steps > 0:
@@ -277,107 +283,112 @@ def compute_loss(apply_fn: Callable, params, batch, hidden, cfg: LossConfig,
     tmasks = batch["turn_mask"]
     value_target_masks, return_target_masks = omasks, omasks
 
-    log_selected_b = (
-        jnp.log(jnp.clip(batch["selected_prob"], 1e-16, 1.0)) * emasks
-    )
-    log_policy = jax.nn.log_softmax(outputs["policy"], axis=-1)
-    log_selected_t = (
-        jnp.take_along_axis(log_policy, actions, axis=-1) * emasks
-    )
+    with jax.named_scope("loss.targets"):
+        log_selected_b = (
+            jnp.log(jnp.clip(batch["selected_prob"], 1e-16, 1.0)) * emasks
+        )
+    with jax.named_scope("loss.terms"):
+        log_policy = jax.nn.log_softmax(outputs["policy"], axis=-1)
+        log_selected_t = (
+            jnp.take_along_axis(log_policy, actions, axis=-1) * emasks
+        )
     log_selected_g = None
-    if impact:
-        log_policy_g = jax.nn.log_softmax(tgt_outputs["policy"], axis=-1)
-        log_selected_g = (
-            jnp.take_along_axis(log_policy_g, actions, axis=-1) * emasks
+    with jax.named_scope("loss.targets"):
+        if impact:
+            log_policy_g = jax.nn.log_softmax(tgt_outputs["policy"], axis=-1)
+            log_selected_g = (
+                jnp.take_along_axis(log_policy_g, actions, axis=-1) * emasks
+            )
+
+    with jax.named_scope("loss.targets"):
+        # importance-sampling ratios (behavior -> correction policy),
+        # clipped at rho_clip/c_clip.  Standard: the live learner policy.
+        # IMPACT: the target network's policy — stable under staleness,
+        # because the correction target moves on the sync cadence instead
+        # of every optimizer step.
+        if impact:
+            log_rhos = log_selected_g - log_selected_b
+        else:
+            log_rhos = lax.stop_gradient(log_selected_t) - log_selected_b
+        # exp of an unbounded log-ratio overflows to inf on the first
+        # badly-stale batch; +/-20 is far beyond the useful range (the
+        # ratios are clipped to rho_clip/c_clip right below) but keeps
+        # the op finite
+        rhos = jnp.exp(jnp.clip(log_rhos, -20.0, 20.0))
+        clipped_rhos = jnp.clip(rhos, 0.0, cfg.rho_clip)
+        cs = jnp.clip(rhos, 0.0, cfg.c_clip)
+
+        if impact:
+            # IMPACT bootstraps targets from the TARGET network's heads
+            outputs_nograd = dict(tgt_outputs)
+        else:
+            outputs_nograd = {k: lax.stop_gradient(v)
+                              for k, v in outputs.items()}
+
+        if "value" in outputs_nograd:
+            values_nograd = outputs_nograd["value"]
+            if cfg.turn_based_training and values_nograd.shape[2] == 2:
+                # two-player zero-sum: average own value with the negated
+                # opponent view wherever either observed
+                values_opp = -jnp.flip(values_nograd, axis=2)
+                omasks_opp = jnp.flip(omasks, axis=2)
+                values_nograd = (
+                    values_nograd * omasks + values_opp * omasks_opp
+                ) / (omasks + omasks_opp + 1e-8)
+                value_target_masks = jnp.clip(omasks + omasks_opp, 0.0, 1.0)
+            # beyond the terminal step the target is the final outcome
+            outputs_nograd["value"] = (
+                values_nograd * emasks + batch["outcome"] * (1 - emasks)
+            )
+
+        targets, advantages = {}, {}
+        value_args = (
+            outputs_nograd.get("value", None), batch["outcome"], None,
+            cfg.lambda_, 1.0, clipped_rhos, cs, value_target_masks,
+        )
+        return_args = (
+            outputs_nograd.get("return", None), batch["return"], batch["reward"],
+            cfg.lambda_, cfg.gamma, clipped_rhos, cs, return_target_masks,
         )
 
-    # importance-sampling ratios (behavior -> correction policy),
-    # clipped at rho_clip/c_clip.  Standard: the live learner policy.
-    # IMPACT: the target network's policy — stable under staleness,
-    # because the correction target moves on the sync cadence instead
-    # of every optimizer step.
-    if impact:
-        log_rhos = log_selected_g - log_selected_b
-    else:
-        log_rhos = lax.stop_gradient(log_selected_t) - log_selected_b
-    # exp of an unbounded log-ratio overflows to inf on the first
-    # badly-stale batch; +/-20 is far beyond the useful range (the
-    # ratios are clipped to rho_clip/c_clip right below) but keeps
-    # the op finite
-    rhos = jnp.exp(jnp.clip(log_rhos, -20.0, 20.0))
-    clipped_rhos = jnp.clip(rhos, 0.0, cfg.rho_clip)
-    cs = jnp.clip(rhos, 0.0, cfg.c_clip)
-
-    if impact:
-        # IMPACT bootstraps targets from the TARGET network's heads
-        outputs_nograd = dict(tgt_outputs)
-    else:
-        outputs_nograd = {k: lax.stop_gradient(v)
-                          for k, v in outputs.items()}
-
-    if "value" in outputs_nograd:
-        values_nograd = outputs_nograd["value"]
-        if cfg.turn_based_training and values_nograd.shape[2] == 2:
-            # two-player zero-sum: average own value with the negated
-            # opponent view wherever either observed
-            values_opp = -jnp.flip(values_nograd, axis=2)
-            omasks_opp = jnp.flip(omasks, axis=2)
-            values_nograd = (
-                values_nograd * omasks + values_opp * omasks_opp
-            ) / (omasks + omasks_opp + 1e-8)
-            value_target_masks = jnp.clip(omasks + omasks_opp, 0.0, 1.0)
-        # beyond the terminal step the target is the final outcome
-        outputs_nograd["value"] = (
-            values_nograd * emasks + batch["outcome"] * (1 - emasks)
+        targets["value"], advantages["value"] = compute_target(
+            cfg.value_target, *value_args
         )
+        targets["return"], advantages["return"] = compute_target(
+            cfg.value_target, *return_args
+        )
+        if cfg.policy_target != cfg.value_target:
+            _, advantages["value"] = compute_target(cfg.policy_target, *value_args)
+            _, advantages["return"] = compute_target(cfg.policy_target, *return_args)
 
-    targets, advantages = {}, {}
-    value_args = (
-        outputs_nograd.get("value", None), batch["outcome"], None,
-        cfg.lambda_, 1.0, clipped_rhos, cs, value_target_masks,
-    )
-    return_args = (
-        outputs_nograd.get("return", None), batch["return"], batch["reward"],
-        cfg.lambda_, cfg.gamma, clipped_rhos, cs, return_target_masks,
-    )
-
-    targets["value"], advantages["value"] = compute_target(
-        cfg.value_target, *value_args
-    )
-    targets["return"], advantages["return"] = compute_target(
-        cfg.value_target, *return_args
-    )
-    if cfg.policy_target != cfg.value_target:
-        _, advantages["value"] = compute_target(cfg.policy_target, *value_args)
-        _, advantages["return"] = compute_target(cfg.policy_target, *return_args)
-
-    denom = tmasks.sum() + 1e-8
-    if impact:
-        # IMPACT surrogate objective: the V-Trace rho factor is
-        # replaced by the current/target ratio under a two-sided PPO
-        # clip — maximize min(r*A, clip(r, 1-eps, 1+eps)*A)
-        adv = sum(advantages.values())
-        # same finite-exp discipline as the rhos above: the surrogate
-        # clip bounds the USED ratio to 1 +/- eps, so clamping the
-        # exponent changes nothing numerically useful
-        ratio = jnp.exp(jnp.clip(log_selected_t - log_selected_g,
-                                 -20.0, 20.0))
-        eps = cfg.surrogate_clip
-        surrogate = jnp.minimum(
-            ratio * adv, jnp.clip(ratio, 1.0 - eps, 1.0 + eps) * adv)
-        policy_loss = -surrogate
-        clip_frac = (
-            (jnp.abs(ratio - 1.0) > eps) * tmasks).sum() / denom
-        losses, dcnt = compose_losses(
-            outputs, log_selected_t, None, targets, batch, cfg,
-            policy_loss=policy_loss)
-    else:
-        total_advantages = clipped_rhos * sum(advantages.values())
-        # how often the rho clip actually engaged: the off-policy
-        # pressure signal (0 on fresh data; grows with staleness)
-        clip_frac = ((rhos > cfg.rho_clip) * tmasks).sum() / denom
-        losses, dcnt = compose_losses(
-            outputs, log_selected_t, total_advantages, targets, batch,
-            cfg)
+    with jax.named_scope("loss.terms"):
+        denom = tmasks.sum() + 1e-8
+        if impact:
+            # IMPACT surrogate objective: the V-Trace rho factor is
+            # replaced by the current/target ratio under a two-sided PPO
+            # clip — maximize min(r*A, clip(r, 1-eps, 1+eps)*A)
+            adv = sum(advantages.values())
+            # same finite-exp discipline as the rhos above: the surrogate
+            # clip bounds the USED ratio to 1 +/- eps, so clamping the
+            # exponent changes nothing numerically useful
+            ratio = jnp.exp(jnp.clip(log_selected_t - log_selected_g,
+                                     -20.0, 20.0))
+            eps = cfg.surrogate_clip
+            surrogate = jnp.minimum(
+                ratio * adv, jnp.clip(ratio, 1.0 - eps, 1.0 + eps) * adv)
+            policy_loss = -surrogate
+            clip_frac = (
+                (jnp.abs(ratio - 1.0) > eps) * tmasks).sum() / denom
+            losses, dcnt = compose_losses(
+                outputs, log_selected_t, None, targets, batch, cfg,
+                policy_loss=policy_loss)
+        else:
+            total_advantages = clipped_rhos * sum(advantages.values())
+            # how often the rho clip actually engaged: the off-policy
+            # pressure signal (0 on fresh data; grows with staleness)
+            clip_frac = ((rhos > cfg.rho_clip) * tmasks).sum() / denom
+            losses, dcnt = compose_losses(
+                outputs, log_selected_t, total_advantages, targets, batch,
+                cfg)
     losses["clip_frac"] = clip_frac
     return losses, dcnt

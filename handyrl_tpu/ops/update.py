@@ -135,14 +135,21 @@ def make_update_core(model, cfg: LossConfig,
         grads, (losses, dcnt) = jax.grad(loss_fn, has_aux=True)(
             params, batch, hidden, target_params
         )
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        gnorm = optax.global_norm(grads)
-        # in-graph nonfinite flag: 1.0 when the loss or the gradient
-        # global norm went NaN/Inf this step.  It rides the per-step
-        # metrics dict to the ONE per-epoch device_get, where the
-        # learner's NumericsGuard counts it — no extra host syncs
-        finite = jnp.isfinite(losses["total"]) & jnp.isfinite(gnorm)
+        # scopes are HLO metadata only (the step's phases on a device
+        # trace, telemetry/devtrace.py); the forward and the loss carry
+        # theirs in compute_loss, and the backward pass needs none: its
+        # operations read transpose(jvp(<scope>))
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+            gnorm = optax.global_norm(grads)
+            # in-graph nonfinite flag: 1.0 when the loss or the
+            # gradient global norm went NaN/Inf this step.  It rides
+            # the per-step metrics dict to the ONE per-epoch
+            # device_get, where the learner's NumericsGuard counts it
+            # — no extra host syncs
+            finite = jnp.isfinite(losses["total"]) & jnp.isfinite(gnorm)
         metrics = {**losses, "dcnt": dcnt, "grad_norm": gnorm,
                    "nonfinite": 1.0 - finite.astype(jnp.float32)}
         return params, opt_state, metrics
@@ -156,8 +163,9 @@ def make_update_core(model, cfg: LossConfig,
     def update_step(params, opt_state, batch, target_params):
         params, opt_state, metrics = _step(
             params, opt_state, batch, target_params)
-        target_params = refresh_target(params, target_params, opt_state,
-                                       cfg)
+        with jax.named_scope("optimizer"):
+            target_params = refresh_target(params, target_params,
+                                           opt_state, cfg)
         return params, opt_state, metrics, target_params
 
     return update_step

@@ -6,12 +6,67 @@ asks for a sharding.  Design follows the standard JAX recipe: build a
 and let XLA insert the collectives.
 """
 
+import threading
 from dataclasses import dataclass, field
 from typing import Any, Dict, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+# Two threads that each launch a program with collectives over the same
+# devices (the trainer's step and the inference service's GSPMD forward
+# share the training mesh) must not interleave their per-device
+# enqueues: device 0 would run step-then-forward, device 1
+# forward-then-step, and each program's collective would wait for a
+# participant queued behind the other's.  JAX leaves that ordering to
+# its caller.
+_LAUNCH_LOCK = threading.Lock()
+
+
+class OrderedLaunch:
+    """A jitted callable whose launches over ``mesh`` are ordered
+    process-wide; without a mesh it is the callable itself.
+
+    The lock is held only for the enqueue of an executable that is
+    already compiled: a signature not seen before is lowered and
+    compiled ahead of time OUTSIDE it, so a cold compile of the
+    trainer's step (or a ring-growth recompile) never holds up the
+    service's forwards, nor the reverse.  ``key(args)`` is a cheap
+    hint that tells apart the signatures a caller alternates between
+    (the service's batch buckets); the executable's own argument
+    check, which runs before anything is enqueued or donated, decides.
+    """
+
+    def __init__(self, fn, mesh, key=None):
+        self._fn = fn
+        self._ordered = mesh is not None
+        self._key = key
+        self._compiled = {}
+
+    def _compile(self, key, args):
+        compiled = self._compiled[key] = self._fn.lower(*args).compile()
+        return compiled
+
+    def __call__(self, *args):
+        if not self._ordered:
+            return self._fn(*args)
+        if not hasattr(self._fn, "lower"):
+            with _LAUNCH_LOCK:      # no jit: nothing to compile ahead
+                return self._fn(*args)
+        key = self._key(args) if self._key is not None else None
+        compiled = self._compiled.get(key) or self._compile(key, args)
+        try:
+            with _LAUNCH_LOCK:
+                return compiled(*args)
+        except (TypeError, ValueError):
+            # compiled for another signature (a ring growth)
+            compiled = self._compile(key, args)
+        with _LAUNCH_LOCK:
+            return compiled(*args)
+
+    def __getattr__(self, name):
+        return getattr(self._fn, name)
 
 # canonical axis order: data, sequence(time), tensor(model)
 AXES = ("dp", "sp", "tp")

@@ -551,19 +551,25 @@ class InferenceService:
             except Exception:
                 pass
         import jax
+        import numpy as np
 
         def apply(params, obs):
             return module.apply({"params": params}, obs, None)
 
         if self._mesh is not None:
-            from ..parallel.mesh import inference_shardings
+            from ..parallel.mesh import OrderedLaunch, inference_shardings
 
             self._infer_sh = inference_shardings(
                 self._mesh, model.params, fsdp=self._fsdp)
-            fwd = jax.jit(apply,
-                          in_shardings=(self._infer_sh.params,
-                                        self._infer_sh.obs),
-                          out_shardings=self._infer_sh.out)
+            # the trainer's step runs over the same devices: launches
+            # are ordered with it, one executable per batch bucket
+            fwd = OrderedLaunch(
+                jax.jit(apply,
+                        in_shardings=(self._infer_sh.params,
+                                      self._infer_sh.obs),
+                        out_shardings=self._infer_sh.out),
+                self._mesh,
+                key=lambda args: np.shape(jax.tree.leaves(args[1])[0]))
         else:
             self._infer_sh = None
             fwd = jax.jit(apply)

@@ -58,6 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .batch import BF16, ILLEGAL, _build_columnar
+from .telemetry import spans as _telemetry
 from .utils.tree import tree_map
 
 
@@ -91,13 +92,17 @@ def make_replay_update_step(replay, model, loss_cfg, optimizer,
         # draw scalars ON DEVICE and threading the step counter through
         # the jit means a steady-state step uploads NOTHING
         size, oldest, step_idx = state[0], state[1], state[2]
-        slots, tstarts, seats = replay._draw_on_device(
-            buffers, size, oldest, step_idx, base_key, batch_size)
-        batch = replay._gather_batch(buffers, slots, tstarts, seats)
-        if replay._out is not None:
-            batch = jax.tree.map(
-                lambda x: jax.lax.with_sharding_constraint(
-                    x, replay._out), batch)
+        # the scopes are HLO metadata only (the step's phases on a
+        # device trace, telemetry/devtrace.py): they change no operation
+        with jax.named_scope("replay.draw"):
+            slots, tstarts, seats = replay._draw_on_device(
+                buffers, size, oldest, step_idx, base_key, batch_size)
+        with jax.named_scope("replay.gather"):
+            batch = replay._gather_batch(buffers, slots, tstarts, seats)
+            if replay._out is not None:
+                batch = jax.tree.map(
+                    lambda x: jax.lax.with_sharding_constraint(
+                        x, replay._out), batch)
         return batch
 
     if impact:
@@ -239,6 +244,7 @@ class DeviceReplay:
 
         # server thread -> trainer thread handoff
         self.pending = deque()
+        self._offered_at = deque()   # telemetry-clock stamp per pending
         self.pending_cap = 512
         self.dropped = 0
         self._lock = threading.Lock()
@@ -268,10 +274,15 @@ class DeviceReplay:
         """Learner-server-thread side: queue raw episodes for the
         trainer thread.  Bounded: a stalled trainer sheds the OLDEST
         pending episodes rather than growing without limit."""
+        episodes = [e for e in episodes if e is not None]
         with self._lock:
-            self.pending.extend(e for e in episodes if e is not None)
+            self.pending.extend(episodes)
+            # the stamp behind ``ingest.append``'s ``wait_ms``: taken
+            # here, kept in step with ``pending``, shed with it
+            self._offered_at.extend([_telemetry.now()] * len(episodes))
             while len(self.pending) > self.pending_cap:
                 self.pending.popleft()
+                self._offered_at.popleft()
                 self.dropped += 1
 
     def ingest(self, max_episodes=64, batch=_MAX_RUN):
@@ -282,7 +293,14 @@ class DeviceReplay:
         per-dispatch latency, not bandwidth, dominates small uploads —
         and each episode ships only its bucket-rounded length, not a
         full t_max stripe."""
-        batch = min(batch, _MAX_RUN)
+        if not self.pending:
+            return      # an empty call records no span
+        with _telemetry.trace_span("trainer.ingest") as span:
+            episodes = self.episodes_seen
+            self._ingest(max_episodes, min(batch, _MAX_RUN))
+            span.attrs["episodes"] = self.episodes_seen - episodes
+
+    def _ingest(self, max_episodes, batch):
         if self.buffers is None:
             # size T_max from everything already waiting (the warmup
             # backlog usually contains a near-maximal episode, saving
@@ -295,13 +313,17 @@ class DeviceReplay:
                                       for e in self.pending if e)))
         done = 0
         while done < max_episodes:
-            cols = []
+            raw, stamps = [], []
             with self._lock:
-                while self.pending and len(cols) < batch:
-                    cols.append(self.pending.popleft())
-            if not cols:
+                while self.pending and len(raw) < batch:
+                    raw.append(self.pending.popleft())
+                    stamps.append(self._offered_at.popleft())
+            if not raw:
                 return
-            cols = [_decompress_episode(ep) for ep in cols]
+            with _telemetry.trace_span("ingest.decompress"):
+                cols = [_decompress_episode(ep) for ep in raw]
+            for col, stamp in zip(cols, stamps):
+                col["offered_at"] = stamp
             done += len(cols)
             # batched is the ONLY path: size/allocate/grow decisions
             # are taken once over the whole run, then the run lands as
@@ -544,47 +566,58 @@ class DeviceReplay:
         guarantee buffers exist and no episode exceeds t_max; slot
         wrap-around needs no special casing — indices are explicit."""
         k = len(cols)
-        lens = [len(c["turn_idx"]) for c in cols]
-        rows = [_round_up(t) for t in lens]
-        eps = [self._pad_episode(c, r) for c, r in zip(cols, rows)]
-        slots = [(self.write_ptr + i) % self.capacity
-                 for i in range(k)]
-        total = sum(rows)
-        pad = -total % _RUN_ROUND
-        scratch = self.capacity * self.t_max
-        flat_idx = np.concatenate(
-            [s * self.t_max + np.arange(r, dtype=np.int32)
-             for s, r in zip(slots, rows)]
-            + ([scratch + np.arange(pad, dtype=np.int32)]
-               if pad else []))
-        slot_idx = np.asarray(
-            slots + [self.capacity] * (_MAX_RUN - k), np.int32)
+        with _telemetry.trace_span("ingest.pad"):
+            lens = [len(c["turn_idx"]) for c in cols]
+            rows = [_round_up(t) for t in lens]
+            eps = [self._pad_episode(c, r) for c, r in zip(cols, rows)]
+            slots = [(self.write_ptr + i) % self.capacity
+                     for i in range(k)]
+            total = sum(rows)
+            pad = -total % _RUN_ROUND
+            scratch = self.capacity * self.t_max
+            flat_idx = np.concatenate(
+                [s * self.t_max + np.arange(r, dtype=np.int32)
+                 for s, r in zip(slots, rows)]
+                + ([scratch + np.arange(pad, dtype=np.int32)]
+                   if pad else []))
+            slot_idx = np.asarray(
+                slots + [self.capacity] * (_MAX_RUN - k), np.int32)
 
-        def cat_steps(*arrs):
-            out = np.concatenate(arrs)
-            if pad:
-                out = np.concatenate(
-                    [out, np.zeros((pad,) + out.shape[1:], out.dtype)])
-            return out
+            def cat_steps(*arrs):
+                out = np.concatenate(arrs)
+                if pad:
+                    out = np.concatenate(
+                        [out,
+                         np.zeros((pad,) + out.shape[1:], out.dtype)])
+                return out
 
-        def cat_slots(*arrs):
-            out = np.concatenate(arrs)
-            if k < _MAX_RUN:
-                out = np.concatenate([out, np.zeros(
-                    (_MAX_RUN - k,) + out.shape[1:], out.dtype)])
-            return out
+            def cat_slots(*arrs):
+                out = np.concatenate(arrs)
+                if k < _MAX_RUN:
+                    out = np.concatenate([out, np.zeros(
+                        (_MAX_RUN - k,) + out.shape[1:], out.dtype)])
+                return out
 
-        ep = {key: jax.tree.map(
-            cat_slots if key in _PER_SLOT else cat_steps,
-            *[e[key] for e in eps]) for key in eps[0]}
-        self.buffers = self._append_fn(
-            self.buffers, ep, flat_idx, slot_idx)
-        for s, t in zip(slots, lens):
-            self.ep_len[s] = t
-        self.write_ptr = (self.write_ptr + k) % self.capacity
-        self.size = min(self.size + k, self.capacity)
-        self.episodes_seen += k
-        self._state_dirty = True
+            ep = {key: jax.tree.map(
+                cat_slots if key in _PER_SLOT else cat_steps,
+                *[e[key] for e in eps]) for key in eps[0]}
+        with _telemetry.trace_span("ingest.append") as span:
+            # the dispatch and the implicit upload of ``ep``
+            self.buffers = self._append_fn(
+                self.buffers, ep, flat_idx, slot_idx)
+            for s, t in zip(slots, lens):
+                self.ep_len[s] = t
+            self.write_ptr = (self.write_ptr + k) % self.capacity
+            self.size = min(self.size + k, self.capacity)
+            self.episodes_seen += k
+            self._state_dirty = True
+            if _telemetry.enabled():
+                # offer -> drawable, per episode of the run (an episode
+                # that came by another road than ``offer`` has no stamp)
+                now = _telemetry.now()
+                span.attrs["wait_ms"] = [
+                    round(1e3 * (now - c["offered_at"]), 3)
+                    for c in cols if "offered_at" in c]
 
     def _grow(self, new_t_max):
         """A longer episode than ever seen arrived: re-lay the ring

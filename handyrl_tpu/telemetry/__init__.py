@@ -5,6 +5,9 @@ Public surface (see :mod:`.spans` for the design notes):
   * spans: ``trace_span`` / ``record_span`` / ``add_event`` /
     ``span_begin`` / ``span_end``, configured per process via
     ``configure_from_args`` (the same args dict every child receives);
+    live spans are mirrored onto the profiler's clock as ``hrl:<name>``
+    where the process handed ``configure`` its annotation class, and
+    :mod:`.devtrace` reduces a device trace against them;
   * trace context: ``new_trace`` / ``maybe_trace`` / ``current_trace``
     / ``set_trace`` / ``clear_trace`` and the wire envelope
     ``wrap_trace`` / ``unwrap_trace`` (ridden by
@@ -47,6 +50,7 @@ from .spans import (  # noqa: F401
     flush,
     install_signal_dump,
     maybe_trace,
+    mirror,
     new_trace,
     now,
     payload_trace,

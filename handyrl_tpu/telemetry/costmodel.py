@@ -30,7 +30,10 @@ Three pieces:
     runs first and writes the entry, the call's own compile then reads
     it back.  Switchable off via ``perf.cost_analysis: false``.  A
     harvest that fails leaves the perf keys None and says why, once
-    per program, on stderr.
+    per program, on stderr.  The harvest also keeps the compiled
+    program itself, so that :meth:`CostModel.hlo_text` can print its
+    HLO text when asked: the one place a device trace's op events find
+    their ``jax.named_scope`` (:mod:`.devtrace`).
 
   * **The epoch reduction** — :meth:`CostModel.epoch_metrics` turns
     (steps this epoch, seconds inside the device step) into the
@@ -193,6 +196,7 @@ class CostModel:
         self._peaks = None
         self._lock = threading.Lock()
         self._programs = {}        # label -> {flops, bytes, harvests}
+        self._compiled = {}        # label -> latest harvested compile
         self.harvest_failures = 0
         self._reported = set()     # labels whose failure was printed
         self._queue = queue.Queue()  # deferred (label, fn, args, kwargs)
@@ -269,12 +273,13 @@ class CostModel:
     def _harvest(self, label, fn, args, kwargs):
         try:
             lower = getattr(fn, "lower")
-            analysis = lower(*args, **kwargs).compile().cost_analysis()
-            flops, hbm_bytes = _normalize_cost(analysis)
+            compiled = lower(*args, **kwargs).compile()
+            flops, hbm_bytes = _normalize_cost(compiled.cost_analysis())
         except Exception as exc:
             self._note_failure(label, exc)
             return
         with self._lock:
+            self._compiled[label] = compiled
             prog = self._programs.setdefault(
                 label, {"flops": 0.0, "bytes": 0.0, "harvests": 0})
             # keep the LATEST signature's numbers: a replay-ring
@@ -302,6 +307,21 @@ class CostModel:
         with self._lock:
             prog = self._programs.get(label)
             return dict(prog) if prog else None
+
+    def hlo_text(self, label):
+        """The HLO text of the latest harvested compile of ``label``
+        ("" when none, or on a backend that prints none), printed when
+        asked: its ``op_name`` metadata names the scope of every
+        instruction a device trace shows.  An executable loaded from a
+        persistent-cache entry carries the metadata of the build that
+        WROTE the entry (JAX keys the cache without it; see
+        ``utils.compile_cache.metadata_in_key``)."""
+        with self._lock:
+            compiled = self._compiled.get(label)
+        try:
+            return compiled.as_text() if compiled is not None else ""
+        except Exception:
+            return ""
 
     # -- epoch reduction ---------------------------------------------
     def epoch_metrics(self, label, device_sec, steps):

@@ -10,11 +10,23 @@ Three mechanisms, all cheap enough to stay armed in production:
   * **Spans** — ``with trace_span("batch.make"):`` records one
     ``{name, ts, dur, pid, tid, trace, span, parent}`` dict against an
     injectable monotonic clock.  Completed spans land in a per-thread
-    buffer (no lock on the hot path; the flush takes one) and stream to
-    a per-process ``spans-<pid>.jsonl`` in the run directory, which
-    ``scripts/export_trace.py`` renders into a Chrome/Perfetto
-    ``trace.json``.  When telemetry is off every entry point is a
-    constant-time no-op.
+    buffer (no lock on the hot path; the flush takes one) and reach the
+    per-process ``spans-<pid>.jsonl`` in the run directory when
+    :func:`flush` is called — the learner does at every epoch boundary
+    and at exit, every :func:`dump` does — or when one thread's buffer
+    reaches ``_SPAN_BUFFER_CAP`` records: nothing is written between
+    boundaries on a hot thread.  ``scripts/export_trace.py`` renders the
+    logs into a Chrome/Perfetto ``trace.json``.  When telemetry is off
+    every entry point is a constant-time no-op.
+
+  * **The profiler's clock** — a process that has JAX hands its
+    ``jax.profiler.TraceAnnotation`` to ``configure(annotate=)``; every
+    LIVE span (``trace_span``, ``mirror``) then also enters an
+    annotation ``hrl:<name>``, so under any profiler session the span
+    lies on the host plane of the same xplane as the device's
+    ``XLA Ops``.  Spans recorded after the fact (``span_begin`` /
+    ``span_end``, ``record_span``) are not mirrored: an annotation has
+    to be open while the work runs.
 
   * **Trace context** — a compact ``(trace_id, span_id)`` pair rides the
     framed ``(verb, payload)`` control plane inside a backward-
@@ -49,7 +61,8 @@ from collections import deque
 # senders only wrap when a context is actually set.
 TRACE_HEAD = "!tr"
 
-_SPAN_FLUSH_EVERY = 16      # spans buffered per thread before a file write
+_SPAN_BUFFER_CAP = 4096     # spans one thread buffers before it writes itself
+MIRROR_PREFIX = "hrl:"      # the spans' names on the profiler's host plane
 _DEFAULT_RING = 2048        # flight-recorder capacity (flightrec_spans)
 
 
@@ -64,6 +77,7 @@ class _State:
         self.role = ""
         self.primary = True
         self.log_dir = None          # None = no span log file
+        self.annotate = None         # jax.profiler.TraceAnnotation, or None
         self.ring = deque(maxlen=_DEFAULT_RING)
         self.dump_count = 0
         self.dump_path = None
@@ -88,9 +102,13 @@ _tls = threading.local()
 # -- configuration ------------------------------------------------------
 
 def configure(enabled=True, sample_rate=1.0, ring=_DEFAULT_RING,
-              log_dir=None, role="", primary=True, clock=None):
+              log_dir=None, role="", primary=True, clock=None,
+              annotate=None):
     """(Re)arm this process's telemetry.  Resets the ring and buffers —
-    call once at process start (learner init, child entry points)."""
+    call once at process start (learner init, child entry points).
+    ``annotate`` is ``jax.profiler.TraceAnnotation`` in a process that
+    has JAX (this module imports none): live spans are mirrored onto
+    the profiler's clock through it."""
     global _state
     state = _State()
     state.enabled = bool(enabled)
@@ -99,6 +117,7 @@ def configure(enabled=True, sample_rate=1.0, ring=_DEFAULT_RING,
     state.role = role or f"pid-{os.getpid()}"
     state.primary = bool(primary)
     state.ring = deque(maxlen=max(1, int(ring or _DEFAULT_RING)))
+    state.annotate = annotate if enabled else None
     if enabled and log_dir is not None:
         state.log_dir = log_dir
         state.dump_path = os.path.join(
@@ -110,7 +129,7 @@ def configure(enabled=True, sample_rate=1.0, ring=_DEFAULT_RING,
     return state
 
 
-def configure_from_args(args, role="", primary=True):
+def configure_from_args(args, role="", primary=True, annotate=None):
     """Configure from a train-args mapping (the dict the learner ships
     to every worker/gather/batcher child).  The span log lives next to
     ``metrics_path``; with no metrics sink configured, spans stay in
@@ -123,7 +142,7 @@ def configure_from_args(args, role="", primary=True):
         sample_rate=float(args.get("trace_sample_rate", 1.0) or 0.0),
         ring=int(args.get("flightrec_spans", _DEFAULT_RING)
                  or _DEFAULT_RING),
-        log_dir=log_dir, role=role, primary=primary)
+        log_dir=log_dir, role=role, primary=primary, annotate=annotate)
 
 
 def enabled():
@@ -270,7 +289,7 @@ def record_span(name, t0, dur, **attrs):
     if state.log_dir is not None:
         buf = _buffer()
         buf.append(rec)
-        if len(buf) >= _SPAN_FLUSH_EVERY:
+        if len(buf) >= _SPAN_BUFFER_CAP:
             _flush_buffer(buf)
 
 
@@ -280,27 +299,45 @@ def add_event(name, **attrs):
     record_span(name, _state.clock(), 0.0, **attrs)
 
 
+def mirror(name):
+    """An open-able ``hrl:<name>`` annotation on the profiler's clock,
+    or None when telemetry is off or this process was given no
+    annotation class.  For callers that time a live block themselves
+    (``SectionTimers.section``); ``trace_span`` does it for its own."""
+    annotate = _state.annotate
+    return annotate(MIRROR_PREFIX + name) if annotate is not None else None
+
+
 class trace_span:
     """``with trace_span("batch.make"):`` — records one span on exit.
     A plain class, not @contextmanager: when telemetry is off the
-    whole enter/exit costs two attribute reads and no generator."""
+    whole enter/exit costs two attribute reads and no generator.
+    ``attrs`` may be filled in while the block runs (counts known only
+    at its end): they are read on exit."""
 
-    __slots__ = ("name", "attrs", "t0")
+    __slots__ = ("name", "attrs", "t0", "_mirror")
 
     def __init__(self, name, **attrs):
         self.name = name
         self.attrs = attrs
         self.t0 = 0.0
+        self._mirror = None
 
     def __enter__(self):
         if _state.enabled:
+            self._mirror = mirror(self.name)
+            if self._mirror is not None:
+                self._mirror.__enter__()
             self.t0 = _state.clock()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         if _state.enabled:
-            record_span(self.name, self.t0, _state.clock() - self.t0,
-                        **self.attrs)
+            dur = _state.clock() - self.t0
+            if self._mirror is not None:
+                self._mirror.__exit__(exc_type, exc, tb)
+                self._mirror = None
+            record_span(self.name, self.t0, dur, **self.attrs)
         return False
 
 
@@ -369,7 +406,7 @@ def _flush_buffer(buf):
 
 def flush():
     """Drain every thread's buffer to the span log (epoch boundaries,
-    process exit)."""
+    process exit, every flight-recorder dump)."""
     with _state.lock:
         buffers = list(_state.buffers)
     for buf in buffers:
@@ -395,6 +432,7 @@ def dump(reason, path=None):
     path = path or state.dump_path
     if not state.enabled or path is None:
         return None
+    flush()  # what the ring shows, the span log holds too
     with state.lock:
         # hot-path appends don't take the lock, so snapshot the ring
         # defensively: a concurrent append mid-copy must not crash the
@@ -440,14 +478,12 @@ def stall_hook(loop, silent):
     """StallWatchdog ``on_stall`` callback: note the event in the ring,
     then dump — the wedge's causal timeline, not just its stack."""
     add_event("stall", loop=loop, silent_sec=round(silent, 3))
-    flush()
     dump("stall_event")
 
 
 def crash_dump(where, exc):
     """Crash-path dump (the trainer thread's except block)."""
     add_event("crash", where=where, error=repr(exc))
-    flush()
     dump("crash")
 
 
@@ -475,7 +511,6 @@ def install_signal_dump(pre_dump=None):
                 traceback.print_exc()
         if _state.enabled:
             add_event("sigterm")
-            flush()
             dump("sigterm")
         sys.exit(1)
 

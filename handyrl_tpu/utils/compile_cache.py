@@ -14,6 +14,7 @@ its first call; with the cache on, the call's own compile reads that
 entry back instead of compiling the same program a second time.
 """
 
+import contextlib
 import os
 import sys
 
@@ -33,3 +34,28 @@ def configure_compile_cache():
     if jax is not None:   # it read the (then unset) variable at import
         jax.config.update("jax_compilation_cache_dir", _DEFAULT)
     return _DEFAULT
+
+
+def metadata_in_key():
+    """A context, for the calling thread alone, in which a program's
+    cache key also holds its metadata (scopes, source lines).
+
+    JAX keys an entry without them, so a program whose metadata
+    changed and whose operations did not loads the entry, and with it
+    the metadata, of whichever build wrote it first.  The fused step's
+    scopes are READ (telemetry/devtrace.py joins a device trace's ops
+    to them), so the trainer thread compiles that one program in this
+    context: an entry never answers for another build's scopes.  The
+    price is a cold compile of the step for a checkout that moved a
+    line under it.  The switch is JAX's own
+    ``jax_compilation_cache_include_metadata_in_key``, taken in its
+    thread-local form so that no other thread's compile is re-keyed; a
+    JAX that lacks that form keys as it always did, and devtrace says
+    so when a text names no scope."""
+    try:
+        from jax._src.config import (
+            compilation_cache_include_metadata_in_key as state)
+
+        return state(True)
+    except Exception:
+        return contextlib.nullcontext()

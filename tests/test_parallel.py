@@ -444,3 +444,100 @@ def test_tp_actually_partitions_wide_net():
     sharded_after = [l for l in jax.tree.leaves(params)
                      if "tp" in tuple(l.sharding.spec)]
     assert sharded_after, "tp sharding lost through the update step"
+
+
+# -- OrderedLaunch: launches over a mesh ordered, compiles outside ----
+class _FakeJit:
+    """Stands for a jitted callable: records whether the launch lock was
+    held at each lowering/compile and at each launch."""
+
+    def __init__(self):
+        self.compiled_unlocked = []
+        self.launched_locked = []
+        self.plain_calls = 0
+
+    def __call__(self, *args):
+        self.plain_calls += 1
+        return "plain", args
+
+    def lower(self, *args):
+        from handyrl_tpu.parallel.mesh import _LAUNCH_LOCK
+
+        fake, shapes = self, tuple(np.shape(a) for a in args)
+
+        class _Lowered:
+            def compile(self):
+                fake.compiled_unlocked.append(not _LAUNCH_LOCK.locked())
+
+                def executable(*call_args):
+                    if tuple(np.shape(a) for a in call_args) != shapes:
+                        raise TypeError("compiled for other shapes")
+                    fake.launched_locked.append(_LAUNCH_LOCK.locked())
+                    return shapes
+                return executable
+        return _Lowered()
+
+
+def test_ordered_launch_without_a_mesh_is_the_callable_itself():
+    from handyrl_tpu.parallel.mesh import OrderedLaunch
+
+    fake = _FakeJit()
+    step = OrderedLaunch(fake, None)
+    assert step(np.zeros(3))[0] == "plain"
+    assert fake.plain_calls == 1 and fake.compiled_unlocked == []
+    assert step.lower is not None        # the jit's own attributes show
+
+
+def test_ordered_launch_compiles_outside_the_lock_and_launches_inside():
+    from handyrl_tpu.parallel.mesh import OrderedLaunch
+
+    fake = _FakeJit()
+    step = OrderedLaunch(fake, mesh=object())
+    for _ in range(3):
+        assert step(np.zeros(3)) == ((3,),)
+    assert fake.compiled_unlocked == [True]          # once, lock free
+    assert fake.launched_locked == [True] * 3
+    # a ring growth: the executable refuses, a new one is compiled
+    # outside the lock and replaces it
+    assert step(np.zeros(5)) == ((5,),)
+    assert step(np.zeros(5)) == ((5,),)
+    assert fake.compiled_unlocked == [True, True]
+    assert fake.plain_calls == 0
+
+
+def test_ordered_launch_keeps_one_executable_per_key():
+    from handyrl_tpu.parallel.mesh import OrderedLaunch
+
+    fake = _FakeJit()
+    step = OrderedLaunch(fake, mesh=object(),
+                         key=lambda args: np.shape(args[0]))
+    for rows in (8, 32, 8, 32, 8):      # the service's batch buckets
+        assert step(np.zeros(rows)) == ((rows,),)
+    assert len(fake.compiled_unlocked) == 2
+
+
+def test_ordered_launch_on_a_real_mesh_donates_and_regrows():
+    _need_devices(4)
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from handyrl_tpu.parallel.mesh import OrderedLaunch
+
+    mesh = make_mesh(MeshSpec.from_config({"dp": 4}))
+    rep = NamedSharding(mesh, P())
+    rows = NamedSharding(mesh, P("dp"))
+
+    def fn(acc, x):
+        return acc + x.sum(), x * 2
+
+    step = OrderedLaunch(
+        jax.jit(fn, in_shardings=(rep, rows), out_shardings=(rep, rows),
+                donate_argnums=(0,)), mesh)
+    acc = jax.device_put(np.float32(0), rep)
+    for n in (8, 8, 16):                 # the third is a new shape
+        acc, doubled = step(acc, np.ones(n, np.float32))
+        assert doubled.sharding.is_equivalent_to(rows, 1)
+    assert float(acc) == 32.0
+    # an argument committed to another layout is refused, as jit does
+    elsewhere = jax.device_put(np.ones(16, np.float32), rep)
+    with pytest.raises(ValueError):
+        step(acc, elsewhere)

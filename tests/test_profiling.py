@@ -13,11 +13,17 @@ from handyrl_tpu.utils.profiling import SectionTimers, TraceWindow
 
 
 class _StubProfiler:
+    class ProfileOptions:
+        python_tracer_level = None
+        enable_hlo_proto = None
+
     def __init__(self):
         self.calls = []
+        self.options = []
 
-    def start_trace(self, trace_dir):
+    def start_trace(self, trace_dir, profiler_options=None):
         self.calls.append(("start", trace_dir))
+        self.options.append(profiler_options)
 
     def stop_trace(self):
         self.calls.append(("stop", None))
@@ -42,6 +48,31 @@ def test_window_starts_and_stops_at_configured_steps(profiler):
     assert len(profiler.calls) == 1
     win.tick()                       # step 5: stop fires, one-shot
     assert profiler.calls[-1] == ("stop", None)
+    assert win.done and not win.active
+
+
+def test_window_traces_without_python_tracer_and_hlo_proto(profiler):
+    """JAX's default Python tracer slows the trainer thread severalfold
+    and the HLO proto slows the DRC step on the device (PERF.md, PR 24):
+    the program's own trace is taken with both off."""
+    win = TraceWindow("/tmp/tw", start_step=1, stop_step=2)
+    win.tick()
+    (options,) = profiler.options
+    assert options.python_tracer_level == 0
+    assert options.enable_hlo_proto is False
+
+
+def test_a_trace_that_cannot_be_reduced_says_so_and_goes_on(
+        profiler, capsys):
+    """The stub wrote no xplane: the reduction prints one line, leaves
+    no step_phases.json, and the window still ends as done."""
+    win = TraceWindow("/tmp/tw-none", start_step=1, stop_step=2,
+                      hlo_text=lambda: "")
+    win.tick()
+    win.tick()
+    out = capsys.readouterr().out
+    assert "profiler trace not reduced" in out
+    assert "step phases =" not in out
     assert win.done and not win.active
 
 
