@@ -35,9 +35,13 @@ intermediate — and, critically, the persistent ring pads to the TPU's
 ``(N, P, 6, 6, 7)``) instead would tile-pad the ring up to ~24x and
 OOM the device (observed on Geister: a 2 GB ring became a 47 GB
 allocation).  Wide buffers are stored with whole 128-lane rows
-(``_stored_width``) so the gather reads the ring in place.  The gather
+(``_stored_width``) and the per-step scalars and masks ride ONE
+int32 channel, the masks packed (``_pack_steps``), so the gather reads
+the ring in place and only the batch's rows of it.  The gather
 reshapes windows back to logical shapes in-jit, where they are
-transient activations XLA lays out freely.
+transient activations XLA lays out freely; in ``seat`` mode it keeps
+the drawn seat's columns of an observation row before that re-lay,
+not after it.
 Per-slot channels (outcome, lengths) are ``(CAP + 1, ...)``; the +1
 and an extra ``_RUN_ROUND``-row stripe past the ring are SCRATCH that
 batched-append padding scatters into and no gather ever reads.
@@ -186,6 +190,57 @@ def _stored_width(width):
     return width if width <= 128 else _round_up(width, 128)
 
 
+def _mask_words(P, A):
+    """32-bit words one ring row's masks pack into: ``omask`` P bits,
+    ``tmask`` P bits, ``amask`` P*A bits, in that order, bit ``k`` of
+    the row at bit ``k % 32`` of word ``k // 32``."""
+    return -(-P * (A + 2) // 32)
+
+
+def _steps_width(P, A):
+    """Columns of the ring's ``steps`` channel (``_pack_steps``)."""
+    return 5 * P + 1 + _mask_words(P, A)
+
+
+def _pack_steps(col):
+    """A columnar episode's per-step scalars -> the ``(T,
+    _steps_width(P, A))`` int32 rows of the ring's ``steps`` channel:
+    ``prob``, ``act``, ``value``, ``reward``, ``return`` (P columns
+    each, the float ones as their bits), the turn index, then the
+    three masks' bits (host side, once per episode; ``_gather_batch``
+    takes the row apart again, bit for bit).
+
+    ONE channel of 32-bit elements, and not one per quantity, because
+    of what the TPU's row gather does with a narrow channel (asked of
+    the compiler and read off the chip's trace; tests/
+    test_tpu_compile.py holds it).  One-byte elements (the masks as
+    bools) it re-lays row-major first: the WHOLE ring, inside every
+    training step.  A channel one element wide it re-lays to one
+    dimension, ring-wide too.  And each channel of a few 32-bit
+    columns it copies whole into fast memory before the gather, in
+    quarters, every step: the step's time grew with the ring's rows.
+    One wider channel is read in place, the batch's rows alone (or,
+    where it fits the fast memory whole, rides the compiler's one
+    prefetch across calls)."""
+    T = len(col["turn_idx"])
+
+    def i32(key):
+        return np.reshape(col[key], (T, -1)).astype(np.int32)
+
+    def f32_bits(key):
+        return np.reshape(col[key], (T, -1)).astype(np.float32).view("<i4")
+
+    bits = np.concatenate(
+        [np.reshape(col[key], (T, -1)) != 0
+         for key in ("omask", "tmask", "amask")], axis=1)
+    bits = np.pad(bits, [(0, 0), (0, -bits.shape[1] % 32)])
+    return np.concatenate(
+        [f32_bits("prob"), i32("act"), f32_bits("value"),
+         f32_bits("reward"), f32_bits("return"), i32("turn_idx"),
+         np.packbits(bits, axis=1, bitorder="little").view("<i4")],
+        axis=1)
+
+
 class DeviceReplay:
     """Ring buffer of episodes in device memory + jitted batch gather.
 
@@ -228,6 +283,7 @@ class DeviceReplay:
         self.t_max = _round_up(max(max_steps_hint, self.t_win))
         self.buffers = None        # device pytree
         self.num_players = None
+        self.num_actions = None
         self._append_fn = None
         self._sample_fn = None
 
@@ -376,13 +432,13 @@ class DeviceReplay:
         Counts what the TPU lays out, not logical bytes.  A persistent
         2-D ``(rows, width)`` buffer rides its ROWS on the 128-lane
         axis, and its width pads to the sublane tile — 1, 2, 4, or a
-        multiple of 8 elements — so a narrow per-step channel (prob,
-        act, value, reward, return, tmask, omask, turn_idx: widths
-        1..P) costs a few bytes a row, not a 128-wide stripe.  Read off
-        a v5e (``Array.format`` + ``memory_stats`` on the chip) and
-        held to the TPU compiler's own ``memory_analysis`` at the
-        flagship geometry by tests/test_tpu_compile.py, because the
-        rule is the compiler's to change.  The module docstring's
+        multiple of 8 elements — so the channel of per-step scalars
+        and masks (``_pack_steps``) costs its few dozen words a row,
+        not a 128-wide stripe each.  Read off a v5e (``Array.format``
+        + ``memory_stats`` on the chip) and held to the TPU compiler's
+        own ``memory_analysis`` at two geometries by
+        tests/test_tpu_compile.py, because the rule is the compiler's
+        to change.  The module docstring's
         trap is the OTHER layout: small trailing dims kept logical
         (``(N, P, 6, 6, 7)``) tile to (8, 128) each."""
         def row(width, itemsize):
@@ -399,12 +455,7 @@ class DeviceReplay:
                     if np.issubdtype(leaf.dtype, np.floating)
                     else leaf.dtype.itemsize)
             obs_bytes += row(width, item)
-        step = (obs_bytes                    # observation tree
-                + row(P, 4) * 3              # prob + value f32, act i32
-                + row(P * A, 1)              # amask bool
-                + row(P, 4) * 2              # reward, return
-                + row(P, 1) * 2              # tmask, omask bool
-                + row(1, 4))                 # turn_idx
+        step = obs_bytes + row(_steps_width(P, A), 4)
         return step * self.t_max + self._slot_const_bytes(P)
 
     @staticmethod
@@ -447,11 +498,7 @@ class DeviceReplay:
         self.obs_shapes = [leaf.shape[1:]
                            for leaf in jax.tree.leaves(col["obs"])]
         self.obs_treedef = jax.tree.structure(col["obs"])
-        self.shapes = {
-            "prob": (P, 1), "act": (P, 1), "amask": (P, A),
-            "value": (P, 1), "reward": (P, 1), "return": (P, 1),
-            "tmask": (P, 1), "omask": (P, 1),
-        }
+        self.num_actions = A
 
         def spec(shape, dtype):
             return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
@@ -467,15 +514,7 @@ class DeviceReplay:
                                  if np.issubdtype(a.dtype, np.floating)
                                  else a.dtype),
                 col["obs"]),
-            "prob": flat2d((P, 1), jnp.float32),
-            "act": flat2d((P, 1), jnp.int32),
-            "amask": flat2d((P, A), jnp.bool_),
-            "value": flat2d((P, 1), jnp.float32),
-            "reward": flat2d((P, 1), jnp.float32),
-            "return": flat2d((P, 1), jnp.float32),
-            "tmask": flat2d((P, 1), jnp.bool_),
-            "omask": flat2d((P, 1), jnp.bool_),
-            "turn_idx": flat2d((), jnp.int32),
+            "steps": flat2d((_steps_width(P, A),), jnp.int32),
             "outcome": spec((self.capacity + 1, P, 1), jnp.float32),
             "ep_len": spec((self.capacity + 1,), jnp.int32),
             "ep_total": spec((self.capacity + 1,), jnp.int32),
@@ -521,13 +560,12 @@ class DeviceReplay:
         T = len(col["turn_idx"])
         pad = rows - T
 
-        def padt(a, value=0):
+        def padt(a):
             a = np.ascontiguousarray(a).reshape(T, -1)  # 2D storage
             lanes = _stored_width(a.shape[1]) - a.shape[1]
             if pad == 0 and lanes == 0:
                 return a
-            return np.pad(a, [(0, pad), (0, lanes)],
-                          constant_values=value)
+            return np.pad(a, [(0, pad), (0, lanes)])
 
         def obs_store(a):
             if not np.issubdtype(a.dtype, np.floating):
@@ -543,15 +581,7 @@ class DeviceReplay:
 
         return {
             "obs": tree_map(lambda a: padt(obs_store(a)), col["obs"]),
-            "prob": padt(col["prob"].astype(np.float32)),
-            "act": padt(col["act"].astype(np.int32)),
-            "amask": padt(col["amask"] != 0, True),
-            "value": padt(col["value"].astype(np.float32)),
-            "reward": padt(col["reward"].astype(np.float32)),
-            "return": padt(col["return"].astype(np.float32)),
-            "tmask": padt(col["tmask"] != 0),
-            "omask": padt(col["omask"] != 0),
-            "turn_idx": padt(col["turn_idx"].astype(np.int32)),
+            "steps": padt(_pack_steps(col)),
             "outcome": col["outcome"][None],  # (1, P, 1): one ring slot
             "ep_len": np.asarray([T], np.int32),
             "ep_total": np.asarray([col["steps"]], np.int32),
@@ -775,19 +805,41 @@ class DeviceReplay:
             shape = m.shape + (1,) * (x.ndim - 2)
             return jnp.where(m.reshape(shape), x, pad_value)
 
-        turn = fetch(buffers["turn_idx"], ())            # (B,T)
+        def fetch_seat(buf, shape):
+            # seat mode keeps ONE seat of an observation: its columns
+            # are chosen on the gathered 2D rows, so only that seat's
+            # share is re-laid to the logical (B, T, 1, ...) shape
+            per = int(np.prod(shape[1:]))
+            rows = buf[flat_idx]
+            seat = seats[:, None, None]
+            sel = rows[..., :per]
+            for p in range(1, shape[0]):
+                sel = jnp.where(seat == p,
+                                rows[..., p * per:(p + 1) * per], sel)
+            return sel.reshape(flat_idx.shape + (1,) + tuple(shape[1:]))
+
+        # the per-step scalars' row, taken apart (_pack_steps)
+        P, A = self.num_players, self.num_actions
+        steps = buffers["steps"][flat_idx]               # (B,T,5P+1+W)
+
+        def column(i, dtype=jnp.float32):
+            x = steps[..., i * P:(i + 1) * P, None]      # (B,T,P,1)
+            return jax.lax.bitcast_convert_type(x, dtype)
+
+        prob, act = column(0), column(1, jnp.int32)
+        value, reward, ret = column(2), column(3), column(4)
+        turn = steps[..., 5 * P]                         # (B,T)
+        bits = (steps[..., 5 * P + 1:, None] >> jnp.arange(32)) & 1
+        bits = bits.reshape(flat_idx.shape + (-1,)) != 0
+        omask = bits[..., :P, None]
+        tmask = bits[..., P:2 * P, None]
+        amask = bits[..., 2 * P:P * (A + 2)].reshape(
+            flat_idx.shape + (P, A))
         obs = jax.tree.unflatten(self.obs_treedef, [
-            fetch(buf, shape) for buf, shape in zip(
+            (fetch_seat if self.mode == "seat" else fetch)(buf, shape)
+            for buf, shape in zip(
                 jax.tree.leaves(buffers["obs"]), self.obs_shapes)
-        ])                                               # (B,T,P,...)
-        prob = fetch(buffers["prob"], self.shapes["prob"])
-        act = fetch(buffers["act"], self.shapes["act"])
-        amask = fetch(buffers["amask"], self.shapes["amask"])
-        value = fetch(buffers["value"], self.shapes["value"])
-        reward = fetch(buffers["reward"], self.shapes["reward"])
-        ret = fetch(buffers["return"], self.shapes["return"])
-        tmask = fetch(buffers["tmask"], self.shapes["tmask"])
-        omask = fetch(buffers["omask"], self.shapes["omask"])
+        ])                              # (B,T,P,...); seat: (B,T,1,...)
         outcome = buffers["outcome"][slots]              # (B,P,1)
 
         def select_players(x, idx):
@@ -817,7 +869,7 @@ class DeviceReplay:
         cdt = jnp.dtype(self.compute_dtype)
 
         def obs_out(a):
-            sel = acting(a)
+            sel = a if self.mode == "seat" else acting(a)
             if (jnp.issubdtype(sel.dtype, jnp.floating)
                     or sel.dtype == jnp.uint8):
                 sel = sel.astype(cdt)

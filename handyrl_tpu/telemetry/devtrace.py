@@ -121,9 +121,10 @@ def load(path, hlo_text=""):
 def phase_of(op_name):
     """The phase an ``op_name`` belongs to, or None when no scope of
     the step claims it.  The compiler's own copy of one of the ring's
-    arrays (it re-lays a whole channel before the gather reads it) is
-    no traced op and bears the ARGUMENT's name, ``buffers['omask']``:
-    only the draw and the gather read the ring, so it is gather."""
+    arrays (it re-lays the per-slot ``outcome`` before the gather
+    reads it) is no traced op and bears the ARGUMENT's name,
+    ``buffers['outcome']``: only the draw and the gather read the
+    ring, so it is gather."""
     if "transpose(" in op_name:
         return "backward"
     if op_name.startswith(RING_ARGUMENT):

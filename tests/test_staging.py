@@ -374,3 +374,93 @@ def test_ingest_batch_larger_than_tiny_ring_stays_coherent():
     for a, b in zip(jax.tree.leaves(ref.buffers),
                     jax.tree.leaves(batched.buffers)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _random_columnar(rng, steps, P, A):
+    """A columnar episode (``batch._build_columnar``'s form) of random
+    masks; the other channels carry the step index so that a row read
+    from the wrong place shows."""
+    from handyrl_tpu.batch import ILLEGAL
+
+    def mask(*shape):
+        return rng.random((steps,) + shape) < 0.5
+
+    t = np.arange(steps, dtype=np.float32)[:, None, None]
+    channel = np.broadcast_to(t, (steps, P, 1)).copy()
+    return {
+        "players": list(range(P)),
+        "obs": np.broadcast_to(t[..., None], (steps, P, 3, 2)).copy(),
+        "prob": channel, "act": channel.astype(np.int64),
+        "amask": np.where(mask(P, A), ILLEGAL, np.float32(0)),
+        "value": channel, "reward": channel, "return": channel,
+        "tmask": mask(P, 1).astype(np.float32),
+        "omask": mask(P, 1).astype(np.float32),
+        "turn_idx": rng.integers(0, P, steps),
+        "outcome": np.zeros((P, 1), np.float32), "steps": steps,
+    }
+
+
+@pytest.mark.parametrize("P,A,turn_based,observation", [
+    (4, 4, False, False),    # seat mode, 24 bits: one word (Geese)
+    (2, 9, True, False),     # turn mode, 22 bits: one word (TicTacToe)
+    (2, 214, True, True),    # all mode, 432 bits: 14 words (Geister)
+    (2, 14, True, True),     # 32 bits: ends exactly on the word
+])
+def test_packed_masks_round_trip(P, A, turn_based, observation):
+    """Random masks (and turn indices) through ``_pad_episode``'s
+    packing into the ring and back through ``_gather_batch`` bit for bit — on a ring that
+    wrapped (short episodes over a long one's stale rows), was re-laid
+    by ``_grow`` and appended to again, over windows that run past an
+    episode's end."""
+    import jax.numpy as jnp
+
+    from handyrl_tpu.batch import ILLEGAL
+    from handyrl_tpu.staging import DeviceReplay, _mask_words
+
+    rng = np.random.default_rng(100 * P + A)
+    cfg = dict(CFG_BASE, turn_based_training=turn_based,
+               observation=observation)
+    replay = DeviceReplay(cfg, capacity=3, max_bytes=1 << 30,
+                          max_steps_hint=40)
+    cols = [_random_columnar(rng, steps, P, A)
+            for steps in (64, 9, 33, 17, 90, 12)]
+    replay._init_buffers(cols[0])
+    assert _mask_words(P, A) == -(-P * (A + 2) // 32)
+    assert replay.buffers["steps"].shape[1] == 5 * P + 1 + _mask_words(P, A)
+    replay._append_run(cols[:3])
+    replay._append_run(cols[3:4])       # lands on the 64-step slot
+    replay._grow(96)
+    replay._append_run(cols[4:])
+    # _grow re-laid the ring oldest first (slots 0, 1, 2 = episodes 1,
+    # 2, 3), so the last run landed on slots 0 and 1
+    live = {2: cols[3], 0: cols[4], 1: cols[5]}          # slot: episode
+    assert [int(n) for n in replay.ep_len] == [90, 12, 17]
+
+    draws = [(s, t0, seat) for s, c in live.items()
+             for t0 in (0, max(0, c["steps"] - FWD), c["steps"] - 1)
+             for seat in range(P)]
+    batch = replay._sample_fn(
+        replay.buffers, *(jnp.asarray(column, jnp.int32)
+                          for column in zip(*draws)))
+
+    for row, (s, t0, seat) in enumerate(draws):
+        col = live[s]
+        for t in range(FWD):
+            g = t0 + t
+            valid = g < col["steps"]
+            players = [seat] if replay.mode == "seat" else range(P)
+            acting = {"seat": [seat], "all": range(P),
+                      "turn": [col["turn_idx"][g]] if valid else [0],
+                      }[replay.mode]
+            for key, src, seats in (("turn_mask", "tmask", players),
+                                    ("observation_mask", "omask", players)):
+                want = [float(col[src][g, p, 0]) if valid else 0.0
+                        for p in seats]
+                np.testing.assert_array_equal(
+                    np.asarray(batch[key][row, t, :, 0]), want,
+                    err_msg=f"{key} draw {s, t0, seat} step {t}")
+            want = [col["amask"][g, p] if valid
+                    else np.full(A, ILLEGAL) for p in acting]
+            np.testing.assert_array_equal(
+                np.asarray(batch["action_mask"][row, t]), want,
+                err_msg=f"action_mask draw {s, t0, seat} step {t}")

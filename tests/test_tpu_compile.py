@@ -6,10 +6,12 @@ Nothing runs, so these say nothing about results or times — they say
 whether the main path's programs are accepted at the flagship shapes
 (HungryGeese / GeeseNet 32f x 12, batch 256 x 8 steps, bf16 compute,
 uint8 wire, the ring at the capacity the learner picks under the
-default ``device_replay_mb``), whether they fit the chip's 16 GB, and
-whether the ring's own byte estimate matches what the compiler lays
-out — the estimate sizes the ring, and tile padding is exactly what it
-exists to get right (staging.py docstring).
+default ``device_replay_mb``; the fused step at Geister's recurrent
+geometry too), whether they fit the chip's 16 GB, whether the ring's
+own byte estimate matches what the compiler lays out — the estimate
+sizes the ring, and tile padding is exactly what it exists to get
+right (staging.py docstring) — and whether the step's gather reads the
+ring in place.
 
 Also here, on the CPU: a run whose trainer thread died must not end
 green, and the persistent compile cache must stay in one place.
@@ -18,6 +20,7 @@ green, and the persistent compile cache must stay in one place.
 import json
 import os
 import pathlib
+import re
 import subprocess
 import sys
 
@@ -58,37 +61,82 @@ def v5e():
     compilation_cache.reset_cache()
 
 
-@pytest.fixture(scope="module")
-def flagship():
-    """The flagship's pieces as the learner builds them, with the ring
-    PLANNED at full capacity and nothing allocated."""
+def _planned(env_name, train, batch, steps_hint):
+    """One configuration's pieces as the learner builds them, with the
+    ring PLANNED at full capacity and nothing allocated."""
+    import random
+
     import jax
 
-    from __graft_entry__ import _build_model_and_batch
-    from handyrl_tpu.envs.kaggle.hungry_geese import EPISODE_STEPS
+    from handyrl_tpu.environment import make_env
+    from handyrl_tpu.generation import Generator
+    from handyrl_tpu.models import RandomModel, TPUModel
     from handyrl_tpu.ops.losses import LossConfig
     from handyrl_tpu.ops.update import DEFAULT_LR, make_optimizer
     from handyrl_tpu.staging import DeviceReplay, _decompress_episode
 
-    model, _, cfg, episodes = _build_model_and_batch(
-        batch_size=1, return_episodes=True)
-    col = _decompress_episode(episodes[0])
-    replay = DeviceReplay(
-        {"turn_based_training": False, "observation": False,
-         "forward_steps": cfg["forward_steps"], "burn_in_steps": 0,
-         "transfer_dtype": "uint8", "compute_dtype": "bfloat16"},
-        MAX_EPISODES, RING_MB << 20, max_steps_hint=EPISODE_STEPS)
+    cfg = {"gamma": 0.8, "burn_in_steps": 0, "compress_steps": 4,
+           "entropy_regularization": 0.1,
+           "entropy_regularization_decay": 0.1, "lambda": 0.7,
+           "compute_dtype": "bfloat16", **train}
+    random.seed(0)
+    env = make_env({"env": env_name})
+    env.reset()
+    players = env.players()
+    model = TPUModel(env.net())
+    obs0 = env.observation(players[0])
+    model.init_params(obs0, seed=0)
+    rollout = RandomModel(model, obs0)
+    job = {"player": players, "model_id": {p: 1 for p in players}}
+    episode = None
+    while episode is None:
+        episode = Generator(env, cfg).generate(
+            {p: rollout for p in players}, job)
+    col = _decompress_episode(episode)
+    replay = DeviceReplay(cfg, MAX_EPISODES, RING_MB << 20,
+                          max_steps_hint=steps_hint)
     buffers = replay._plan_buffers(col)
     optimizer = make_optimizer(
-        DEFAULT_LR * BATCH * cfg["forward_steps"])
+        DEFAULT_LR * batch * cfg["forward_steps"])
     params = jax.eval_shape(lambda: model.params)
     return {
         "model": model, "col": col, "replay": replay,
         "buffers": buffers, "optimizer": optimizer, "params": params,
         "opt_state": jax.eval_shape(optimizer.init, params),
-        "loss_cfg": LossConfig.from_config(cfg),
+        "loss_cfg": LossConfig.from_config(cfg), "batch": batch,
         "estimate": replay.capacity * replay._per_slot_bytes(col),
     }
+
+
+@pytest.fixture(scope="module")
+def flagship():
+    """``geese32``: HungryGeese / GeeseNet, simultaneous seats (the
+    ring's ``seat`` mode), uint8 wire, batch 256 x 8.  Temporaries
+    read 239 MB of a 4,294 MB estimate; with the masks stored as three
+    one-byte channels they read 370 MB (three whole-ring copies)."""
+    from handyrl_tpu.envs.kaggle.hungry_geese import EPISODE_STEPS
+
+    return dict(_planned(
+        "HungryGeese",
+        {"turn_based_training": False, "observation": False,
+         "forward_steps": 8, "transfer_dtype": "uint8",
+         "policy_target": "UPGO", "value_target": "TD"},
+        BATCH, EPISODE_STEPS), temp_share=0.07)
+
+
+@pytest.fixture(scope="module")
+def geister():
+    """``geister_drc``: Geister / GeisterNet's DRC, turn-based with
+    observation (the ring's ``all`` mode), burn-in 4, 214 actions (the
+    masks pack into 14 words a row), bf16 wire, batch 128 x 12.  Its
+    temporaries are the recurrent net's activations: 889 MB."""
+    return dict(_planned(
+        "Geister",
+        {"turn_based_training": True, "observation": True,
+         "forward_steps": 8, "burn_in_steps": 4,
+         "transfer_dtype": "bfloat16",
+         "policy_target": "TD", "value_target": "TD"},
+        128, 202), temp_share=0.25)
 
 
 def _on(tree, sharding):
@@ -105,18 +153,23 @@ def _footprint(mem):
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
 
 
+_DEFINED = re.compile(
+    r"\s*(?:ROOT )?%?([\w.\-]+) = \w+\[(\d+)[,\]]\S* ([\w\-]+)\(")
+
+
 def _compile_replay_step(v5e, f):
     """The device-replay fused step (draw + gather + update)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from handyrl_tpu.staging import make_replay_update_step
+    from handyrl_tpu.staging import _RUN_ROUND, make_replay_update_step
 
     chip = SingleDeviceSharding(v5e[0])
+    replay = f["replay"]
     step = make_replay_update_step(
-        f["replay"], f["model"], f["loss_cfg"], f["optimizer"],
-        "bfloat16", batch_size=BATCH)
+        replay, f["model"], f["loss_cfg"], f["optimizer"],
+        "bfloat16", batch_size=f["batch"])
     compiled = step.lower(
         *_on((f["params"], f["opt_state"], f["buffers"],
               jax.ShapeDtypeStruct((3,), jnp.int32)), chip)).compile()
@@ -128,9 +181,22 @@ def _compile_replay_step(v5e, f):
     # the gather reads the ring IN PLACE: temporaries stay a step's
     # worth.  (Stored at its logical width the observation buffer was
     # re-laid whole inside every step — temp ~= the ring itself; see
-    # staging._stored_width)
-    assert mem.temp_size_in_bytes < 0.15 * f["estimate"], (
+    # staging._stored_width.  Stored as one-byte elements the narrow
+    # mask channels were: staging._pack_steps)
+    assert mem.temp_size_in_bytes < f["temp_share"] * f["estimate"], (
         mem.temp_size_in_bytes, f["estimate"])
+    # ... and no instruction of the step makes an array as long as the
+    # ring, but for the compiler's one asynchronous prefetch across
+    # calls (copy-start / copy-done) of a channel that fits its fast
+    # memory whole
+    rows = replay.capacity * replay.t_max + _RUN_ROUND
+    ring_long = {}
+    for line in compiled.as_text().splitlines():
+        m = _DEFINED.match(line)
+        if m and int(m.group(2)) == rows and m.group(3) not in (
+                "parameter", "copy-done"):
+            ring_long[m.group(1)] = line.strip()[:160]
+    assert not ring_long, ring_long
 
 
 def _compile_ring_append(v5e, f):
@@ -239,12 +305,15 @@ def _compile_dp4_step(v5e, f):
     assert _footprint(compiled.memory_analysis()) < HBM_BYTES
 
 
-@pytest.mark.parametrize("program", [
-    _compile_replay_step, _compile_ring_append,
-    _compile_service_forward, _compile_dp4_step,
-], ids=lambda fn: fn.__name__.replace("_compile_", ""))
-def test_main_path_compiles_for_a_described_v5e(program, v5e, flagship):
-    program(v5e, flagship)
+@pytest.mark.parametrize("program,geometry", [
+    (_compile_replay_step, "flagship"), (_compile_replay_step, "geister"),
+    (_compile_ring_append, "flagship"),
+    (_compile_service_forward, "flagship"), (_compile_dp4_step, "flagship"),
+], ids=["replay_step", "replay_step_geister", "ring_append",
+        "service_forward", "dp4_step"])
+def test_main_path_compiles_for_a_described_v5e(
+        program, geometry, v5e, request):
+    program(v5e, request.getfixturevalue(geometry))
 
 
 # -- a dead trainer is a failed run ------------------------------------
