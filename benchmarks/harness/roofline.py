@@ -1,6 +1,14 @@
 """What one fused step needs, counted from shapes, and the chip's
 peaks.  Kept with the benchmark so that no later PR can recount.
 
+The count below is the default, for nets of conv and dense layers that
+apply each kernel to every sample.  A configuration whose net it does
+not describe (an embedding table, stacked experts of which a token
+meets a few, attention's score work) names ``cost: <module>`` and
+brings ``benchmarks/cost/<module>.py`` as a file of its own, found by
+that name as a plain reference is (``cost_function``).  ``peaks`` and
+``roofline`` stay the one place that knows the chip.
+
 Operations: 2 * cells * kh * kw * cin * cout per conv application and
 2 * din * dout per dense one (the arithmetic of ``bench.py``'s
 ``model_flops_per_sample``, which is right for a net that applies each
@@ -73,6 +81,19 @@ def step_cost(param_shapes, train_args, geometry, ring_row_bytes):
               + 32 * n_params
               + rows * elements * act_bytes * (2 * trained + burn))
     return {"flops": float(flops), "bytes": float(bytes_)}
+
+
+def cost_function(config):
+    """The configuration's count of one fused step:
+    ``benchmarks/cost/<config["cost"]>.py``'s ``step_cost(param_shapes,
+    train_args, geometry, ring_row_bytes) -> {"flops", "bytes"}`` where
+    the configuration names one, else ``step_cost`` above."""
+    if "cost" not in config:
+        return step_cost
+    import importlib
+
+    return importlib.import_module(
+        "benchmarks.cost." + config["cost"]).step_cost
 
 
 def roofline(cost, device_kind, seconds, chips=1):

@@ -1,7 +1,12 @@
 """Initial weights, made by the benchmark from ``--seed`` on the device
 in one jitted call: the program's module gives only the SHAPES (an
 abstract ``init``); every value is drawn here, so the plain reference
-is handed nothing the program computed."""
+is handed nothing the program computed.
+
+A leaf's scale follows from its name and shape, and from two lists a
+configuration may state: ``head_layers`` (conv layers that emit a
+head's output) and ``stacked_layers`` (layers whose ``kernel`` is a
+stack of independent kernels along its leading axis)."""
 
 import math
 
@@ -16,8 +21,11 @@ def param_shapes(module, example_obs, hidden):
     )["params"]
 
 
-def make_params(shapes, seed, head_layers=()):
-    """kernels ~ N(0, 1/fan_in), but the layers that emit a head's
+def make_params(shapes, seed, head_layers=(), stacked_layers=()):
+    """kernels ~ N(0, 1/fan_in), fan-in being every axis but the last;
+    under a layer the configuration names in ``stacked_layers`` a kernel's
+    leading axis is a stack of independent kernels (experts) and stays
+    out of the fan-in.  But the layers that emit a head's
     output (every dense layer of these nets, and the conv layers the
     configuration names in ``head_layers``) ~ N(0, 0.01/fan_in), so that
     policies start near uniform and values unsaturated, as heads are
@@ -35,7 +43,9 @@ def make_params(shapes, seed, head_layers=()):
                                   jnp.float32)
             name = path[-1].key
             if name == "kernel":
-                z = z / math.sqrt(math.prod(leaf.shape[:-1]))
+                stack = any(getattr(k, "key", None) in stacked_layers
+                            for k in path)
+                z = z / math.sqrt(math.prod(leaf.shape[int(stack):-1]))
                 if len(leaf.shape) == 2 or any(
                         getattr(k, "key", None) in head_layers
                         for k in path):

@@ -12,6 +12,18 @@ What belongs to a cell is data: ``BENCHMARK.json`` names the
 configuration (``benchmarks/configs/``), the traffic mix
 (``benchmarks/traffic/``) and the per-layer readers
 (``benchmarks/layer_metrics/``); no code here reads a cell's name.
+Beside its sizes, its limits and the name of its plain reference
+(``benchmarks/reference/``) a configuration may state who plays its
+corpus (``corpus.policy``: ``harness/corpus.py``), the count of its
+fused step (``cost``: a module of ``benchmarks/cost/``, see
+``harness/roofline.py``) and which layers hold stacked kernels
+(``stacked_layers``: ``harness/weights.py``); absent, each is what the
+harness did before the key existed.
+
+After the window, in this order: the ring's rows and the counts are
+checked, a traced run takes the step's phases, every array on the
+device is freed, and only then the plain reference follows the first
+steps: it has the chip to itself.
 """
 
 import argparse
@@ -211,7 +223,8 @@ def main(argv=None, rehearsal=None):
         model.module, env.observation(env.players()[0]),
         model.init_hidden([1]))
     model.params = weights.make_params(
-        shapes, opts.seed, config.get("head_layers", ()))
+        shapes, opts.seed, config.get("head_layers", ()),
+        config.get("stacked_layers", ()))
     initial_params = jax.device_get(model.params)
     learner = Learner(args=args, net=model)
     trainer, replay = learner.trainer, learner.trainer.device_replay
@@ -384,7 +397,7 @@ def main(argv=None, rehearsal=None):
     run.trace = traced
     run.window_compiles = sum(1 for t in lowered if t_open <= t < t_close)
     run.device = dict(device, memory_peak_bytes=int(peak))
-    run.step_cost = roofline.step_cost(
+    run.step_cost = roofline.cost_function(config)(
         shapes, train, config["roofline"], ring["row_bytes"])
     in_window = [(due, at) for due, at in probes.pairing.landed
                  if t_open <= due < t_close]
@@ -426,7 +439,32 @@ def main(argv=None, rehearsal=None):
          f"{run.window_compiles}")
 
     # -- correct: outside the window and outside setup_s --------------
+    # What needs the program's live state comes first: the ring's rows,
+    # the counts and, in a traced run, the step's phases (the trainer
+    # caches its answer; the readers below read the cache).  Then every
+    # array the process holds on the device is freed, so that the plain
+    # reference has the chip to itself: beside a train state and ring
+    # of gigabytes its own float32 parameters, gradient and Adam would
+    # not fit.  The peak was read at the window's close, before any of
+    # this; ``probes.captured`` and ``initial_params`` are host copies.
     t_check = time.perf_counter()
+    ring_numbers = {
+        "ring_mismatch": _check_ring_rows(
+            config, train, opts.seed, replay, probes, corpus, feeder, ring,
+            t_open, t_close),
+        "unaccounted": float(abs(
+            learner.episodes_received - learner.episodes_rejected_stale
+            - (replay.episodes_seen - len(primed)) - replay.dropped
+            - len(replay.pending)))}
+    if opts.trace:
+        t_profile = time.perf_counter()
+        trainer.step_profile()
+        _say(f"step profile took {time.perf_counter() - t_profile:.2f} s")
+    deleted = _release_device()
+    in_use = max((d.memory_stats() or {}).get("bytes_in_use", 0)
+                 for d in devices)
+    _say(f"device bytes in use before the reference: {in_use} "
+         f"({deleted} arrays deleted)")
     reference = check.reference_follow(
         config, train, primed, ring["capacity"], initial_params,
         steps=len(probes.captured["losses"]))
@@ -434,13 +472,7 @@ def main(argv=None, rehearsal=None):
          f"reference {reference[0]}")
     numbers = check.training_numbers(
         probes.captured, reference, initial_params)
-    numbers["ring_mismatch"] = _check_ring_rows(
-        config, train, opts.seed, replay, probes, corpus, feeder, ring,
-        t_open, t_close)
-    numbers["unaccounted"] = float(abs(
-        learner.episodes_received - learner.episodes_rejected_stale
-        - (replay.episodes_seen - len(primed)) - replay.dropped
-        - len(replay.pending)))
+    numbers.update(ring_numbers)
     correct, lines = check.verdict(numbers, config["check_limits"])
     for line in lines:
         _say(line)
@@ -474,10 +506,31 @@ def main(argv=None, rehearsal=None):
     if traced is not None:
         result["breakdown"] = {"device_ops": traced["device_ops"],
                                "idle_gaps": traced["idle_gaps"]}
+    # every number compared, beside its limit: last in the line, and
+    # the last lines of standard error
+    limits = config["check_limits"]
+    result["check"] = {name: {"value": float(value),
+                              "limit": float(limits[name])}
+                       for name, value in numbers.items()}
     os.chdir(BENCH_DIR)
     shutil.rmtree(run_dir, ignore_errors=True)   # WAL and checkpoints
+    print("\n".join(lines), file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
+
+
+def _release_device():
+    """Delete every array this process still holds on the device (the
+    learner has ended: train state, ring, snapshots, the constants its
+    programs closed over); returns how many.  Nothing of the program is
+    called after this but ``step_profile``'s cached answer and host
+    counters."""
+    import jax
+
+    arrays = jax.live_arrays()
+    for array in arrays:
+        array.delete()
+    return len(arrays)
 
 
 def _check_ring_rows(config, train, seed, replay, probes, corpus, feeder,

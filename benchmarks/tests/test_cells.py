@@ -5,6 +5,7 @@ plain reference as a file of its own."""
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -30,14 +31,45 @@ def test_cell_runs_end_to_end_in_rehearsal(cell):
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
     lines = proc.stdout.strip().splitlines()
     result = json.loads(lines[-1])
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "check"]
+    limits = Cell(load_manifest(), cell).config["check_limits"]
+    assert {k: v["limit"] for k, v in result["check"].items()} == limits
+    assert proc.stderr.strip().splitlines()[-len(result["check"]):] == [
+        l for l in lines if l.startswith("check ") and " limit " in l]
     assert result["correct"] is True, [l for l in lines if "check " in l]
     assert "setup_s" in result["metrics"] and len(result["metrics"]) >= 2
     assert all(v["value"] > 0 for v in result["metrics"].values())
     phases = [l.split()[1] for l in lines if l.startswith("setup_phase ")]
     assert phases == ["import", "backend", "corpus", "build", "prime",
                       "compile", "warm"]
+
+
+@pytest.mark.parametrize("cell", CELLS[:1])
+def test_the_reference_follows_with_the_device_released(cell):
+    """A traced rehearsal: after the window the ring's rows are checked
+    and the step's phases captured (once) while the program's state is
+    alive, then the device is released, and only then the reference
+    follows; the readers find the captured phases in the cache."""
+    proc = subprocess.run(
+        [sys.executable, os.path.join(HERE, "rehearse.py"), cell, "traced"],
+        capture_output=True, text=True, timeout=1500, cwd=ROOT, env=_env())
+    assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
+    lines = proc.stdout.strip().splitlines()
+    marks = [l for l in lines if l.startswith((
+        "order ", "device bytes in use before the reference: ", "check "))]
+    assert marks[:3] == [
+        "order ring_rows", "order step_profile", "order release"]
+    in_use, deleted = re.fullmatch(
+        r"device bytes in use before the reference: (\d+) "
+        r"\((\d+) arrays deleted\)", marks[3]).groups()
+    assert int(in_use) == 0 < int(deleted)    # the CPU reports no bytes
+    assert marks[4].startswith("check losses ")
+    assert [m for m in marks if m.startswith("order ")] == marks[:3]
+    result = json.loads(lines[-1])
+    assert result["correct"] is True
+    assert {"step_device_ms", "device_idle_share"} <= set(result["metrics"])
+    assert list(result)[-2:] == ["breakdown", "check"]
 
 
 @pytest.mark.parametrize("cell", CELLS[:1])
