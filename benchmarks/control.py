@@ -42,9 +42,7 @@ def control_numbers(cell, seed, lowp, capacity=4096):
     shapes = weights.param_shapes(
         model.module, env.observation(env.players()[0]),
         model.init_hidden([1]))
-    initial = jax.device_get(weights.make_params(
-        shapes, seed, config.get("head_layers", ()),
-        config.get("stacked_layers", ())))
+    initial = jax.device_get(weights.config_params(shapes, seed, config))
     t_max = -(-int(config["horizon_steps"]) // staging._GROW_ROUND) \
         * staging._GROW_ROUND
     groups, rest = priming.prime_groups(

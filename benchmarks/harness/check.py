@@ -31,6 +31,8 @@ set from readings of sound runs and of the control on the chip; the
 readings are in PERF.md.
 """
 
+from collections.abc import Iterator
+
 import jax
 import numpy as np
 
@@ -48,29 +50,54 @@ def adam_first_moment(opt_state):
     return found[0].mu
 
 
-def leaf_norms(tree):
-    return np.asarray([float(np.sqrt(np.sum(np.square(
-        np.asarray(x, np.float64))))) for x in jax.tree.leaves(tree)])
+def leaf_norms(tree, minus=None):
+    """The float64 norm of every leaf of ``tree`` (of ``tree - minus``
+    where a second tree is given), walking the leaves in step with ONE
+    leaf in float64 at a time.  No float64 copy of a whole tree is ever
+    made: at hundreds of millions of parameters those were gigabytes of
+    host memory apiece."""
+    others = None if minus is None else jax.tree.leaves(minus)
+    norms = []
+    for leaf in _leaves(tree):
+        other = None if others is None else others[len(norms)]
+        norms.append(_norm(leaf, other))
+        del leaf        # one an iterator made goes before the next is made
+    if others is not None and len(norms) != len(others):
+        raise ValueError(f"{len(norms)} leaves against {len(others)}")
+    return np.asarray(norms)
 
 
-def worst_leaf_gap(program, reference):
+def _norm(leaf, minus):
+    """numpy casts the operands block by block into the one float64
+    result, which is then squared in place."""
+    if minus is None:
+        wide = np.square(leaf, dtype=np.float64)
+    else:
+        wide = np.subtract(leaf, minus, dtype=np.float64)
+        np.square(wide, out=wide)
+    return float(np.sqrt(np.sum(wide)))
+
+
+def _leaves(tree):
+    """A tree's leaves; an iterator that makes its leaves one at a time
+    (``training_numbers``' first gradient) passes as it is."""
+    return tree if isinstance(tree, Iterator) else jax.tree.leaves(tree)
+
+
+def worst_leaf_gap(program, reference, minus=None):
     """Gap between the two trees' per-leaf norms, against the
     reference's norm of that leaf or of the median leaf, whichever is
-    larger (some gradients are all but zero)."""
-    p, r = leaf_norms(program), leaf_norms(reference)
+    larger (some gradients are all but zero).  With ``minus`` the norms
+    are those of each tree's change from it."""
+    p, r = leaf_norms(program, minus), leaf_norms(reference, minus)
     return float(np.max(np.abs(p - r) / np.maximum(r, np.median(r))))
 
 
 def worst_leaf_difference(program, reference):
     """Per-leaf norm of the DIFFERENCE, against the same denominator."""
     r = leaf_norms(reference)
-    return float(np.max(leaf_norms(tree_sub(program, reference))
+    return float(np.max(leaf_norms(program, minus=reference)
                         / np.maximum(r, np.median(r))))
-
-
-def tree_sub(a, b):
-    return jax.tree.map(lambda x, y: np.asarray(x, np.float64)
-                        - np.asarray(y, np.float64), a, b)
 
 
 def training_numbers(captured, reference, initial):
@@ -78,16 +105,19 @@ def training_numbers(captured, reference, initial):
     after step one, params after step three); ``reference``: the plain
     follow's (losses, first gradient, final params)."""
     ref_losses, ref_first, ref_final, ref_scales = reference
-    prog_first = jax.tree.map(lambda m: np.asarray(m) / (1 - ADAM_B1),
-                              captured["mu_after_first"])
+
+    def prog_first():
+        # the gradient Adam was handed, a float32 leaf at a time
+        return (np.asarray(m) / (1 - ADAM_B1)
+                for m in jax.tree.leaves(captured["mu_after_first"]))
+
     return {
         "loss_gap": max(abs(p - r) / scale for p, r, scale in
                         zip(captured["losses"], ref_losses, ref_scales)),
-        "grad_gap": worst_leaf_gap(prog_first, ref_first),
-        "grad_diff": worst_leaf_difference(prog_first, ref_first),
+        "grad_gap": worst_leaf_gap(prog_first(), ref_first),
+        "grad_diff": worst_leaf_difference(prog_first(), ref_first),
         "update_gap": worst_leaf_gap(
-            tree_sub(captured["params_after_third"], initial),
-            tree_sub(ref_final, initial)),
+            captured["params_after_third"], ref_final, minus=initial),
     }
 
 
@@ -104,18 +134,25 @@ def verdict(numbers, limits):
 
 
 def reference_setup(config, train):
-    """The configuration's plain reference, found by the name in its
-    file as the per-layer readers are: ``benchmarks/reference/<name>.py``
-    with ``forward(params, obs, hidden, lowp)``, ``RECURRENT`` and, where
-    that is true, ``init_hidden(batch_shape)``.  A configuration with a
-    new net brings its reference as a file of its own."""
+    """The configuration's plain reference, both halves found by the
+    names in its file as the per-layer readers are (the interface is
+    written once, in ``benchmarks/reference/__init__.py``): the NET,
+    ``benchmarks/reference/<reference>.py``, and the TRAINING SIDE,
+    ``benchmarks/reference/<reference_training>.py`` (absent:
+    ``training``).  A configuration with a new net, or one whose window
+    the default training side cannot follow (a sequence along the time
+    axis, columns without a dense mask, a batch to follow in blocks),
+    brings each as a file of its own."""
     import importlib
 
-    from benchmarks.reference import training
-
+    name = config.get("reference_training", "training")
+    training = importlib.import_module("benchmarks.reference." + name)
     net = importlib.import_module(
         "benchmarks.reference." + config["reference"])
-    if train["turn_based_training"] and not train["observation"]:
+    if (name == "training" and train["turn_based_training"]
+            and not train["observation"]):
+        # the default module's own lack; one brought by name that has
+        # the gather is not refused
         raise NotImplementedError(
             "the plain reference has no turn-player gather yet")
     return training, net, not train["turn_based_training"]

@@ -3,10 +3,12 @@ in one jitted call: the program's module gives only the SHAPES (an
 abstract ``init``); every value is drawn here, so the plain reference
 is handed nothing the program computed.
 
-A leaf's scale follows from its name and shape, and from two lists a
+A leaf's scale follows from its name and shape, and from three lists a
 configuration may state: ``head_layers`` (conv layers that emit a
-head's output) and ``stacked_layers`` (layers whose ``kernel`` is a
-stack of independent kernels along its leading axis)."""
+head's output), ``stacked_layers`` (layers whose ``kernel`` is a
+stack of independent kernels along its leading axis) and
+``trunk_layers`` (layers whose 2-D kernels are projections of the
+trunk, not heads); naming a parent module covers what lies under it."""
 
 import math
 
@@ -21,12 +23,25 @@ def param_shapes(module, example_obs, hidden):
     )["params"]
 
 
-def make_params(shapes, seed, head_layers=(), stacked_layers=()):
+LAYER_LISTS = ("head_layers", "stacked_layers", "trunk_layers")
+
+
+def config_params(shapes, seed, config):
+    """``make_params`` with the lists the configuration states."""
+    return make_params(shapes, seed,
+                       *(config.get(key, ()) for key in LAYER_LISTS))
+
+
+def make_params(shapes, seed, head_layers=(), stacked_layers=(),
+                trunk_layers=()):
     """kernels ~ N(0, 1/fan_in), fan-in being every axis but the last;
     under a layer the configuration names in ``stacked_layers`` a kernel's
     leading axis is a stack of independent kernels (experts) and stays
     out of the fan-in.  But the layers that emit a head's
-    output (every dense layer of these nets, and the conv layers the
+    output (every dense layer, but those under a layer the configuration
+    names in ``trunk_layers``: in a transformer every projection is a
+    2-D kernel, and drawn as heads they would leave the residual stream
+    to the embedding alone; and the conv layers the
     configuration names in ``head_layers``) ~ N(0, 0.01/fan_in), so that
     policies start near uniform and values unsaturated, as heads are
     commonly initialised: with unit-variance heads the softmax
@@ -42,13 +57,13 @@ def make_params(shapes, seed, head_layers=(), stacked_layers=()):
             z = jax.random.normal(jax.random.fold_in(key, i), leaf.shape,
                                   jnp.float32)
             name = path[-1].key
+            under = {getattr(k, "key", None) for k in path}
             if name == "kernel":
-                stack = any(getattr(k, "key", None) in stacked_layers
-                            for k in path)
+                stack = not under.isdisjoint(stacked_layers)
                 z = z / math.sqrt(math.prod(leaf.shape[int(stack):-1]))
-                if len(leaf.shape) == 2 or any(
-                        getattr(k, "key", None) in head_layers
-                        for k in path):
+                head = (len(leaf.shape) == 2
+                        and under.isdisjoint(trunk_layers))
+                if head or not under.isdisjoint(head_layers):
                     z = 0.1 * z
             elif name == "scale":
                 z = 1.0 + 0.1 * z

@@ -12,12 +12,14 @@ What belongs to a cell is data: ``BENCHMARK.json`` names the
 configuration (``benchmarks/configs/``), the traffic mix
 (``benchmarks/traffic/``) and the per-layer readers
 (``benchmarks/layer_metrics/``); no code here reads a cell's name.
-Beside its sizes, its limits and the name of its plain reference
-(``benchmarks/reference/``) a configuration may state who plays its
-corpus (``corpus.policy``: ``harness/corpus.py``), the count of its
-fused step (``cost``: a module of ``benchmarks/cost/``, see
-``harness/roofline.py``) and which layers hold stacked kernels
-(``stacked_layers``: ``harness/weights.py``); absent, each is what the
+Beside its sizes, its limits and the name of its plain reference's net
+(``reference``: ``benchmarks/reference/``) a configuration may state
+the reference's training side (``reference_training``: a module there
+too, see its ``__init__.py``), who plays its corpus (``corpus.policy``:
+``harness/corpus.py``), the count of its fused step (``cost``: a module
+of ``benchmarks/cost/``, see ``harness/roofline.py``) and which layers
+hold stacked kernels or the trunk's projections (``stacked_layers``,
+``trunk_layers``: ``harness/weights.py``); absent, each is what the
 harness did before the key existed.
 
 After the window, in this order: the ring's rows and the counts are
@@ -68,8 +70,9 @@ class Phases:
     """Set-up as named, timed phases.  Each prints
     ``setup_phase <name> <seconds>`` at the start of a line (the program
     prints progress marks without a newline), followed by what JAX
-    compiled or fetched from its persistent cache meanwhile: a phase
-    that swings from run to run names its cause there."""
+    compiled or fetched from its persistent cache meanwhile (a phase
+    that swings from run to run names its cause there) and the most
+    host memory the process has held so far."""
 
     def __init__(self):
         self.last = -_since_process_start()
@@ -95,7 +98,8 @@ class Phases:
             "programs": 0, "seconds": 0.0, "hits": 0}
         _say(f"\nsetup_phase {name} {now - self.last:.3f}  "
              f"(programs built {c['programs']} in {c['seconds']:.2f} s, "
-             f"{c['hits']} from the persistent cache)")
+             f"{c['hits']} from the persistent cache; host peak rss "
+             f"{_host_peak_rss() / 1e9:.2f} GB)")
         self.last = now
 
 
@@ -222,9 +226,7 @@ def main(argv=None, rehearsal=None):
     shapes = weights.param_shapes(
         model.module, env.observation(env.players()[0]),
         model.init_hidden([1]))
-    model.params = weights.make_params(
-        shapes, opts.seed, config.get("head_layers", ()),
-        config.get("stacked_layers", ()))
+    model.params = weights.config_params(shapes, opts.seed, config)
     initial_params = jax.device_get(model.params)
     learner = Learner(args=args, net=model)
     trainer, replay = learner.trainer, learner.trainer.device_replay
@@ -465,6 +467,7 @@ def main(argv=None, rehearsal=None):
                  for d in devices)
     _say(f"device bytes in use before the reference: {in_use} "
          f"({deleted} arrays deleted)")
+    rss_before = _host_peak_rss()
     reference = check.reference_follow(
         config, train, primed, ring["capacity"], initial_params,
         steps=len(probes.captured["losses"]))
@@ -477,6 +480,8 @@ def main(argv=None, rehearsal=None):
     for line in lines:
         _say(line)
     _say(f"check took {time.perf_counter() - t_check:.2f} s")
+    _say(f"host peak rss: {_host_peak_rss()} bytes "
+         f"({rss_before} before the reference)")
     for t, message in compiled_names:
         if t_open <= t < t_close:
             _say(f"compiled in the window: {message}")
@@ -517,6 +522,15 @@ def main(argv=None, rehearsal=None):
     print("\n".join(lines), file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
+
+
+def _host_peak_rss():
+    """The most host memory this process has held, in bytes (Linux
+    counts ``ru_maxrss`` in KiB): what the next configuration's train
+    state and the check's host copies are sized against."""
+    import resource
+
+    return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
 
 
 def _release_device():

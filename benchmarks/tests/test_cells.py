@@ -43,6 +43,11 @@ def test_cell_runs_end_to_end_in_rehearsal(cell):
     phases = [l.split()[1] for l in lines if l.startswith("setup_phase ")]
     assert phases == ["import", "backend", "corpus", "build", "prime",
                       "compile", "warm"]
+    (rss,) = [l for l in lines if l.startswith("host peak rss: ")]
+    peak, before = map(int, re.fullmatch(
+        r"host peak rss: (\d+) bytes \((\d+) before the reference\)",
+        rss).groups())
+    assert peak >= before > 100e6
 
 
 @pytest.mark.parametrize("cell", CELLS[:1])

@@ -1,6 +1,7 @@
 """What a configuration may state in place of harness code: who plays
 its corpus, the count of its fused step, which layers hold stacked
-kernels.  Absent, each is what the harness did before the key existed,
+kernels or the trunk's projections.  Absent, each is what the harness
+did before the key existed,
 so the configurations that are there read as they did."""
 
 import math
@@ -124,9 +125,10 @@ def test_without_the_key_the_count_is_the_conv_dense_one(name):
     assert roofline.cost_function(config) is roofline.step_cost
 
 
-# -- stacked_layers ---------------------------------------------------
-def _make_params_before(shapes, seed, head_layers=()):
-    """``weights.make_params`` as it was before ``stacked_layers``: the
+# -- stacked_layers, trunk_layers -------------------------------------
+def _make_params_before(shapes, seed, head_layers=(), stacked_layers=()):
+    """``weights.make_params`` as it was before ``trunk_layers`` (with
+    no ``stacked_layers`` given, as it was before that key too): the
     reference the default is held to, bit for bit."""
     leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
 
@@ -137,7 +139,9 @@ def _make_params_before(shapes, seed, head_layers=()):
                                   jnp.float32)
             name = path[-1].key
             if name == "kernel":
-                z = z / math.sqrt(math.prod(leaf.shape[:-1]))
+                stack = any(getattr(k, "key", None) in stacked_layers
+                            for k in path)
+                z = z / math.sqrt(math.prod(leaf.shape[int(stack):-1]))
                 if len(leaf.shape) == 2 or any(
                         getattr(k, "key", None) in head_layers
                         for k in path):
@@ -152,18 +156,64 @@ def _make_params_before(shapes, seed, head_layers=()):
     return jax.jit(build)(jax.random.PRNGKey(seed))
 
 
-@pytest.mark.parametrize("name", CONFIGS)
-def test_without_stacked_layers_the_weights_are_what_they_were(name):
-    config = _config(name)
-    assert "stacked_layers" not in config
-    shapes = _module_shapes(config)
-    heads = config.get("head_layers", ())
-    now = weights.make_params(shapes, 2**31 + 5, heads,
-                              config.get("stacked_layers", ()))
-    before = _make_params_before(shapes, 2**31 + 5, heads)
+def _assert_same_bits(now, before):
     assert jax.tree.structure(now) == jax.tree.structure(before)
     for a, b in zip(jax.tree.leaves(now), jax.tree.leaves(before)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_without_stacked_layers_the_weights_are_what_they_were(name):
+    config = _config(name)
+    assert not set(weights.LAYER_LISTS[1:]) & set(config)
+    shapes = _module_shapes(config)
+    heads = config.get("head_layers", ())
+    now = weights.config_params(shapes, 2**31 + 5, config)
+    _assert_same_bits(now, _make_params_before(shapes, 2**31 + 5, heads))
+
+
+def test_without_trunk_layers_stacked_kernels_are_what_they_were():
+    shapes = {"Experts_0": {"kernel": jax.ShapeDtypeStruct(
+        (4, 8, 8), jnp.float32)},
+        "Dense_0": {"kernel": jax.ShapeDtypeStruct((8, 8), jnp.float32),
+                    "bias": jax.ShapeDtypeStruct((8,), jnp.float32)}}
+    config = {"stacked_layers": ["Experts_0"], "head_layers": ["Dense_0"]}
+    _assert_same_bits(
+        weights.config_params(shapes, 9, config),
+        _make_params_before(shapes, 9, ["Dense_0"], ["Experts_0"]))
+
+
+def test_a_trunk_layers_dense_kernel_is_drawn_at_its_fan_in():
+    """A 2-D kernel under a named layer (or under a module so named) is
+    a projection of the trunk, ~ N(0, 1/fan-in); one outside stays a
+    head's, ~ N(0, 0.01/fan-in).  The same draw either way (same
+    ``fold_in`` index), and nothing else moves."""
+    kernel = {"kernel": jax.ShapeDtypeStruct((64, 64), jnp.float32)}
+    shapes = {"Block_0": {"attention": {"query": kernel, "out": kernel},
+                          "LayerNorm_0": {"scale": jax.ShapeDtypeStruct(
+                              (64,), jnp.float32)}},
+              "mlp_up": kernel, "policy_head": kernel}
+    plain = weights.make_params(shapes, 9)
+    config = {"trunk_layers": ["Block_0", "mlp_up"]}
+    trunk = weights.config_params(shapes, 9, config)
+    for name, leaf in (("query", trunk["Block_0"]["attention"]["query"]),
+                       ("out", trunk["Block_0"]["attention"]["out"]),
+                       ("mlp_up", trunk["mlp_up"])):
+        assert float(np.var(np.asarray(leaf["kernel"]))) == \
+            pytest.approx(1 / 64, rel=0.1), name
+    assert float(np.var(np.asarray(trunk["policy_head"]["kernel"]))) == \
+        pytest.approx(0.01 / 64, rel=0.1)
+    np.testing.assert_allclose(
+        np.asarray(trunk["mlp_up"]["kernel"]),
+        10.0 * np.asarray(plain["mlp_up"]["kernel"]), rtol=1e-6)
+    _assert_same_bits(trunk["policy_head"], plain["policy_head"])
+    _assert_same_bits(trunk["Block_0"]["LayerNorm_0"],
+                      plain["Block_0"]["LayerNorm_0"])
+    # a head named inside the trunk stays a head
+    both = weights.make_params(shapes, 9, head_layers=["out"],
+                               trunk_layers=["Block_0"])
+    _assert_same_bits(both["Block_0"]["attention"]["out"],
+                      plain["Block_0"]["attention"]["out"])
 
 
 def test_a_stacked_kernel_takes_its_fan_in_from_the_middle_axes():
