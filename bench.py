@@ -1736,7 +1736,7 @@ def setup_device_replay(seed, batch_size, compute_dtype, steps=40,
     optimizer = make_optimizer(1e-3)
     params = jax.tree.map(jnp.array, model.params)
     opt_state = optimizer.init(params)
-    from handyrl_tpu.staging import make_replay_update_step
+    from handyrl_tpu.staging import make_replay_update_step, step_state
 
     # the production path: draw + gather + update fused into ONE jit
     # per step, fed three host scalars (no per-step array uploads).
@@ -1747,7 +1747,7 @@ def setup_device_replay(seed, batch_size, compute_dtype, steps=40,
 
     timers = SectionTimers()
     state = {"params": params, "opt_state": opt_state,
-             "draw": replay.device_state(0)}
+             "draw": step_state(replay, 0)}
 
     def one_step(params, opt_state, draw):
         with timers.section("update"):

@@ -390,6 +390,22 @@ class DevicePrefetcher:
             thread.join(timeout=5)
 
 
+def _sum_steps(per_step):
+    """An epoch's per-step ``metrics`` (host numbers) -> what
+    ``Trainer._finish_epoch`` reads of them, in the form the fused
+    replay step carries on the device (``staging.epoch_sums``): each
+    key's sum over the steps, and ``steps``, their count."""
+    from .staging import COUNT_SUMS, LOSS_SUMS
+
+    keys = LOSS_SUMS + COUNT_SUMS + ("anakin_frames", "anakin_games")
+    sums = {"steps": len(per_step)}
+    for metrics in per_step:
+        for key in keys:
+            if key in metrics:
+                sums[key] = sums.get(key, 0.0) + float(metrics[key])
+    return sums
+
+
 class Trainer:
     """Owns device state (params + optimizer) and the jitted step."""
 
@@ -1004,10 +1020,12 @@ class Trainer:
         jitted program per step fed three host scalars; the host only
         drains newly arrived episodes into the ring (bounded per
         step)."""
+        from .staging import step_state
+
         replay = self.device_replay
         cap = self.updates_cap
-        batch_cnt, metric_acc = 0, []
-        state = None
+        batch_cnt = 0
+        state = metrics = None
         while batch_cnt == 0 or not self.update_flag:
             if self.shutdown_flag:
                 return None
@@ -1027,16 +1045,26 @@ class Trainer:
                 # the snapshot, releasing host CPU to the actors
                 time.sleep(0.01)
                 continue
-            if state is None or replay.state_dirty:
-                # one tiny upload per ring change; between changes the
-                # draw state lives on device and rides the jit
-                state = replay.device_state(self.steps)
+            if state is None:
+                # every epoch starts as the run's first did: the
+                # ring's scalars uploaded, the sums at zero
+                state = step_state(replay, self.steps)
+            elif replay.state_dirty:
+                # one tiny upload per ring change, of the ring's half
+                # alone: the epoch's sums stay where they are.  Between
+                # changes the whole state lives on device and rides
+                # the jit
+                state = replay.device_state(self.steps), state[1]
             with self.timers.section("update"):
+                # the step's own metrics are dropped as they return:
+                # the boundary reads the sums the step carries
                 metrics, state = self._fused_step(state)
             self.trace.tick()
-            metric_acc.append(metrics)
             batch_cnt += 1
-        return batch_cnt, metric_acc
+        # of the sums, those this step program adds to (a net with no
+        # return head has no "r"): the keys of any step's metrics
+        return batch_cnt, {key: total for key, total in state[1].items()
+                           if key == "steps" or key in metrics}
 
     def _fused_step(self, state):
         """Dispatch ONE fused replay step on the live params and ring;
@@ -1095,6 +1123,7 @@ class Trainer:
         import shutil
         import tempfile
 
+        from .staging import step_state
         from .telemetry import devtrace
         from .utils.profiling import profiler_options
 
@@ -1105,7 +1134,7 @@ class Trainer:
         if self._run_thread not in (None, threading.current_thread()):
             raise RuntimeError("the trainer thread is running")
         hlo = self._step_hlo_text()
-        state = replay.device_state(self.steps)
+        state = step_state(replay, self.steps)
         trace_dir = tempfile.mkdtemp(prefix="hrl-step-profile-")
         try:
             jax.profiler.start_trace(
@@ -1246,25 +1275,33 @@ class Trainer:
         # the epoch boundary: from the loop's return to train()'s, the
         # stretch in which this thread dispatches no step
         with telemetry.trace_span("trainer.boundary"):
-            return self._finish_epoch(*result)
+            batch_cnt, metrics = result
+            return self._finish_epoch(batch_cnt, self._drain(metrics))
 
-    def _finish_epoch(self, batch_cnt, metric_acc):
-        # ONE device->host fetch for the whole epoch's metrics: each
-        # per-step dict holds device scalars, and float()-ing them one
-        # by one would block on a separate transfer per value per step
-        # (jaxlint host-sync).  It waits for every step still queued.
-        with telemetry.trace_span("boundary.drain"):
-            device_metrics, metric_acc = \
-                metric_acc, jax.device_get(metric_acc)
-            # thousands of device scalars: releasing them is part of
-            # the drain, not of whoever drops the list last
-            del device_metrics[:]
-        data_cnt = sum(float(m["dcnt"]) for m in metric_acc)
-        loss_sum = {}
-        for m in metric_acc:
-            for k in ("p", "v", "r", "ent", "total"):
-                if k in m:
-                    loss_sum[k] = loss_sum.get(k, 0.0) + float(m[k])
+    def _drain(self, metrics):
+        """The epoch's metric sums on the host, by ONE ``device_get``
+        that waits for every step still queued.  The fused replay
+        step carried them on the device (``staging.epoch_sums``): a
+        dozen scalars whatever the epoch's length.  The other loops'
+        steps are other programs and hand over a list of per-step
+        dicts of device scalars: fetched and released one by one,
+        then summed here to the same form."""
+        per_step = isinstance(metrics, list)
+        with telemetry.trace_span("boundary.drain") as span:
+            span.attrs["arrays"] = len(jax.tree.leaves(metrics))
+            sums = jax.device_get(metrics)
+            if per_step:
+                # releasing the device scalars is part of the drain,
+                # not of whoever drops the list last
+                del metrics[:]
+                sums = _sum_steps(sums)
+            span.attrs["steps"] = int(sums["steps"])
+        return sums
+
+    def _finish_epoch(self, batch_cnt, sums):
+        data_cnt = float(sums["dcnt"])
+        loss_sum = {k: float(sums[k])
+                    for k in ("p", "v", "r", "ent", "total") if k in sums}
 
         print("loss = %s" % " ".join(
             [k + ":" + "%.3f" % (l / data_cnt) for k, l in loss_sum.items()]))
@@ -1330,12 +1367,13 @@ class Trainer:
             self.last_metrics["resharding_copies"] = \
                 self.shard_guard.snapshot()
         if self.num_guard is not None:
-            # the step's in-graph finiteness flag rode the metrics dict
-            # to the ONE device_get above — counting it here costs no
-            # extra host syncs.  note_step raises NumericsError when a
-            # max_nonfinite_steps budget is armed and exceeded
-            for m in metric_acc:
-                self.num_guard.note_step(m.get("nonfinite", 0.0))
+            # the steps' in-graph finiteness flags rode the epoch's
+            # sums to the ONE device_get of the drain — counting them
+            # here costs no extra host syncs.  note_step raises
+            # NumericsError when a max_nonfinite_steps budget is armed
+            # and exceeded
+            for _ in range(int(sums.get("nonfinite", 0))):
+                self.num_guard.note_step(1.0)
             self.last_metrics.update(self.num_guard.snapshot())
         if self.device_replay is not None:
             self.last_metrics["replay_episodes"] = \
@@ -1346,8 +1384,8 @@ class Trainer:
             # fused-rollout production this epoch (committed env
             # transitions / completed games); the learner divides by
             # epoch wall time into anakin_{frames,games}_per_sec
-            frames = sum(float(m["anakin_frames"]) for m in metric_acc)
-            games = sum(float(m["anakin_games"]) for m in metric_acc)
+            frames = float(sums["anakin_frames"])
+            games = float(sums["anakin_games"])
             self.anakin_frames_total += frames
             self.anakin_games_total += games
             self.last_metrics["anakin_frames"] = int(frames)
@@ -1357,11 +1395,9 @@ class Trainer:
         # importance ratio hit the clip this epoch (standard: rho >
         # rho_clip; impact: the surrogate ratio outside 1 +- eps) —
         # the live measure of how off-policy the consumed data was
-        fracs = [float(m["clip_frac"]) for m in metric_acc
-                 if "clip_frac" in m]
-        if fracs:
+        if "clip_frac" in sums:
             self.last_metrics["is_clip_frac"] = round(
-                sum(fracs) / len(fracs), 4)
+                float(sums["clip_frac"]) / int(sums["steps"]), 4)
         if self.target_params is not None:
             # steps since the target net last synced (hard interval),
             # or the Polyak EMA's effective horizon (constant by

@@ -80,6 +80,13 @@ def make_replay_update_step(replay, model, loss_cfg, optimizer,
     ring rides replicated and the gathered batch is constrained onto
     ``dp`` — each device materializes only its own batch rows.
 
+    ``state`` is the pair ``step_state`` makes, donated and threaded:
+    the ring's three scalars, and the epoch's running sums of what the
+    epoch boundary reads of ``metrics`` (``epoch_sums``).  The step
+    returns its own ``metrics`` as before and adds them to the sums, so
+    the boundary fetches a dozen scalars whatever the epoch's length,
+    not one device scalar per metric per step.
+
     Under ``update_algorithm: impact`` the signature grows the target
     params (same treatment as ``params``): ``step(params, opt_state,
     buffers, state, target_params)`` returning the refreshed target as
@@ -91,11 +98,12 @@ def make_replay_update_step(replay, model, loss_cfg, optimizer,
     impact = loss_cfg.update_algorithm == "impact"
     base_key = jax.random.PRNGKey(seed)
 
-    def _draw(buffers, state):
-        # state = device int32 [size, oldest, step_idx]: keeping the
-        # draw scalars ON DEVICE and threading the step counter through
-        # the jit means a steady-state step uploads NOTHING
-        size, oldest, step_idx = state[0], state[1], state[2]
+    def _draw(buffers, ring):
+        # ring = the first half of ``state``, device int32 [size,
+        # oldest, step_idx]: keeping the draw scalars ON DEVICE and
+        # threading the step counter through the jit means a
+        # steady-state step uploads NOTHING
+        size, oldest, step_idx = ring[0], ring[1], ring[2]
         # the scopes are HLO metadata only (the step's phases on a
         # device trace, telemetry/devtrace.py): they change no operation
         with jax.named_scope("replay.draw"):
@@ -109,19 +117,29 @@ def make_replay_update_step(replay, model, loss_cfg, optimizer,
                         x, replay._out), batch)
         return batch
 
+    def _advance(state, metrics):
+        ring, sums = state
+        # a dozen scalar adds, beside the gradient norm and the
+        # nonfinite flag they read: no phase of their own on a trace
+        with jax.named_scope("optimizer"):
+            sums = dict(sums, steps=sums["steps"] + 1)
+            for key in LOSS_SUMS + COUNT_SUMS:
+                if key in metrics:
+                    sums[key] = sums[key] + metrics[key].astype(
+                        sums[key].dtype)
+        return ring + jnp.asarray([0, 0, 1], jnp.int32), sums
+
     if impact:
         def step(params, opt_state, buffers, state, target_params):
-            batch = _draw(buffers, state)
+            batch = _draw(buffers, state[0])
             p, o, metrics, t = core(params, opt_state, batch,
                                     target_params)
-            return (p, o, metrics,
-                    state + jnp.asarray([0, 0, 1], jnp.int32), t)
+            return p, o, metrics, _advance(state, metrics), t
     else:
         def step(params, opt_state, buffers, state):
-            batch = _draw(buffers, state)
+            batch = _draw(buffers, state[0])
             p, o, metrics = core(params, opt_state, batch)
-            return p, o, metrics, state + jnp.asarray([0, 0, 1],
-                                                      jnp.int32)
+            return p, o, metrics, _advance(state, metrics)
 
     if mesh is None:
         if impact:
@@ -147,6 +165,40 @@ def make_replay_update_step(replay, model, loss_cfg, optimizer,
         out_shardings=(p_shard, o_shard, rep, rep),
         donate_argnums=(0, 1, 3),
     )
+
+
+# What the epoch boundary reads of a fused step's ``metrics``
+# (learner.Trainer._finish_epoch), every one of them as a sum over the
+# epoch's steps.  The losses sum in float32; the counts are whole
+# numbers (``dcnt`` sums a 0/1 mask, ``nonfinite`` is a 0/1 flag) and
+# sum in int32: ``dcnt`` feeds the learning rate, and float32 is exact
+# only to 2**24, 8192 steps of 2048 rows.
+LOSS_SUMS = ("p", "v", "r", "ent", "total", "clip_frac")
+COUNT_SUMS = ("dcnt", "nonfinite")
+
+
+def epoch_sums(replay):
+    """The running sums of ``state`` at the start of an epoch: zeros
+    for every key a step may add to (one its ``metrics`` lack stays
+    zero), and ``steps``, the steps summed.  The run's first and every
+    later epoch's come from HERE, as the ring's scalars come from
+    ``device_state``: host zeros put on the device (replicated under a
+    mesh), which lowers no program, and looks to the step's ``jit``
+    at every epoch's first step as it did at the run's."""
+    sums = {key: np.zeros((), np.float32) for key in LOSS_SUMS}
+    sums.update({key: np.zeros((), np.int32)
+                 for key in COUNT_SUMS + ("steps",)})
+    # no sharding: uncommitted on the default device, as ``jnp.asarray``
+    # leaves the ring's scalars
+    return jax.device_put(sums, replay._rep)
+
+
+def step_state(replay, step_idx):
+    """The fused step's fourth argument at the start of an epoch:
+    ``(replay.device_state(step_idx), epoch_sums(replay))``.  When the
+    ring moves mid-epoch only the first half is made anew."""
+    return replay.device_state(step_idx), epoch_sums(replay)
+
 
 _GROW_ROUND = 32   # T_max granularity; growth doubles => few recompiles
 # episode uploads pad to _GROW_ROUND-row buckets (not full t_max
@@ -307,12 +359,12 @@ class DeviceReplay:
         self._state_dirty = True   # ring changed since last device_state
 
     def device_state(self, step_idx):
-        """Device int32 ``[size, oldest, step_idx]`` for the fused
-        update step (make_replay_update_step).  Uploaded once here and
-        then THREADED through the jit (which returns it with the step
-        counter advanced), so steady-state steps upload nothing; call
-        again only when ``state_dirty`` says an append/growth moved
-        the ring."""
+        """Device int32 ``[size, oldest, step_idx]``: the ring's half
+        of the fused update step's ``state`` (make_replay_update_step,
+        ``step_state``).  Uploaded once here and then THREADED through
+        the jit (which returns it with the step counter advanced), so
+        steady-state steps upload nothing; call again only when
+        ``state_dirty`` says an append/growth moved the ring."""
         self._state_dirty = False
         arr = jnp.asarray(
             np.asarray([self.size, self.oldest, step_idx], np.int32))

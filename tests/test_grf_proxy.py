@@ -61,7 +61,8 @@ def test_net_carries_state_and_update_steps(tmp_path):
 
     from handyrl_tpu.ops.losses import LossConfig
     from handyrl_tpu.ops.update import make_optimizer
-    from handyrl_tpu.staging import DeviceReplay, make_replay_update_step
+    from handyrl_tpu.staging import (
+        DeviceReplay, make_replay_update_step, step_state)
 
     env, model, eps = _episodes(3)
     replay = DeviceReplay(CFG, capacity=8, max_bytes=2 << 30)
@@ -76,11 +77,15 @@ def test_net_carries_state_and_update_steps(tmp_path):
     update = make_replay_update_step(
         replay, model, LossConfig.from_config(CFG), optimizer,
         "bfloat16", batch_size=4, seed=0)
-    state = replay.device_state(0)
-    params, opt_state, metrics, state = update(
+    state = step_state(replay, 0)
+    params, opt_state, metrics, (ring, sums) = update(
         params, opt_state, replay.buffers, state)
     assert np.isfinite(float(metrics["total"]))
-    assert int(state[2]) == 1  # device-side step counter advanced
+    assert int(ring[2]) == 1  # device-side step counter advanced
+    # ... and the step's metrics went into the epoch's sums
+    assert int(sums["steps"]) == 1
+    assert float(sums["total"]) == float(metrics["total"])
+    assert int(sums["dcnt"]) == float(metrics["dcnt"]) > 0
 
 
 def test_ring_budget_caps_at_grf_byte_cost():

@@ -318,7 +318,7 @@ def _lowered_debug_text(kind):
     from handyrl_tpu.ops.update import (
         DEFAULT_LR, make_optimizer, make_update_step)
     from handyrl_tpu.staging import (
-        _decompress_episode, make_replay_update_step)
+        _decompress_episode, epoch_sums, make_replay_update_step)
 
     model, cfg, episodes = _ttt(2)
     if kind == "impact":
@@ -341,7 +341,8 @@ def _lowered_debug_text(kind):
         step = make_replay_update_step(
             replay, model, loss_cfg, optimizer, "float32", batch_size=4)
         args = [params, opt_state, buffers,
-                jax.ShapeDtypeStruct((3,), jnp.int32)]
+                (jax.ShapeDtypeStruct((3,), jnp.int32),
+                 epoch_sums(replay))]
         if kind == "impact":
             args.append(params)
         lowered = step.lower(*args)

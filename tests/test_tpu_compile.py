@@ -163,7 +163,8 @@ def _compile_replay_step(v5e, f):
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from handyrl_tpu.staging import _RUN_ROUND, make_replay_update_step
+    from handyrl_tpu.staging import (
+        _RUN_ROUND, epoch_sums, make_replay_update_step)
 
     chip = SingleDeviceSharding(v5e[0])
     replay = f["replay"]
@@ -172,7 +173,8 @@ def _compile_replay_step(v5e, f):
         "bfloat16", batch_size=f["batch"])
     compiled = step.lower(
         *_on((f["params"], f["opt_state"], f["buffers"],
-              jax.ShapeDtypeStruct((3,), jnp.int32)), chip)).compile()
+              (jax.ShapeDtypeStruct((3,), jnp.int32), epoch_sums(replay))),
+             chip)).compile()
     mem = compiled.memory_analysis()
     # the ring is all but ~6 MB (params + Adam moments) of the arguments
     assert abs(mem.argument_size_in_bytes - f["estimate"]) \
