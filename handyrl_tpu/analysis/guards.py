@@ -1064,8 +1064,8 @@ class ResourceLedger:
                     "epochs_sampled": self.epochs}
 
     def delta_line(self, since: dict) -> str:
-        """One-line human delta vs an earlier :meth:`sample` (bench
-        rounds log this so leak regressions show in CI artifacts)."""
+        """One-line human delta vs an earlier :meth:`sample`: what
+        moved, for a log line between two stretches of work."""
         now = self.sample()
 
         def arrow(key):

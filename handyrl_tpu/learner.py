@@ -464,8 +464,8 @@ class Trainer:
         # guard's on_compile hook harvests XLA's own flops/bytes for
         # each step program at its (rare) new-signature moments, and
         # train() reduces them into per-epoch mfu/achieved_tflops/
-        # roofline keys next to the guard counters — every run, not
-        # just bench
+        # roofline keys next to the guard counters, every run (read
+        # them as upper bounds: they divide by dispatch seconds)
         from .telemetry.costmodel import CostModel, PerfConfig
 
         self.costmodel = CostModel(

@@ -49,7 +49,7 @@ Routing semantics (the pool's failure model):
     request after request.
 
 Reconciliation invariant (same as the replica frontend, proven by the
-chaos drill and ``bench.py --router``): every arriving request is
+chaos drill in tests/test_router.py): every arriving request is
 accounted as exactly one of ``ok``/``shed``/``errors`` —
 ``submitted == ok + shed + errors`` at all times.
 

@@ -22,7 +22,7 @@ Public surface (see :mod:`.spans` for the design notes):
     accounting, reusable for any span family);
   * perf attribution: :mod:`.costmodel` (runtime MFU/roofline cost
     accounting over the guarded jit programs — ``CostModel`` /
-    ``PerfConfig`` / the one ``DEVICE_PEAKS`` table bench shares) and
+    ``PerfConfig`` / the one ``DEVICE_PEAKS`` table) and
     :mod:`.attribution` (the per-epoch self-time tree + the
     ``untracked_residual_sec`` wall-time reconciliation), surfaced in
     metrics.jsonl, the status ``perf`` section, and flight-recorder

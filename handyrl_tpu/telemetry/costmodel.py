@@ -1,18 +1,17 @@
 """Runtime MFU/roofline cost accounting for the guarded jit programs.
 
-The ROADMAP's honest perf gaps (MFU 0.0897 with 10x headroom, the
-e2e-vs-device-replay 0.104 ratio) were diagnosable only by hand-reading
-bench JSON; this module makes the same arithmetic a RUNTIME metric,
-every run, so perf PRs regress numerically instead of by vibes
-(Podracer, arXiv:2104.06272, treats exactly this decomposition as the
-primary dataflow-design signal).
+XLA's own flops and bytes per guarded program, reduced once per epoch
+into metrics.jsonl keys, every run (Podracer, arXiv:2104.06272, treats
+this decomposition as the primary dataflow-design signal).  The
+seconds it divides by are the trainer thread's DISPATCH seconds, on a
+device that runs steps ahead of the host: read ``mfu`` and
+``achieved_tflops`` as upper bounds, not as a utilization (the
+benchmark's device-time roofline is benchmarks/harness/roofline.py).
 
 Three pieces:
 
   * **The peak table** — ONE per-device-kind (bf16 peak TFLOP/s, peak
-    HBM GB/s) table, :data:`DEVICE_PEAKS`.  bench.py's former private
-    ``PEAK_TFLOPS`` copy is a view of this table, so bench and runtime
-    can never disagree on what "peak" means.  Unknown kinds (CPU CI
+    HBM GB/s) table, :data:`DEVICE_PEAKS`.  Unknown kinds (CPU CI
     hosts) resolve to ``(None, None)`` and the roofline verdict reads
     ``unknown`` — unless the run overrides via ``perf.peak_tflops`` /
     ``perf.peak_hbm_gbs`` (:class:`PerfConfig`), which is also how CPU
@@ -48,7 +47,7 @@ Three pieces:
     ``series()`` skip-absent pattern does the right thing.
 
 jax is imported lazily (device-kind detection only): scripts read the
-peak table and the ledger math without dragging a jax runtime in.
+peak table without dragging a jax runtime in.
 """
 
 import queue
@@ -56,8 +55,7 @@ import sys
 import threading
 
 # bf16 peak TFLOP/s and peak HBM GB/s per chip by device kind (public
-# specs).  THE one table — bench.py's PEAK_TFLOPS is a view of column
-# one.  Unknown kinds fall back to (None, None) -> mfu omitted/None.
+# specs).  Unknown kinds fall back to (None, None) -> mfu None.
 DEVICE_PEAKS = {
     "TPU v4": (275.0, 1228.0),
     "TPU v5": (459.0, 2765.0),
@@ -66,9 +64,6 @@ DEVICE_PEAKS = {
     "TPU v6 lite": (918.0, 1640.0),
     "TPU v6e": (918.0, 1640.0),
 }
-
-# bench.py compatibility view (kind -> bf16 peak TFLOP/s)
-PEAK_TFLOPS = {kind: peaks[0] for kind, peaks in DEVICE_PEAKS.items()}
 
 
 def device_kind():
@@ -164,22 +159,6 @@ def _abstractify(args, kwargs):
         return leaf
 
     return jax.tree.map(to_struct, (args, kwargs))
-
-
-def mfu_extras(flops_step, steps_per_sec, kind=None, peak=None):
-    """The bench-side achieved-TFLOPs/MFU reduction (bench.py's former
-    private plumbing, now shared with the runtime): extras dict with
-    ``achieved_tflops_est`` always and ``mfu_measured`` when a peak is
-    known for ``kind`` (or given directly)."""
-    achieved = float(flops_step) * float(steps_per_sec) / 1e12
-    out = {"achieved_tflops_est": round(achieved, 2)}
-    if peak is None:
-        if kind is None:
-            kind = device_kind()
-        peak = PEAK_TFLOPS.get(kind)
-    if peak:
-        out["mfu_measured"] = round(achieved / peak, 4)
-    return out
 
 
 class CostModel:

@@ -5,7 +5,7 @@ set in code — jax reads that variable itself.  Unset, the cache goes to
 ONE fixed directory inside the checkout: the directory is part of the
 cache key, so a temp name, a pid or a timestamp would never hit.  The
 choice is exported through the same variable, so every process this
-one spawns (actors, batchers, eval matches, bench children — all fresh
+one spawns (actors, batchers, eval matches — all fresh
 interpreters) lands on the same directory without being told.
 
 What it buys on a cold machine: the cost harvest

@@ -337,7 +337,7 @@ def test_refresh_pool_shifts_newest_in_oldest_out():
 
 
 def test_fused_step_compiles_once_and_keeps_layouts():
-    """The acceptance contract the bench asserts too: N fused steps =
+    """The acceptance contract of the fused step: N fused steps =
     exactly 1 compile (RetraceGuard) and 0 resharding copies
     (ShardingContractGuard) with donated state threading through."""
     from handyrl_tpu.analysis.guards import (

@@ -179,8 +179,8 @@ def test_gather_tree_scales_to_16_workers():
     assert args["worker"]["num_gathers"] == 1  # 16 workers -> 1 gather
 
     # modest bar with generous wall budget: this asserts the topology
-    # works at 16 workers, not a throughput number (bench.py measures
-    # that) — CI hosts and parallel test runs share cores
+    # works at 16 workers, not a throughput number — CI hosts and
+    # parallel test runs share cores
     episodes, target = 0, 48
     deadline = time.time() + 240
     try:

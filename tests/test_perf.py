@@ -1,4 +1,4 @@
-"""Perf attribution layer: cost model, self-time tree, ledger, report.
+"""Perf attribution layer: cost model, self-time tree, report.
 
 Covers the PR-20 contracts end to end without a training run:
 
@@ -12,8 +12,7 @@ Covers the PR-20 contracts end to end without a training run:
     untracked-residual identity over a metrics record's rounded values;
   * Attributor snapshots + the flight-recorder ``register_dump_extra``
     ride-along;
-  * scripts/perf_ledger.py append/--check regression verdicts and
-    scripts/attribution_report.py over a synthetic run directory.
+  * scripts/attribution_report.py over a synthetic run directory.
 """
 
 import json
@@ -35,10 +34,8 @@ from handyrl_tpu.telemetry.attribution import (
 )
 from handyrl_tpu.telemetry.costmodel import (
     DEVICE_PEAKS,
-    PEAK_TFLOPS,
     CostModel,
     PerfConfig,
-    mfu_extras,
     resolve_peaks,
 )
 
@@ -46,7 +43,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__),
                                 "..", "scripts"))
 
 import attribution_report  # noqa: E402
-import perf_ledger  # noqa: E402
 
 
 # -- PerfConfig / peaks -------------------------------------------------
@@ -76,20 +72,6 @@ def test_resolve_peaks_table_override_and_unknown():
         (123.0, DEVICE_PEAKS["TPU v4"][1])
     # unknown kind, no override: nothing to claim
     assert resolve_peaks(None, kind="CPU") == (None, None)
-
-
-def test_bench_view_is_column_one_of_the_table():
-    assert PEAK_TFLOPS == {k: v[0] for k, v in DEVICE_PEAKS.items()}
-
-
-def test_mfu_extras_matches_the_bench_reduction():
-    out = mfu_extras(1e12, 2.0, kind="TPU v4")
-    assert out["achieved_tflops_est"] == 2.0
-    assert out["mfu_measured"] == round(2.0 / 275.0, 4)
-    # unknown kind: MFU omitted, achieved still reported
-    out = mfu_extras(1e12, 2.0, kind="CPU")
-    assert "mfu_measured" not in out
-    assert out["achieved_tflops_est"] == 2.0
 
 
 # -- guard hook + harvest ----------------------------------------------
@@ -423,81 +405,6 @@ def test_failing_dump_extra_never_blocks_the_dump(tmp_path):
     path = telemetry.dump("test")
     doc = json.loads(open(path).read())
     assert doc["reason"] == "test" and "flaky" not in doc
-
-
-# -- perf ledger -------------------------------------------------------
-
-def _ledger_with(tmp_path, source, values, key="steps_per_sec"):
-    path = str(tmp_path / "ledger.jsonl")
-    for i, value in enumerate(values):
-        perf_ledger.append_entry(path, source, {key: value}, ts=i)
-    return path
-
-
-def test_ledger_append_from_bench_json_and_check_green(tmp_path, capsys):
-    bench = tmp_path / "bench_pipeline.json"
-    bench.write_text(json.dumps({
-        "metric": "pipeline_e2e_speedup", "value": 1.4,
-        "unit": "ratio", "learner_steps_per_sec_e2e_pipelined": 20.0}))
-    ledger = str(tmp_path / "ledger.jsonl")
-    rc = perf_ledger.main([str(bench), "--ledger", ledger, "--ts", "1"])
-    assert rc == 0
-    entry = json.loads(open(ledger).read())
-    assert entry["source"] == "pipeline_e2e_speedup"
-    assert entry["metrics"] == {
-        "value": 1.4, "learner_steps_per_sec_e2e_pipelined": 20.0}
-    # < min-prior history: trivially green
-    assert perf_ledger.main(["--check", "--ledger", ledger]) == 0
-    assert "no regressions" in capsys.readouterr().out
-
-
-def test_ledger_check_fails_on_throughput_regression(tmp_path, capsys):
-    ledger = _ledger_with(tmp_path, "bench",
-                          [10.0, 10.2, 9.8, 10.1, 5.0])
-    rc = perf_ledger.main(["--check", "--ledger", ledger,
-                           "--tolerance", "0.25"])
-    out = capsys.readouterr().out
-    assert rc == 1 and "REGRESS" in out
-    # the same drop inside tolerance passes
-    ledger2 = _ledger_with(tmp_path / "b", "bench",
-                           [10.0, 10.2, 9.8, 10.1, 9.0])
-    assert perf_ledger.main(["--check", "--ledger", ledger2]) == 0
-
-
-def test_ledger_check_directions(tmp_path):
-    # lower-is-better: recovery_sec rising fails
-    ledger = _ledger_with(tmp_path, "chaos", [1.0, 1.1, 0.9, 3.0],
-                          key="chaos_recovery_sec")
-    assert perf_ledger.main(["--check", "--ledger", ledger]) == 1
-    # higher value of a lower-is-better metric in the PAST is fine
-    ledger2 = _ledger_with(tmp_path / "b", "chaos",
-                           [3.0, 1.1, 0.9, 1.0],
-                           key="chaos_recovery_sec")
-    assert perf_ledger.main(["--check", "--ledger", ledger2]) == 0
-    # unregistered metric names are archived but never gate
-    ledger3 = _ledger_with(tmp_path / "c", "misc",
-                           [1.0, 1.0, 1.0, 99.0], key="mystery_number")
-    assert perf_ledger.main(["--check", "--ledger", ledger3]) == 0
-
-
-def test_ledger_summarizes_run_directories(tmp_path):
-    run = tmp_path / "run"
-    run.mkdir()
-    records = []
-    for epoch in range(4):
-        records.append({
-            "epoch": epoch, "steps": 100 * (epoch + 1),
-            "epoch_wall_sec": 10.0, "mfu": 0.1 + epoch * 0.01,
-            "batch_wait_sec": 2.0, "untracked_residual_sec": 1.0})
-    (run / "metrics.jsonl").write_text(
-        "".join(json.dumps(r) + "\n" for r in records))
-    source, metrics = perf_ledger.load_source(str(run))
-    assert source == "run"
-    # 300 steps over 3 post-first-epoch walls of 10s
-    assert metrics["steps_per_sec"] == pytest.approx(10.0)
-    assert metrics["mfu"] == pytest.approx(0.115)
-    assert metrics["batch_wait_share"] == pytest.approx(0.2)
-    assert metrics["residual_share"] == pytest.approx(0.1)
 
 
 # -- attribution report ------------------------------------------------

@@ -308,11 +308,18 @@ def test_epoch_stats_reduce_and_reset():
 # frontend end to end over real TCP (stub model, real service thread)
 # ---------------------------------------------------------------------
 
-def _real_stack(**serving_over):
+def _wait(cond, msg, deadline=10.0):
+    end = time.monotonic() + deadline
+    while not cond():
+        assert time.monotonic() < end, msg
+        time.sleep(0.01)
+
+
+def _real_stack(model=None, **serving_over):
     from handyrl_tpu.pipeline.service import InferenceService
 
     env = _StubEnv()
-    model = _StubModel()
+    model = model or _StubModel()
     pcfg = PipelineConfig.from_config({
         "mode": "on", "batch_window": 0.001, "max_batch": 16})
     svc = InferenceService(model, pcfg, epoch=1)
@@ -387,6 +394,162 @@ def test_service_kill_sheds_typed_then_respawn_resumes():
             client.close()
         fe.close()
         svc.close()
+
+
+def test_service_kill_under_load_answers_every_request_typed():
+    """The kill drill with requests in flight: four closed-loop
+    clients, the inference service killed under them and respawned.
+    No request is lost — each comes back ok, a typed ``service_down``
+    shed or a typed error, requests parked at the kill are answered
+    by the respawned incarnation — serving resumes on the same
+    connections, and the frontend's counters equal the clients'."""
+    env, model, svc, fe = _real_stack()
+    seen = {"ok": 0, "shed": [], "error": 0, "lost": []}
+    lock, stop = threading.Lock(), threading.Event()
+
+    def load():
+        client = ServeClient("127.0.0.1", fe.port, timeout=10.0)
+        obs = np.zeros(2, np.float32)
+        try:
+            while not stop.is_set():
+                try:
+                    client.infer(obs)
+                    with lock:
+                        seen["ok"] += 1
+                except ShedError as err:
+                    with lock:
+                        seen["shed"].append(err.reason)
+                    time.sleep(0.005)   # a shed client backs off
+                except ServeError:
+                    with lock:
+                        seen["error"] += 1
+                except Exception as exc:    # transport: a lost request
+                    with lock:
+                        seen["lost"].append(repr(exc))
+                    return
+        finally:
+            client.close()
+
+    threads = [threading.Thread(target=load, daemon=True)
+               for _ in range(4)]
+    try:
+        for t in threads:
+            t.start()
+        _wait(lambda: seen["ok"] >= 20, "load never warmed")
+        svc.inject_kill()
+        _wait(lambda: not svc.alive, "kill never landed")
+        _wait(lambda: seen["shed"], "the gap shed nothing")
+        ok_at_respawn = seen["ok"]
+        svc.respawn()
+        _wait(lambda: seen["ok"] >= ok_at_respawn + 20,
+              "serving never resumed")
+    finally:
+        stop.set()
+        for t in threads:
+            t.join(timeout=15)
+        stats = fe.stats()
+        fe.close()
+        svc.close()
+    assert not any(t.is_alive() for t in threads)
+    assert seen["lost"] == []
+    assert set(seen["shed"]) == {"service_down"}
+    assert stats["submitted"] == (seen["ok"] + len(seen["shed"])
+                                  + seen["error"])
+    assert stats["ok"] == seen["ok"]
+    assert stats["shed_by"] == {"service_down": len(seen["shed"])}
+    assert stats["errors"] == seen["error"]
+
+
+def test_arrivals_above_capacity_shed_typed_and_reconcile():
+    """Overload over real TCP: more concurrent clients than
+    ``max_inflight`` against a forward that is held shut.  Exactly
+    ``max_inflight`` arrivals are admitted and every other one is
+    answered at once with a typed ``overload`` shed — none queues
+    behind the held forward, none is dropped; then, with arrivals
+    still above the cap, every request of every client comes back as
+    exactly one of ok / shed, and the frontend's counters reconcile
+    with what the clients saw."""
+    cap, n_clients, rounds = 2, 6, 20
+    hold, gate = threading.Event(), threading.Event()
+
+    class _HeldModel(_StubModel):
+        def inference_batch(self, obs, hidden=None):
+            if hold.is_set():
+                assert gate.wait(10.0), "the test never opened the gate"
+            return super().inference_batch(obs, hidden)
+
+    env, model, svc, fe = _real_stack(
+        _HeldModel(), reply_timeout=20.0, max_inflight=cap)
+    seen = {"ok": 0, "overload": 0, "other": []}
+    lock = threading.Lock()
+    first = {}      # what the clients had seen when the held round ended
+    first_done = threading.Barrier(
+        n_clients + 1, action=lambda: first.update(seen))
+
+    def one(client, obs):
+        try:
+            client.infer(obs)
+            outcome = "ok"
+        except ShedError as err:
+            outcome = err.reason
+        except Exception as exc:     # typed error or a lost request
+            outcome = repr(exc)
+        with lock:
+            if outcome in ("ok", "overload"):
+                seen[outcome] += 1
+            else:
+                seen["other"].append(outcome)
+
+    def load():
+        client = ServeClient("127.0.0.1", fe.port, timeout=30.0)
+        obs = np.zeros(2, np.float32)
+        try:
+            one(client, obs)
+            first_done.wait(30.0)
+            for _ in range(rounds):
+                one(client, obs)
+        finally:
+            client.close()
+
+    threads = [threading.Thread(target=load, daemon=True)
+               for _ in range(n_clients)]
+    try:
+        # past the frontend's own warm request, the forward is held
+        _wait(lambda: model.calls, "frontend never warmed")
+        hold.set()
+        for t in threads:
+            t.start()
+        # the cap's worth of arrivals sit admitted, the rest must
+        # already have their typed answer
+        _wait(lambda: fe.stats()["shed"] >= n_clients - cap,
+              "the arrivals above the cap were not shed")
+        held = fe.stats()
+        assert held["submitted"] == n_clients
+        assert held["shed_by"] == {"overload": n_clients - cap}
+        assert held["ok"] == 0 and held["errors"] == 0
+        assert fe.inflight == cap
+        gate.set()
+        first_done.wait(30.0)
+        assert first["ok"] == cap
+        assert first["overload"] == n_clients - cap
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        gate.set()
+        stats = fe.stats()
+        fe.close()
+        svc.close()
+    # every request of every client was answered, and typed
+    total = n_clients * (1 + rounds)
+    assert seen["other"] == []
+    assert seen["ok"] + seen["overload"] == total
+    # and the frontend counted exactly what the clients saw
+    assert stats["submitted"] == total
+    assert stats["ok"] == seen["ok"] and stats["errors"] == 0
+    assert stats["shed"] == seen["overload"]
+    assert stats["shed_by"] == {"overload": seen["overload"]}
+    assert fe.inflight == 0
 
 
 def test_connection_cap_refuses_at_accept():
