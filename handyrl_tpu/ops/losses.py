@@ -5,8 +5,9 @@ Semantic parity with /root/reference/handyrl/train.py:128-268:
     — MXU-friendly: a single large batched matmul/conv stream;
   * recurrent nets run a ``lax.scan`` over time with observation-mask
     hidden blending, turn-based hidden gathering, and gradient-free
-    burn-in (``stop_gradient`` per step — GroupNorm models have no
-    train/eval mode divergence, so burn-in needs no mode switch);
+    burn-in (a forward-only scan of its own ahead of the trained one —
+    GroupNorm models have no train/eval mode divergence, so burn-in
+    needs no mode switch);
   * losses: V-Trace/UPGO/TD/MC targets on detached values, importance
     ratios clipped at ``rho_clip``/``c_clip`` (both 1 by default, the
     reference behavior), two-player zero-sum value symmetrization,
@@ -23,6 +24,7 @@ Semantic parity with /root/reference/handyrl/train.py:128-268:
 Everything here is pure and traced once per batch geometry.
 """
 
+from functools import partial
 from typing import Any, Callable, Dict, NamedTuple, Optional
 
 import jax
@@ -95,18 +97,21 @@ def _flatten_lead(tree, n):
 
 def forward_prediction(apply_fn: Callable, params, hidden, batch,
                        cfg: LossConfig) -> Dict[str, jnp.ndarray]:
-    """Run the net over a (B, T, P_in, ...) batch -> (B, T, P_in/P, ...).
+    """Run the net over a (B, T, P_in, ...) batch -> (B, T - b, P_in/P,
+    ...): the trained steps only, ``b = cfg.burn_in_steps``.
 
-    ``hidden`` is the initial (B, P, ...) recurrent state or None.
+    ``hidden`` is the initial (B, P, ...) recurrent state or None; a
+    recurrent net spends the first ``b`` steps warming it, forward only.
     """
     observations = batch["observation"]
     B, T, P_in = batch["action"].shape[:3]
+    b = cfg.burn_in_steps
 
     if hidden is None:
         obs_flat = _flatten_lead(observations, 3)  # (B*T*P_in, ...)
         out = apply_fn(params, obs_flat, None)
         outputs = {
-            k: v.reshape((B, T, P_in) + v.shape[1:])
+            k: v.reshape((B, T, P_in) + v.shape[1:])[:, b:]
             for k, v in out.items()
             if v is not None
         }
@@ -117,9 +122,8 @@ def forward_prediction(apply_fn: Callable, params, hidden, batch,
         P_model = 1 if (cfg.turn_based_training and not cfg.observation) \
             else omask_full.shape[2]
 
-        def step(carry, xs):
-            hidden = carry
-            obs_t, omask_t, t = xs  # (B, P_in, ...), (B, P, 1), scalar
+        def step(params, hidden, xs):
+            obs_t, omask_t = xs  # (B, P_in, ...), (B, P, 1)
 
             # zero hidden where the player did not observe (episode
             # starts inside the window restart the recurrence)
@@ -148,15 +152,6 @@ def forward_prediction(apply_fn: Callable, params, hidden, batch,
                 next_hidden,
             )
 
-            # burn-in steps contribute no gradient
-            burn = t < cfg.burn_in_steps
-            out = jax.tree.map(
-                lambda v: jnp.where(burn, lax.stop_gradient(v), v), out
-            )
-            next_hidden = jax.tree.map(
-                lambda v: jnp.where(burn, lax.stop_gradient(v), v), next_hidden
-            )
-
             # write the new hidden into observed seats only
             new_hidden = jax.tree.map(
                 lambda h, nh: h * (1 - mask_like(h)) + nh * mask_like(h),
@@ -165,25 +160,31 @@ def forward_prediction(apply_fn: Callable, params, hidden, batch,
             )
             return new_hidden, out
 
-        xs = (
-            jax.tree.map(lambda a: jnp.moveaxis(a, 1, 0), observations),
-            jnp.moveaxis(omask_full, 1, 0),
-            jnp.arange(T),
-        )
-        _, outs = lax.scan(step, hidden, xs)
+        xs = jax.tree.map(lambda a: jnp.moveaxis(a, 1, 0),
+                          (observations, omask_full))
+        if b > 0:
+            # burn-in contributes no gradient: nothing differentiable
+            # goes in, so no backward pass is built and no residual
+            # kept; nothing per step comes out, so the heads drop too
+            hidden, _ = lax.scan(
+                lambda h, x: (step(lax.stop_gradient(params), h, x)[0], None),
+                lax.stop_gradient(hidden),
+                jax.tree.map(lambda a: a[:b], xs))
+        _, outs = lax.scan(partial(step, params), hidden,
+                           jax.tree.map(lambda a: a[b:], xs))
         outputs = {k: jnp.moveaxis(v, 0, 1) for k, v in outs.items()}
 
     # mask heads: policy by turn, scalar heads by observation
     result = {}
     for k, o in outputs.items():
         if k == "policy":
-            o = o * batch["turn_mask"]  # may broadcast P_in -> P
+            o = o * batch["turn_mask"][:, b:]  # may broadcast P_in -> P
             if o.shape[2] > P_in:
                 # turn-alternating batch: collapse back to the acting seat
                 o = o.sum(axis=2, keepdims=True)
-            result[k] = o - batch["action_mask"]
+            result[k] = o - batch["action_mask"][:, b:]
         else:
-            result[k] = o * batch["observation_mask"]
+            result[k] = o * batch["observation_mask"][:, b:]
     return result
 
 
@@ -273,9 +274,6 @@ def compute_loss(apply_fn: Callable, params, batch, hidden, cfg: LossConfig,
             k: v[:, b:] if v.shape[1] > 1 else v for k, v in batch.items()
             if k != "observation"
         } | {"observation": batch["observation"]}
-        outputs = {k: v[:, b:] for k, v in outputs.items()}
-        if tgt_outputs is not None:
-            tgt_outputs = {k: v[:, b:] for k, v in tgt_outputs.items()}
 
     actions = batch["action"]
     emasks = batch["episode_mask"]

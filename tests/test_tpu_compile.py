@@ -129,7 +129,8 @@ def geister():
     """``geister_drc``: Geister / GeisterNet's DRC, turn-based with
     observation (the ring's ``all`` mode), burn-in 4, 214 actions (the
     masks pack into 14 words a row), bf16 wire, batch 128 x 12.  Its
-    temporaries are the recurrent net's activations: 889 MB."""
+    temporaries are the recurrent net's activations over the 8 trained
+    steps: 593 MB (889 MB while the burn-in steps kept theirs too)."""
     return dict(_planned(
         "Geister",
         {"turn_based_training": True, "observation": True,
