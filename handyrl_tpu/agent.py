@@ -23,7 +23,10 @@ ILLEGAL = 1e32
 
 def masked_logits(logits, legal_actions):
     """Return a copy of ``logits`` with illegal entries pushed to -inf
-    scale, so downstream softmax/argmax see only legal actions."""
+    scale, so downstream softmax/argmax see only legal actions;
+    ``legal_actions`` None: every action is legal."""
+    if legal_actions is None:
+        return np.array(logits)
     masked = np.full_like(logits, -ILLEGAL)
     masked[legal_actions] = logits[legal_actions]
     return masked
@@ -40,14 +43,17 @@ def sample_action(logits, legal_actions, temperature=1.0):
     masked = masked_logits(logits, legal_actions)
     probs = softmax_np(masked)
     if temperature == 0:
-        action = int(np.argmax(masked))
-    elif temperature == 1.0:
-        action = random.choices(legal_actions,
-                                weights=probs[legal_actions])[0]
+        return int(np.argmax(masked)), probs
+    drawn = probs if temperature == 1.0 else softmax_np(masked / temperature)
+    if legal_actions is None:
+        # a vocabulary of actions, none listed: one draw against the
+        # running sum, not a weighted choice over a list of thousands
+        cdf = np.cumsum(drawn)
+        action = min(np.searchsorted(cdf, random.random() * cdf[-1],
+                                     side="right"), len(cdf) - 1)
     else:
-        tempered = softmax_np(masked / temperature)
         action = random.choices(legal_actions,
-                                weights=tempered[legal_actions])[0]
+                                weights=drawn[legal_actions])[0]
     return int(action), probs
 
 

@@ -15,7 +15,8 @@ Batch layout (B = batch, T = time, P = players, A = actions):
   observation      pytree of (B, T, P_in, ...)   P_in = 1 if turn-based
   selected_prob    (B, T, P_in, 1)   behavior-policy probability
   action           (B, T, P_in, 1)   int32
-  action_mask      (B, T, P_in, A)   0 legal / 1e32 illegal
+  action_mask      (B, T, P_in, A)   0 legal / 1e32 illegal; A = 0
+                                     where every action is legal
   value/reward/return (B, T, P, V)
   outcome          (B, 1, P, 1)
   episode_mask     (B, T, 1, 1)      0 on padding
@@ -109,11 +110,16 @@ def _nbytes_tree(x):
 def _build_columnar(moments):
     """Stack one block's moments into (T, P_all, ...) arrays."""
     players = list(moments[0]["observation"].keys())
-    turn0 = moments[0]["turn"][0]
-    obs_template = tree_map(
-        lambda a: np.zeros_like(a), moments[0]["observation"][turn0]
-    )
-    num_actions = len(moments[0]["action_mask"][turn0])
+    # a step may have an observer and no one on turn (context a policy
+    # only reads): the shapes come from whoever observed and acted first
+    first_obs = next(o for m in moments
+                     for o in m["observation"].values() if o is not None)
+    obs_template = tree_map(lambda a: np.zeros_like(a), first_obs)
+    # width 0 where the environment lists no legal actions (all are),
+    # and in a block in which no one acted
+    num_actions = len(next((a for m in moments
+                            for a in m["action_mask"].values()
+                            if a is not None), ()))
 
     def pick(m, key, p, default):
         v = m[key][p]
@@ -169,7 +175,8 @@ def _build_columnar(moments):
         np.float32,
     )
     turn_idx = np.array(
-        [players.index(m["turn"][0]) for m in moments], np.int64)
+        [players.index(m["turn"][0]) if m["turn"] else 0
+         for m in moments], np.int64)
 
     return {
         "players": players,

@@ -25,6 +25,7 @@ ENV_REGISTRY = {
     "Geister": "handyrl_tpu.envs.geister",
     "HungryGeese": "handyrl_tpu.envs.kaggle.hungry_geese",
     "GRFProxy": "handyrl_tpu.envs.grf_proxy",
+    "TokenTask": "handyrl_tpu.envs.token_task",
 }
 
 # pure-JAX twins of registered envs: functional (state, action, key)
@@ -141,6 +142,9 @@ class BaseEnvironment:
 
     # -- actions & players ------------------------------------------
     def legal_actions(self, player=None):
+        """The actions ``player`` may take, listed; None says every
+        action is legal (a vocabulary of tokens): no mask is then
+        recorded, sent or kept in the ring."""
         raise NotImplementedError()
 
     def players(self):

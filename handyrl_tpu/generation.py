@@ -97,10 +97,15 @@ def generation_participants(env, trained_players, observation_flag):
 
 def record_action(moment, player, policy, legal):
     """Sample an action from masked ``policy`` and record the behavior
-    probability + action mask into the moment (IS bookkeeping)."""
+    probability + action mask into the moment (IS bookkeeping).  An
+    environment that lists no legal actions (``legal`` None) says all
+    are: the mask recorded has width 0, on the wire and in the ring."""
     action, probs = sample_action(policy, legal)
-    mask = np.full_like(policy, ILLEGAL)
-    mask[legal] = 0.0
+    if legal is None:
+        mask = np.zeros(0, np.float32)
+    else:
+        mask = np.full_like(policy, ILLEGAL)
+        mask[legal] = 0.0
     moment["action"][player] = action
     moment["selected_prob"][player] = float(probs[action])
     moment["action_mask"][player] = mask

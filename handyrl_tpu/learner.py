@@ -64,7 +64,7 @@ from .resilience import FleetRegistry
 from .parallel.mesh import OrderedLaunch
 from .utils.compile_cache import metadata_in_key
 from .utils.profiling import SectionTimers, TraceWindow
-from .ops.losses import LossConfig
+from .ops.losses import SEQUENCE_COUNTERS, LossConfig
 from .ops.update import (
     DEFAULT_LR,
     make_optimizer,
@@ -509,6 +509,10 @@ class Trainer:
             # the learner still serves ``model`` (epoch 0) to workers
             # that ask for it after the first step
             self.params = jax.tree.map(jax.numpy.array, model.params)
+            # ... and the served copy goes to the host, where every
+            # later epoch's snapshot lives: beside a train state of
+            # gigabytes a second set of parameters does not fit the chip
+            model.params = jax.tree.map(np.asarray, model.params)
             self.opt_state = self.optimizer.init(self.params)
             if self.impact:
                 self.target_params = jax.tree.map(np.asarray, self.params)
@@ -916,12 +920,35 @@ class Trainer:
         print(f"defaulting to dp={dp} over {n_dev} devices")
         return {"dp": dp}
 
+    def _check_sequence_net(self, mesh_cfg):
+        """What a net whose window is the sequence cannot be built
+        with: input checks, each refused with its reason."""
+        length = self.model.module.sequence_length
+        if int(self.args.get("burn_in_steps", 0) or 0) > 0:
+            raise ValueError(
+                "a sequence net takes the whole window in one causal "
+                "pass and carries no state to warm: burn_in_steps must "
+                f"be 0 (it is {self.args['burn_in_steps']})")
+        if length > self.args["forward_steps"]:
+            raise ValueError(
+                f"an episode of this net can be {length} steps long and "
+                f"a window holds forward_steps = "
+                f"{self.args['forward_steps']}: windows start at "
+                "position 0 and must hold the whole sequence")
+        if any(int(v) > 1 for k, v in mesh_cfg.items() if k != "fsdp"):
+            raise ValueError(
+                f"mesh {mesh_cfg} asks for more than one device and a "
+                "sequence net's experts have no axis to be divided "
+                "over yet (parallel/mesh.py): it trains on one device")
+
     def _build_update_step(self):
         dtype = self.compute_dtype
         print(f"compute dtype: {dtype}")
         mesh_cfg = dict(self.args.get("mesh") or {})
         axes_cfg = {k: v for k, v in mesh_cfg.items() if k != "fsdp"}
-        if not axes_cfg:
+        if self.model.is_sequence:
+            self._check_sequence_net(mesh_cfg)
+        elif not axes_cfg:
             # only auto-shard when the user left the mesh AXES unset
             # (a bare {fsdp: true} still engages auto-dp); an explicit
             # all-ones mesh (e.g. {dp: 1}) forces the unsharded step
@@ -1034,8 +1061,13 @@ class Trainer:
                 # drain arrivals even when idling at the cap, so the
                 # pending queue can't overflow and shed episodes; the
                 # seconds of every call feed profile_ingest_sec, the
-                # span is ingest's own (none for an empty call)
-                replay.ingest(max_episodes=8)
+                # span is ingest's own (none for an empty call).  One
+                # scatter's worth between two steps: 8 episodes at a
+                # board game's window, 4 at a window of thousands of
+                # steps, whose episodes take a tenth of a second each
+                # to unpack (8 of them outlast the step, and the
+                # device waits)
+                replay.ingest(max_episodes=replay.max_run)
             # ring growth re-lays the buffers (new shapes): those
             # recompiles are designed, so they widen the retrace
             # budget instead of tripping it
@@ -1106,7 +1138,11 @@ class Trainer:
         on the live params and ring under a private profiler session,
         reduced by ``telemetry.devtrace.step_phases`` to ``{steps,
         step_ms, phases: {gather, forward, targets, backward, optimizer,
-        unscoped}}`` (ms per step); the trace is deleted, the answer
+        unscoped}, scopes, counters}`` (ms per step; ``scopes``: the
+        net's own named scopes, forward and transpose together;
+        ``counters``: what the profiled steps counted beside their
+        losses, ``ops.losses.SEQUENCE_COUNTERS``; both empty for a net
+        that has none); the trace is deleted, the answer
         cached.  Only once the trainer thread has ended, or from it: the
         step donates the state that thread owns.  Never raises: a
         failure (no fused step, no TPU plane in the trace, a profiler
@@ -1139,16 +1175,25 @@ class Trainer:
         try:
             jax.profiler.start_trace(
                 trace_dir, profiler_options=profiler_options())
+            counted = []
             try:
                 for _ in range(steps):
-                    _, state = self._fused_step(state)
+                    metrics, state = self._fused_step(state)
+                    counted.append({k: metrics[k] for k in SEQUENCE_COUNTERS
+                                    if k in metrics})
                 jax.block_until_ready(state)
             finally:
                 jax.profiler.stop_trace()
             trace = devtrace.load(devtrace.find_xplane(trace_dir), hlo)
         finally:
             shutil.rmtree(trace_dir, ignore_errors=True)
-        return devtrace.step_phases(trace)
+        profile = devtrace.step_phases(trace)
+        counted = jax.device_get(counted)
+        # the fullest expert of any profiled step; the others as means
+        profile["counters"] = {
+            k: float((max if k.endswith("_max") else np.mean)(
+                [c[k] for c in counted])) for k in counted[0]}
+        return profile
 
     def _epoch_loop_anakin(self):
         """Anakin epoch: self-play rollout, batch assembly, and the
@@ -1206,7 +1251,8 @@ class Trainer:
         global assembly) or the host prefetcher."""
         if self.device_replay is not None:
             with self.timers.section("ingest", span=False):
-                self.device_replay.ingest(max_episodes=8)
+                self.device_replay.ingest(
+                    max_episodes=self.device_replay.max_run)
             # growth recompiles are designed: widen the retrace budget
             self.retrace_guard.allowance = self.device_replay.growths
             with self.timers.section("batch_wait"):

@@ -67,6 +67,14 @@ class TPUModel:
     def is_recurrent(self) -> bool:
         return hasattr(self.module, "init_hidden")
 
+    @property
+    def is_sequence(self) -> bool:
+        """The training window IS this net's sequence (one causal pass
+        over time, no state carried from step to step): a module that
+        declares ``sequence_length``, the positions its actor-side
+        cache holds."""
+        return bool(getattr(self.module, "sequence_length", 0))
+
     # -- forward ----------------------------------------------------
     def apply(self, params, obs, hidden=None):
         return self.module.apply({"params": params}, obs, hidden)

@@ -8,6 +8,10 @@ Semantic parity with /root/reference/handyrl/train.py:128-268:
     burn-in (a forward-only scan of its own ahead of the trained one —
     GroupNorm models have no train/eval mode divergence, so burn-in
     needs no mode switch);
+  * sequence nets (``SEQUENCE``) take the window AS the sequence: one
+    causal pass over ``(B, T)`` tokens, no carried state, the policy
+    factored (``FactoredPolicy``) so that logits of vocabulary width
+    exist a chunk of positions at a time;
   * losses: V-Trace/UPGO/TD/MC targets on detached values, importance
     ratios clipped at ``rho_clip``/``c_clip`` (both 1 by default, the
     reference behavior), two-player zero-sum value symmetrization,
@@ -37,6 +41,29 @@ from .targets import compute_target
 # come from LossConfig (rho_clip / c_clip surface them as config keys)
 CLIP_RHO = 1.0
 CLIP_C = 1.0
+
+# in ``hidden``'s place for a net whose window is the sequence: it
+# carries no state from step to step (``TPUModel.is_sequence``)
+SEQUENCE = "sequence"
+POLICY_CHUNK = 1024     # positions whose logits exist together
+
+
+@jax.tree_util.register_pytree_node_class
+class FactoredPolicy:
+    """Policy logits not yet multiplied out: ``features (..., d)`` of
+    the trunk and the head's ``kernel (d, actions)``.  At a vocabulary
+    of actions the logits of a whole window are gigabytes, so the loss
+    takes what it needs of them (``policy_terms``) chunk by chunk."""
+
+    def __init__(self, features, kernel):
+        self.features, self.kernel = features, kernel
+
+    def tree_flatten(self):
+        return (self.features, self.kernel), None
+
+    @classmethod
+    def tree_unflatten(cls, _aux, children):
+        return cls(*children)
 
 
 class LossConfig(NamedTuple):
@@ -107,6 +134,25 @@ def forward_prediction(apply_fn: Callable, params, hidden, batch,
     B, T, P_in = batch["action"].shape[:3]
     b = cfg.burn_in_steps
 
+    if hidden is SEQUENCE:
+        # the window is the sequence: one seat's tokens (B, T) in one
+        # causal pass; a seat axis of one goes back on what comes out
+        # positions past the episode's end go in as -1: they reach no
+        # loss term and no real position, and take no expert
+        tokens = jnp.where(batch["episode_mask"][:, :, 0, 0] > 0,
+                           observations[:, :, 0], -1)
+        out = apply_fn(params, tokens, None)
+        policy = out["policy"]
+        # no bias in the head: masking the features masks the logits;
+        # an all-legal batch has no action mask to take off (width 0)
+        return {
+            "policy": FactoredPolicy(
+                policy.features[:, :, None] * batch["turn_mask"].astype(
+                    policy.features.dtype), policy.kernel),
+            "value": out["value"][:, :, None] * batch["observation_mask"],
+            "expert_load": out["expert_load"],
+            "expert_picks": out["expert_picks"],
+        }
     if hidden is None:
         obs_flat = _flatten_lead(observations, 3)  # (B*T*P_in, ...)
         out = apply_fn(params, obs_flat, None)
@@ -202,14 +248,51 @@ def _masked_entropy(logits, axis=-1):
     return -jnp.sum(p * jnp.clip(lsm, -1e32, 0.0), axis=axis)
 
 
+def policy_terms(policy, actions):
+    """``(log-probability of the actions taken (..., 1), entropy (...)
+    or None)`` of ``policy``.  Dense logits: the log-softmax's entry,
+    and the entropy is left to ``compose_losses`` as it always was.  A
+    ``FactoredPolicy``: both, from logits made ``POLICY_CHUNK``
+    positions at a time and made again coming back, never whole."""
+    if not isinstance(policy, FactoredPolicy):
+        log_policy = jax.nn.log_softmax(policy, axis=-1)
+        return jnp.take_along_axis(log_policy, actions, axis=-1), None
+    feats = policy.features.reshape(-1, policy.features.shape[-1])
+    taken = actions.reshape(-1)
+    pad = -feats.shape[0] % POLICY_CHUNK
+    feats = jnp.pad(feats, [(0, pad), (0, 0)])
+    taken = jnp.pad(taken, (0, pad))
+
+    @jax.checkpoint
+    def chunk(kernel, xs):
+        f, a = xs
+        logits = jnp.dot(f, kernel, preferred_element_type=jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        p = jax.nn.softmax(logits, axis=-1)
+        selected = jnp.take_along_axis(logits, a[:, None], axis=-1)[:, 0]
+        return selected - lse, lse - (p * logits).sum(-1)
+
+    with jax.named_scope("net.forward"), jax.named_scope("net.head"):
+        selected, entropy = lax.map(
+            partial(chunk, policy.kernel),
+            (feats.reshape(-1, POLICY_CHUNK, feats.shape[-1]),
+             taken.reshape(-1, POLICY_CHUNK)))
+    n = actions.size
+    return (selected.reshape(-1)[:n].reshape(actions.shape),
+            entropy.reshape(-1)[:n].reshape(actions.shape[:-1]))
+
+
 def compose_losses(outputs, log_selected_policies, total_advantages,
-                   targets, batch, cfg: LossConfig, policy_loss=None):
+                   targets, batch, cfg: LossConfig, policy_loss=None,
+                   entropy=None):
     """Combine policy / value / return / entropy losses (summed, not
     averaged — the lr schedule normalizes by the data-count EMA).
 
     ``policy_loss`` (per-element, pre-mask) replaces the default
     score-function term when given — the IMPACT surrogate plugs in
-    here without duplicating the rest of the composition."""
+    here without duplicating the rest of the composition.  ``entropy``
+    (per acting seat) comes with a factored policy, whose logits are
+    not there to take it from."""
     tmasks = batch["turn_mask"]
     omasks = batch["observation_mask"]
 
@@ -228,7 +311,9 @@ def compose_losses(outputs, log_selected_policies, total_advantages,
             _huber(outputs["return"] - targets["return"]) * omasks
         ).sum()
 
-    entropy = _masked_entropy(outputs["policy"]) * tmasks.sum(-1)  # (B,T,P)
+    if entropy is None:
+        entropy = _masked_entropy(outputs["policy"])
+    entropy = entropy * tmasks.sum(-1)  # (B,T,P)
     losses["ent"] = entropy.sum()
 
     base_loss = losses["p"] + losses.get("v", 0.0) + losses.get("r", 0.0)
@@ -286,17 +371,13 @@ def compute_loss(apply_fn: Callable, params, batch, hidden, cfg: LossConfig,
             jnp.log(jnp.clip(batch["selected_prob"], 1e-16, 1.0)) * emasks
         )
     with jax.named_scope("loss.terms"):
-        log_policy = jax.nn.log_softmax(outputs["policy"], axis=-1)
-        log_selected_t = (
-            jnp.take_along_axis(log_policy, actions, axis=-1) * emasks
-        )
+        log_selected_t, entropy = policy_terms(outputs["policy"], actions)
+        log_selected_t = log_selected_t * emasks
     log_selected_g = None
     with jax.named_scope("loss.targets"):
         if impact:
-            log_policy_g = jax.nn.log_softmax(tgt_outputs["policy"], axis=-1)
-            log_selected_g = (
-                jnp.take_along_axis(log_policy_g, actions, axis=-1) * emasks
-            )
+            log_selected_g = policy_terms(
+                tgt_outputs["policy"], actions)[0] * emasks
 
     with jax.named_scope("loss.targets"):
         # importance-sampling ratios (behavior -> correction policy),
@@ -379,7 +460,7 @@ def compute_loss(apply_fn: Callable, params, batch, hidden, cfg: LossConfig,
                 (jnp.abs(ratio - 1.0) > eps) * tmasks).sum() / denom
             losses, dcnt = compose_losses(
                 outputs, log_selected_t, None, targets, batch, cfg,
-                policy_loss=policy_loss)
+                policy_loss=policy_loss, entropy=entropy)
         else:
             total_advantages = clipped_rhos * sum(advantages.values())
             # how often the rho clip actually engaged: the off-policy
@@ -387,6 +468,27 @@ def compute_loss(apply_fn: Callable, params, batch, hidden, cfg: LossConfig,
             clip_frac = ((rhos > cfg.rho_clip) * tmasks).sum() / denom
             losses, dcnt = compose_losses(
                 outputs, log_selected_t, total_advantages, targets, batch,
-                cfg)
+                cfg, entropy=entropy)
     losses["clip_frac"] = clip_frac
+    if "expert_load" in outputs:
+        losses.update(sequence_counters(
+            outputs["expert_load"], outputs["expert_picks"], emasks))
     return losses, dcnt
+
+
+# what a sequence net's step counts beside its losses: they ride the
+# step's ``metrics`` and ``Trainer.step_profile`` reads them
+SEQUENCE_COUNTERS = ("expert_load_max", "expert_load_mean",
+                     "held_pick_share", "window_fill")
+
+
+def sequence_counters(expert_load, expert_picks, episode_mask):
+    """``expert_load (expert layers, experts held)``: positions routed
+    to each held expert this step -> the fullest expert's and the mean;
+    ``held_pick_share``: of a layer's ``expert_picks`` (positions x
+    experts per token), those that fell on held experts;
+    ``window_fill``: the share of window positions that hold a token."""
+    load = expert_load.astype(jnp.float32)
+    return {"expert_load_max": load.max(), "expert_load_mean": load.mean(),
+            "held_pick_share": load.sum(-1).mean() / expert_picks,
+            "window_fill": episode_mask.mean()}

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
-from .losses import LossConfig, compute_loss
+from .losses import SEQUENCE, FactoredPolicy, LossConfig, compute_loss
 
 DEFAULT_LR = 3e-8
 GRAD_CLIP_NORM = 4.0
@@ -78,7 +78,12 @@ def make_apply_fn(model, compute_dtype="float32") -> Callable:
             _cast_floats(obs, dtype),
             _cast_floats(hidden, dtype),
         )
-        return _cast_floats(out, jnp.float32)
+        # a factored policy stays in the compute dtype: its logits are
+        # made float32 a chunk at a time (ops.losses.policy_terms)
+        return jax.tree.map(
+            lambda a: a if isinstance(a, FactoredPolicy)
+            else _cast_floats(a, jnp.float32), out,
+            is_leaf=lambda a: isinstance(a, FactoredPolicy))
 
     return apply_fn
 
@@ -131,7 +136,9 @@ def make_update_core(model, cfg: LossConfig,
     def _step(params, opt_state, batch, target_params):
         B = batch["value"].shape[0]
         P = batch["value"].shape[2]
-        hidden = model.init_hidden([B, P])
+        # a sequence net carries no state across the window's steps
+        hidden = SEQUENCE if model.is_sequence \
+            else model.init_hidden([B, P])
         grads, (losses, dcnt) = jax.grad(loss_fn, has_aux=True)(
             params, batch, hidden, target_params
         )

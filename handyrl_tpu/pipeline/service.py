@@ -788,6 +788,11 @@ class InferenceService:
         try:
             if client is not None:
                 self._adopt_model()
+            # no forward to warm for a sequence net: no worker sends it
+            # a row (a net with per-seat state is never wrapped), and
+            # its stateless call is the learner's pass over a window
+            if client is not None and not getattr(
+                    self._model, "is_sequence", False):
                 buckets = {_bucket(1, self.cfg.max_batch,
                                    self._bucket_floor),
                            _bucket(client.rows_max, self.cfg.max_batch,

@@ -208,7 +208,34 @@ _GROW_ROUND = 32   # T_max granularity; growth doubles => few recompiles
 # past the ring that no gather ever reads
 _RUN_ROUND = 256
 _MAX_RUN = 8       # per-slot scatter width (ingest batch cap)
+_MAX_RUN_ROWS = 16384   # rows one scatter carries at most
 _PER_SLOT = ("outcome", "ep_len", "ep_total")
+
+
+def _run_geometry(t_win):
+    """``(_RUN_ROUND, _MAX_RUN)`` for a ring whose training window is
+    ``t_win`` steps.  An append program exists per ``_RUN_ROUND`` rows
+    up to ``_MAX_RUN`` whole slots: at a window of a dozen steps and
+    episodes of a few hundred that is a handful (256 rows, 8 episodes),
+    at a window that is a 4,096-token sequence it would be 128.  So the
+    bucket is never narrower than the window and a run never carries
+    more than ``_MAX_RUN_ROWS`` rows: 4 programs of 4,096 to 16,384
+    rows there, and what it was wherever the window fits 256 rows."""
+    bucket = max(256, _round_up(t_win, 256))
+    return bucket, max(1, min(8, _MAX_RUN_ROWS // bucket))
+
+
+def fit_runs_to_window(t_win):
+    """Set the module's ``_RUN_ROUND`` and ``_MAX_RUN`` to
+    ``_run_geometry(t_win)`` and return them.  A ring keeps its own
+    pair (fixed for the run); the module's names say what the window
+    declared last uses, which is where the benchmark's priming reads
+    them -- a ring declares its window as it is built, and an
+    environment whose episode IS the window (``envs/token_task.py``) as
+    it is made, for a tool that primes and builds no ring."""
+    global _RUN_ROUND, _MAX_RUN
+    _RUN_ROUND, _MAX_RUN = _run_geometry(t_win)
+    return _RUN_ROUND, _MAX_RUN
 
 
 def _decompress_episode(ep):
@@ -249,18 +276,30 @@ def _mask_words(P, A):
     return -(-P * (A + 2) // 32)
 
 
-def _steps_width(P, A):
-    """Columns of the ring's ``steps`` channel (``_pack_steps``)."""
-    return 5 * P + 1 + _mask_words(P, A)
+def _steps_width(P, A, riders=0):
+    """Columns of the ring's ``steps`` channel (``_pack_steps``);
+    ``riders``: observation leaves that ride it."""
+    return (5 + riders) * P + 1 + _mask_words(P, A)
+
+
+def _rides_steps(leaf):
+    """An observation leaf of ONE integer a seat (a token: ``(T, P)``)
+    rides the ``steps`` channel, ``P`` columns after the turn index: a
+    ring channel of its own one column wide would be re-laid ring-wide
+    by the row gather (``_pack_steps``)."""
+    return leaf.ndim == 2 and np.issubdtype(leaf.dtype, np.integer)
 
 
 def _pack_steps(col):
     """A columnar episode's per-step scalars -> the ``(T,
     _steps_width(P, A))`` int32 rows of the ring's ``steps`` channel:
     ``prob``, ``act``, ``value``, ``reward``, ``return`` (P columns
-    each, the float ones as their bits), the turn index, then the
-    three masks' bits (host side, once per episode; ``_gather_batch``
-    takes the row apart again, bit for bit).
+    each, the float ones as their bits), the turn index, the
+    observation's integer-scalar leaves (``_rides_steps``; none for a
+    board game), then the three masks' bits (host side, once per
+    episode; ``_gather_batch`` takes the row apart again, bit for
+    bit).  An all-legal episode has an action mask of width 0 and so no
+    mask bits but the two seat masks'.
 
     ONE channel of 32-bit elements, and not one per quantity, because
     of what the TPU's row gather does with a narrow channel (asked of
@@ -286,10 +325,13 @@ def _pack_steps(col):
         [np.reshape(col[key], (T, -1)) != 0
          for key in ("omask", "tmask", "amask")], axis=1)
     bits = np.pad(bits, [(0, 0), (0, -bits.shape[1] % 32)])
+    riders = [leaf.reshape(T, -1).astype(np.int32)
+              for leaf in jax.tree.leaves(col["obs"]) if _rides_steps(leaf)]
     return np.concatenate(
         [f32_bits("prob"), i32("act"), f32_bits("value"),
-         f32_bits("reward"), f32_bits("return"), i32("turn_idx"),
-         np.packbits(bits, axis=1, bitorder="little").view("<i4")],
+         f32_bits("reward"), f32_bits("return"), i32("turn_idx")]
+        + riders
+        + [np.packbits(bits, axis=1, bitorder="little").view("<i4")],
         axis=1)
 
 
@@ -323,6 +365,8 @@ class DeviceReplay:
         self.forward_steps = cfg["forward_steps"]
         self.burn_in = cfg.get("burn_in_steps", 0) or 0
         self.t_win = self.burn_in + self.forward_steps
+        # the append programs' geometry follows the window
+        self.run_round, self.max_run = fit_runs_to_window(self.t_win)
         if cfg["turn_based_training"]:
             self.mode = "all" if cfg.get("observation") else "turn"
         else:
@@ -393,7 +437,7 @@ class DeviceReplay:
                 self._offered_at.popleft()
                 self.dropped += 1
 
-    def ingest(self, max_episodes=64, batch=_MAX_RUN):
+    def ingest(self, max_episodes=64, batch=8):
         """Trainer-thread only: move pending episodes into the device
         ring.  Bounded per call so one call can't stall an update.
 
@@ -405,7 +449,7 @@ class DeviceReplay:
             return      # an empty call records no span
         with _telemetry.trace_span("trainer.ingest") as span:
             episodes = self.episodes_seen
-            self._ingest(max_episodes, min(batch, _MAX_RUN))
+            self._ingest(max_episodes, min(batch, self.max_run))
             span.attrs["episodes"] = self.episodes_seen - episodes
 
     def _ingest(self, max_episodes, batch):
@@ -448,7 +492,7 @@ class DeviceReplay:
                 # never more episodes than ring slots in one scatter:
                 # repeated slot indices would mix trajectories
                 # (undefined duplicate-index winner)
-                run = cols[:self.capacity]
+                run = cols[:min(self.capacity, self.max_run)]
                 self._append_run(run)
                 del cols[:len(run)]
 
@@ -500,14 +544,17 @@ class DeviceReplay:
 
         P = len(col["players"])
         A = col["amask"].shape[-1]
-        obs_bytes = 0
+        obs_bytes = riders = 0
         for leaf in jax.tree.leaves(col["obs"]):
+            if _rides_steps(leaf):
+                riders += 1
+                continue
             width = int(np.prod(leaf.shape[1:]))  # (T, P, ...) -> P*...
             item = (np.dtype(self.obs_store).itemsize
                     if np.issubdtype(leaf.dtype, np.floating)
                     else leaf.dtype.itemsize)
             obs_bytes += row(width, item)
-        step = obs_bytes + row(_steps_width(P, A), 4)
+        step = obs_bytes + row(_steps_width(P, A, riders), 4)
         return step * self.t_max + self._slot_const_bytes(P)
 
     @staticmethod
@@ -544,12 +591,14 @@ class DeviceReplay:
         A = col["amask"].shape[-1]
         # + one scratch stripe past the ring (and one scratch slot)
         # where batched-append PADDING rows land; gathers never read it
-        flat = self.capacity * self.t_max + _RUN_ROUND
+        flat = self.capacity * self.t_max + self.run_round
         # logical per-step shapes; stored flattened to 2D (see module
         # docstring: TPU tile padding on small trailing dims)
-        self.obs_shapes = [leaf.shape[1:]
-                           for leaf in jax.tree.leaves(col["obs"])]
+        leaves = jax.tree.leaves(col["obs"])
+        self.obs_shapes = [leaf.shape[1:] for leaf in leaves]
         self.obs_treedef = jax.tree.structure(col["obs"])
+        # leaves that ride the steps channel have no buffer of their own
+        self.obs_rides = [_rides_steps(leaf) for leaf in leaves]
         self.num_actions = A
 
         def spec(shape, dtype):
@@ -560,17 +609,25 @@ class DeviceReplay:
             return spec((flat, _stored_width(width)), dtype)
 
         return {
-            "obs": tree_map(
+            "obs": self._own_channels(
                 lambda a: flat2d(a.shape[1:],
                                  self.obs_store
                                  if np.issubdtype(a.dtype, np.floating)
                                  else a.dtype),
                 col["obs"]),
-            "steps": flat2d((_steps_width(P, A),), jnp.int32),
+            "steps": flat2d(
+                (_steps_width(P, A, sum(self.obs_rides)),), jnp.int32),
             "outcome": spec((self.capacity + 1, P, 1), jnp.float32),
             "ep_len": spec((self.capacity + 1,), jnp.int32),
             "ep_total": spec((self.capacity + 1,), jnp.int32),
         }
+
+    def _own_channels(self, fn, obs):
+        """``fn`` over the observation leaves that have a ring channel
+        of their own; one that rides ``steps`` leaves an empty place."""
+        return jax.tree.unflatten(self.obs_treedef, [
+            None if rides else fn(leaf)
+            for leaf, rides in zip(jax.tree.leaves(obs), self.obs_rides)])
 
     def _init_buffers(self, col):
         self.buffers = jax.tree.map(
@@ -632,7 +689,8 @@ class DeviceReplay:
             return a.astype(self.obs_store)
 
         return {
-            "obs": tree_map(lambda a: padt(obs_store(a)), col["obs"]),
+            "obs": self._own_channels(
+                lambda a: padt(obs_store(a)), col["obs"]),
             "steps": padt(_pack_steps(col)),
             "outcome": col["outcome"][None],  # (1, P, 1): one ring slot
             "ep_len": np.asarray([T], np.int32),
@@ -655,7 +713,7 @@ class DeviceReplay:
             slots = [(self.write_ptr + i) % self.capacity
                      for i in range(k)]
             total = sum(rows)
-            pad = -total % _RUN_ROUND
+            pad = -total % self.run_round
             scratch = self.capacity * self.t_max
             flat_idx = np.concatenate(
                 [s * self.t_max + np.arange(r, dtype=np.int32)
@@ -663,7 +721,7 @@ class DeviceReplay:
                 + ([scratch + np.arange(pad, dtype=np.int32)]
                    if pad else []))
             slot_idx = np.asarray(
-                slots + [self.capacity] * (_MAX_RUN - k), np.int32)
+                slots + [self.capacity] * (self.max_run - k), np.int32)
 
             def cat_steps(*arrs):
                 out = np.concatenate(arrs)
@@ -675,9 +733,9 @@ class DeviceReplay:
 
             def cat_slots(*arrs):
                 out = np.concatenate(arrs)
-                if k < _MAX_RUN:
+                if k < self.max_run:
                     out = np.concatenate([out, np.zeros(
-                        (_MAX_RUN - k,) + out.shape[1:], out.dtype)])
+                        (self.max_run - k,) + out.shape[1:], out.dtype)])
                 return out
 
             ep = {key: jax.tree.map(
@@ -727,7 +785,7 @@ class DeviceReplay:
 
         def relayout(buf):
             def leaf(a):
-                if a.shape[0] == cap * old_t + _RUN_ROUND:
+                if a.shape[0] == cap * old_t + self.run_round:
                     rows = a[flat_keep].reshape(
                         (kept, old_t) + a.shape[1:])
                     pad = [(0, new_cap - kept), (0, new_t_max - old_t)
@@ -736,7 +794,8 @@ class DeviceReplay:
                         (new_cap * new_t_max,) + a.shape[1:])
                     # fresh scratch stripe past the new ring
                     return jnp.pad(
-                        flat, [(0, _RUN_ROUND)] + [(0, 0)] * (a.ndim - 1))
+                        flat,
+                        [(0, self.run_round)] + [(0, 0)] * (a.ndim - 1))
                 # per-slot channel (+ its scratch slot)
                 rows = a[keep]
                 pad = [(0, new_cap + 1 - kept)] + [(0, 0)] * (a.ndim - 1)
@@ -881,17 +940,30 @@ class DeviceReplay:
         prob, act = column(0), column(1, jnp.int32)
         value, reward, ret = column(2), column(3), column(4)
         turn = steps[..., 5 * P]                         # (B,T)
-        bits = (steps[..., 5 * P + 1:, None] >> jnp.arange(32)) & 1
-        bits = bits.reshape(flat_idx.shape + (-1,)) != 0
+        riders = sum(self.obs_rides)
+        words = steps[..., (5 + riders) * P + 1:, None]
+        bits = ((words >> jnp.arange(32)) & 1).reshape(
+            flat_idx.shape + (-1,)) != 0
         omask = bits[..., :P, None]
         tmask = bits[..., P:2 * P, None]
         amask = bits[..., 2 * P:P * (A + 2)].reshape(
             flat_idx.shape + (P, A))
-        obs = jax.tree.unflatten(self.obs_treedef, [
-            (fetch_seat if self.mode == "seat" else fetch)(buf, shape)
-            for buf, shape in zip(
-                jax.tree.leaves(buffers["obs"]), self.obs_shapes)
-        ])                              # (B,T,P,...); seat: (B,T,1,...)
+        own = iter(jax.tree.leaves(buffers["obs"]))
+        rode = iter(range(riders))
+        obs = []
+        for shape, rides in zip(self.obs_shapes, self.obs_rides):
+            if rides:
+                at = 5 * P + 1 + next(rode) * P
+                leaf = steps[..., at:at + P]             # (B,T,P)
+                if self.mode == "seat":
+                    leaf = jnp.take_along_axis(
+                        leaf, seats[:, None, None], axis=2)
+            else:
+                leaf = (fetch_seat if self.mode == "seat"
+                        else fetch)(next(own), shape)
+            obs.append(leaf)
+        obs = jax.tree.unflatten(self.obs_treedef, obs)
+        #                                 (B,T,P,...); seat: (B,T,1,...)
         outcome = buffers["outcome"][slots]              # (B,P,1)
 
         def select_players(x, idx):

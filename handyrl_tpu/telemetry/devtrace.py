@@ -50,6 +50,10 @@ PHASES = ("gather", "forward", "targets", "backward", "optimizer",
 _SCOPE = re.compile(
     r"(?:^|[/(])(" + "|".join(re.escape(s) for s in PHASE_OF_SCOPE)
     + r")(?=[/)]|$)")
+# a net's own scopes inside ``net.forward`` (``net.attention.window``,
+# ``net.moe.experts``, ``net.head``, ...: models/sequence_net.py), by
+# which a step's time is also told apart, forward and transpose together
+_NET_SCOPE = re.compile(r"(?:^|[/(])(net\.[\w.]+)(?=[/)]|$)")
 _INSTRUCTION = re.compile(r"\s*(?:ROOT )?%?([\w.\-]+)(?: = |$)")
 _DEFINITION = re.compile(r"\s*(?:ROOT )?%?([\w.\-]+) = ")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
@@ -133,6 +137,13 @@ def phase_of(op_name):
     return PHASE_OF_SCOPE[scopes[-1]] if scopes else None
 
 
+def net_scope_of(op_name):
+    """The innermost scope ``net.<part>`` an ``op_name`` lies in,
+    ``net.forward`` itself aside; None where it lies in none."""
+    scopes = [s for s in _NET_SCOPE.findall(op_name) if s != "net.forward"]
+    return scopes[-1] if scopes else None
+
+
 def _chip0(trace):
     devices = sorted((p for p in trace["planes"]
                       if p["name"].startswith(DEVICE_PLANE)),
@@ -172,7 +183,10 @@ def step_phases(trace, module=None):
     Two parts of ``unscoped`` are given beside it: ``op_gap_ms``, the
     step's time in which no op ran at all, and ``unmatched_ms``, ops
     whose instruction the HLO text does not hold (a text of another
-    compile).  A text that names no scope of the step at all (none
+    compile).  ``scopes`` tells the same time apart by the net's own
+    scopes (``net_scope_of``; ms a step, an op under ``transpose(``
+    counted with its forward; empty for a net that names none).  A
+    text that names no scope of the step at all (none
     was kept, or the executable came from a compile-cache entry that a
     build without the scopes wrote) is an error, not a step that is
     all ``unscoped``."""
@@ -189,6 +203,7 @@ def step_phases(trace, module=None):
             f"the text of {module} names no scope of the step (none "
             "kept, or a compile-cache entry of a build without them)")
     total = dict.fromkeys(PHASES, 0.0)
+    scopes = {}
     unmatched, covered, at = 0.0, 0.0, 0
     for name, start, dur in _top_level(_line(plane, OPS_LINE)):
         while at < len(steps) and steps[at][1] <= start:
@@ -203,11 +218,15 @@ def step_phases(trace, module=None):
         phase = phase_of(names.get(name, ""))
         if phase is not None:
             total[phase] += dur
+        part = net_scope_of(names.get(name, ""))
+        if part is not None:
+            scopes[part] = scopes.get(part, 0.0) + dur
     step_ns = sum(b - a for a, b in steps)
     total["unscoped"] = step_ns - sum(total.values())
     per_step = 1e-6 / len(steps)
     return {"steps": len(steps), "step_ms": step_ns * per_step,
             "phases": {k: v * per_step for k, v in total.items()},
+            "scopes": {k: v * per_step for k, v in sorted(scopes.items())},
             "op_gap_ms": (step_ns - covered) * per_step,
             "unmatched_ms": unmatched * per_step}
 

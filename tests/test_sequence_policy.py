@@ -1,0 +1,386 @@
+"""The sparse-expert sequence policy at its tiny preset, seeded weights,
+CPU, float32: the program against the plain reference
+(``benchmarks/reference/trinity_net.py``), the chip's share of the
+experts against the uncut layer, the actor's one-token step against the
+learner's sequence pass, the window, the maskless wire and ring, the
+chunked head, the refusals, and one epoch of ``main.py --train``'s path.
+"""
+
+import os
+import random
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.harness import weights
+from benchmarks.reference import training as ref_training
+from benchmarks.reference import trinity_net, trinity_training
+from handyrl_tpu.batch import make_batch
+from handyrl_tpu.environment import make_env
+from handyrl_tpu.generation import Generator
+from handyrl_tpu.models import sequence_net as sn
+from handyrl_tpu.models.wrapper import TPUModel
+from handyrl_tpu.ops import losses
+from handyrl_tpu.ops.update import make_apply_fn
+from handyrl_tpu.staging import DeviceReplay, _run_geometry
+
+TINY = sn.PRESETS["tiny"]
+ENV_ARGS = {"env": "TokenTask", "net": "tiny"}
+TRAIN = {
+    "turn_based_training": False, "observation": True, "gamma": 1.0,
+    "forward_steps": 32, "burn_in_steps": 0, "compress_steps": 4,
+    "entropy_regularization": 0.01, "entropy_regularization_decay": 0.1,
+    "lambda": 0.95, "policy_target": "TD", "value_target": "TD",
+    "compute_dtype": "float32", "batch_size": 4,
+}
+LAYERS = [f"layer_{i}" for i in range(len(TINY.layer_types))]
+
+
+@pytest.fixture(autouse=True)
+def _highest():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+@pytest.fixture()
+def tiny_geometry(monkeypatch):
+    """The plain reference told the tiny preset's geometry (what the
+    weights' shapes do not say)."""
+    for key, value in {
+            "layer_types": TINY.layer_types, "num_dense_layers": 1,
+            "sliding_window": TINY.window, "query_block": 16,
+            "num_experts_per_tok": TINY.experts_per_token}.items():
+        monkeypatch.setitem(trinity_net.GEOMETRY, key, value)
+
+
+@pytest.fixture(scope="module")
+def model():
+    net = TPUModel(sn.sequence_net("tiny"))
+    shapes = weights.param_shapes(net.module, np.int32(0),
+                                  net.init_hidden([1]))
+    net.params = weights.make_params(shapes, 7, (), ["experts"], LAYERS)
+    return net
+
+
+@pytest.fixture(scope="module")
+def episodes(model):
+    random.seed(3)
+    env = make_env(ENV_ARGS)
+    play = Generator(env, {"observation": True, "gamma": 1.0,
+                           "compress_steps": 4, "episode_compress": False})
+    job = {"player": [0], "model_id": {0: 0}}
+    return [play.generate({0: model}, job) for _ in range(6)]
+
+
+def _tokens(seed=1, batch=2):
+    return jax.random.randint(jax.random.PRNGKey(seed),
+                              (batch, TINY.sequence_length), 0, TINY.vocab)
+
+
+def _logits(out):
+    return out["policy"].features @ out["policy"].kernel
+
+
+# -- the net against the plain reference ----------------------------------
+
+def test_logits_and_value_equal_the_plain_reference(model, tiny_geometry):
+    tokens = _tokens()
+    out = model.module.apply({"params": model.params}, tokens, None)
+    ref = trinity_net.forward(model.params, tokens)
+    np.testing.assert_allclose(_logits(out), ref["policy"], atol=2e-6)
+    np.testing.assert_allclose(out["value"], ref["value"], atol=2e-6)
+    assert out["expert_load"].shape == (2, TINY.experts_held)
+
+
+def _batch(episodes):
+    columns = [trinity_training.episode_columns(ep) for ep in episodes]
+    return trinity_training.gather(
+        columns, [0, 1, 2, 3], [0] * 4, [0] * 4, 32, 0, True)
+
+
+def test_loss_and_every_gradient_leaf_equal_the_plain_reference(
+        model, episodes, tiny_geometry):
+    batch = _batch(episodes)
+    cfg = losses.LossConfig.from_config(TRAIN)
+    apply_fn = make_apply_fn(model, "float32")
+
+    def program(params):
+        device = dict(jax.tree.map(jnp.asarray, batch),
+                      action_mask=jnp.zeros((4, 32, 1, 0)))
+        out, _ = losses.compute_loss(apply_fn, params, device,
+                                     losses.SEQUENCE, cfg)
+        return out["total"], out
+
+    def reference(params):
+        return sum(trinity_training.loss(
+            trinity_net, params, jax.tree.map(lambda a: a[b:b + 1], batch),
+            TRAIN)[0] for b in range(4))
+
+    (total, parts), grads = jax.value_and_grad(program, has_aux=True)(
+        model.params)
+    ref_total, ref_grads = jax.value_and_grad(reference)(model.params)
+    np.testing.assert_allclose(total, ref_total, rtol=1e-5)
+    assert 0 < float(parts["window_fill"]) < 1
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, ours), theirs in zip(flat, jax.tree.leaves(ref_grads)):
+        assert float(jnp.abs(theirs).max()) > 0, path
+        np.testing.assert_allclose(
+            ours, theirs, rtol=2e-4, atol=2e-5 * float(jnp.abs(theirs).max()),
+            err_msg=jax.tree_util.keystr(path))
+
+
+def test_the_one_sequence_loss_is_the_general_reference_loss(
+        model, episodes, tiny_geometry):
+    """``trinity_training.loss`` (one sequence, the targets a scan) is
+    ``training.loss`` (any batch, the targets a Python loop) on the
+    same sequence: total, parts and gradient."""
+    class OneSequence:
+        RECURRENT = False
+
+        @staticmethod
+        def forward(params, obs, hidden, lowp):
+            return trinity_net.sequence(params, obs, lowp)
+
+    row = jax.tree.map(lambda a: a[1:2], _batch(episodes))
+
+    def general(params):
+        return ref_training.loss(OneSequence, params, dict(
+            row, action_mask=jnp.zeros((1, 1, 1, 1))), TRAIN)
+
+    def ours(params):
+        return trinity_training.loss(trinity_net, params, row, TRAIN)
+
+    (want, want_parts), want_grad = jax.value_and_grad(
+        general, has_aux=True)(model.params)
+    (got, got_parts), got_grad = jax.value_and_grad(
+        ours, has_aux=True)(model.params)
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    assert set(got_parts) == set(want_parts) == {"p", "v", "ent"}
+    for key in want_parts:
+        np.testing.assert_allclose(got_parts[key], want_parts[key],
+                                   rtol=1e-6)
+    for a, b in zip(jax.tree.leaves(got_grad), jax.tree.leaves(want_grad)):
+        np.testing.assert_allclose(a, b, rtol=1e-5,
+                                   atol=1e-6 * float(jnp.abs(b).max()))
+
+
+def test_the_shares_of_all_chips_add_up_to_the_uncut_layer(tiny_geometry):
+    """Every chip's held experts' part, the shared expert counted once,
+    is what the uncut reference gives for the whole layer."""
+    shares = TINY.experts // TINY.experts_held
+    whole = sn.SparseExperts(TINY._replace(experts_held=TINY.experts))
+    m = jax.random.normal(jax.random.PRNGKey(0), (2, 16, TINY.hidden))
+    shapes = jax.eval_shape(
+        lambda: whole.init(jax.random.PRNGKey(0), m))["params"]
+    params = weights.make_params(shapes, 11, (), ["experts"], ["router"])
+    uncut = trinity_net.experts(
+        m.reshape(-1, TINY.hidden), params, None, trinity_net.GEOMETRY)
+    shared = trinity_net.swiglu(
+        m.reshape(-1, TINY.hidden), *(params["shared"][k]["kernel"]
+                                      for k in ("w1", "w3", "w2")), None)
+    total, loads = 0.0, []
+    for share in range(shares):
+        first = share * TINY.experts_held
+        held = dict(params, experts=jax.tree.map(
+            lambda k: k[first:first + TINY.experts_held],
+            params["experts"]))
+        y, load = sn.SparseExperts(
+            TINY._replace(first_expert=first)).apply({"params": held}, m)
+        total = total + y.reshape(-1, TINY.hidden) - shared
+        loads.append(load)
+    np.testing.assert_allclose(total + shared, uncut, atol=5e-6)
+    # every pick fell on some chip's expert
+    assert int(sum(l.sum() for l in loads)) == 32 * TINY.experts_per_token
+
+
+def test_padding_takes_no_expert_and_moves_no_real_position(model):
+    """A window's positions past its episode's end go in as -1: they
+    are routed nowhere, and what the real positions give is what it
+    was (causal: nothing real looks at them)."""
+    tokens = _tokens(seed=9)
+    real = 20
+    padded = tokens.at[:, real:].set(-1)
+    whole = model.module.apply({"params": model.params}, tokens, None)
+    cut = model.module.apply({"params": model.params}, padded, None)
+    np.testing.assert_allclose(_logits(cut)[:, :real],
+                               _logits(whole)[:, :real], atol=2e-6)
+    np.testing.assert_allclose(cut["value"][:, :real],
+                               whole["value"][:, :real], atol=2e-6)
+    assert float(cut["expert_picks"]) == 2 * real * TINY.experts_per_token
+    assert float(whole["expert_picks"]) == tokens.size * TINY.experts_per_token
+    assert (cut["expert_load"] <= whole["expert_load"]).all()
+    assert int(cut["expert_load"].sum()) < int(whole["expert_load"].sum())
+    head = model.module.apply({"params": model.params}, tokens[:, :real],
+                              None)
+    np.testing.assert_array_equal(cut["expert_load"], head["expert_load"])
+
+
+# -- the actor's side -------------------------------------------------------
+
+def test_the_cached_step_equals_the_sequence_pass_position_by_position(
+        model):
+    """...so the behaviour probability an actor records is the one the
+    learner recomputes."""
+    tokens = _tokens(seed=5)
+    out = model.module.apply({"params": model.params}, tokens, None)
+    hidden = model.init_hidden([2])
+    stepped = []
+    for t in range(TINY.sequence_length):
+        o = model.module.apply({"params": model.params}, tokens[:, t], hidden)
+        hidden = o["hidden"]
+        stepped.append(o["policy"])
+        np.testing.assert_allclose(o["value"], out["value"][:, t], atol=2e-6)
+    stepped = jnp.stack(stepped, 1)
+    np.testing.assert_allclose(stepped, _logits(out), atol=2e-6)
+    actions = _tokens(seed=6)[..., None]
+    recorded = jnp.take_along_axis(jax.nn.softmax(stepped), actions, -1)
+    learner, _ = losses.policy_terms(out["policy"], actions)
+    np.testing.assert_allclose(jnp.exp(learner), recorded, rtol=1e-5)
+    assert int(hidden["pos"][0]) == TINY.sequence_length
+
+
+@pytest.mark.parametrize("kind,moved", [(sn.SLIDING, False), (sn.FULL, True)])
+def test_a_key_beyond_the_window_moves_only_a_full_layer(kind, moved):
+    attention = sn.Attention(TINY, kind)
+    a = jax.random.normal(jax.random.PRNGKey(2), (1, 32, TINY.hidden))
+    params = attention.init(jax.random.PRNGKey(3), a)
+    far = TINY.window + 3           # position 0 is out of its window
+    base = attention.apply(params, a)[0]
+    other = attention.apply(params, a.at[:, 0].add(1.0))[0]
+    changed = float(jnp.abs(base - other)[0, far:].max())
+    assert (changed > 1e-4) == moved, changed
+    # inside the window either kind sees it
+    assert float(jnp.abs(base - other)[0, 1:TINY.window].max()) > 1e-4
+
+
+# -- wire, ring and gather without a mask -----------------------------------
+
+def test_an_all_legal_episode_crosses_wire_ring_and_gather_without_a_mask(
+        episodes):
+    from handyrl_tpu.batch import load_block
+
+    moment = load_block(episodes[0]["moment"][-1])[-1]
+    assert moment["action_mask"][0].shape == (0,)      # nothing listed
+    replay = DeviceReplay(dict(TRAIN), 8, 64 << 20)
+    replay.offer(episodes)
+    replay.ingest()
+    # the token rides the packed int32 channel: no channel of its own,
+    # and no word of mask beyond the two seat bits
+    assert replay.buffers["obs"] is None
+    assert replay.buffers["steps"].shape[1] == 5 + 1 + 1 + 1
+    slots = np.arange(len(episodes), dtype=np.int32)
+    zeros = np.zeros(len(episodes), np.int32)
+    got = jax.device_get(replay._sample_fn(
+        replay.buffers, jnp.asarray(slots), jnp.asarray(zeros),
+        jnp.asarray(zeros)))
+    random.seed(0)
+    want = make_batch(
+        [dict(ep, start=0, end=ep["steps"], base=0, train_start=0,
+              total=ep["steps"]) for ep in episodes], TRAIN)
+    assert got["action_mask"].shape == want["action_mask"].shape == (
+        len(episodes), 32, 1, 0)
+    assert set(got) == set(want)
+    for key in want:
+        np.testing.assert_array_equal(got[key], want[key], err_msg=key)
+    assert got["observation"].dtype == np.int32
+    # context is read, not acted on: no turn where the prompt is
+    assert (want["turn_mask"].sum(1) < want["observation_mask"].sum(1)).all()
+
+
+def test_a_sequence_window_bounds_the_append_programs(monkeypatch):
+    """One append program per ``_RUN_ROUND`` rows up to ``_MAX_RUN``
+    slots: 4 at a 4,096-step window, where the board games' geometry
+    would make 128; theirs is what it was."""
+    import handyrl_tpu.staging as staging
+
+    assert _run_geometry(8) == _run_geometry(12) == (256, 8)
+    assert _run_geometry(4096) == (4096, 4)
+    monkeypatch.setattr(staging, "_RUN_ROUND", staging._RUN_ROUND)
+    monkeypatch.setattr(staging, "_MAX_RUN", staging._MAX_RUN)
+    replay = DeviceReplay(dict(TRAIN, forward_steps=4096), 8, 64 << 20)
+    assert (staging._RUN_ROUND, staging._MAX_RUN) == (4096, 4)
+    assert staging._MAX_RUN * replay.t_max // staging._RUN_ROUND == 4
+
+
+# -- the head in chunks -------------------------------------------------------
+
+def test_the_chunked_head_equals_the_unchunked(monkeypatch):
+    monkeypatch.setattr(losses, "POLICY_CHUNK", 8)
+    feats = jax.random.normal(jax.random.PRNGKey(0), (3, 7, 1, 16))
+    kernel = jax.random.normal(jax.random.PRNGKey(1), (16, 50))
+    actions = jax.random.randint(jax.random.PRNGKey(2), (3, 7, 1, 1), 0, 50)
+    logits = feats @ kernel
+
+    def chunked(feats, kernel):
+        selected, entropy = losses.policy_terms(
+            losses.FactoredPolicy(feats, kernel), actions)
+        return selected.sum() + 2 * entropy.sum(), (selected, entropy)
+
+    def whole(feats, kernel):
+        selected, _ = losses.policy_terms(feats @ kernel, actions)
+        entropy = losses._masked_entropy(feats @ kernel)
+        return selected.sum() + 2 * entropy.sum(), (selected, entropy)
+
+    (_, ours), g_ours = jax.value_and_grad(chunked, (0, 1), has_aux=True)(
+        feats, kernel)
+    (_, theirs), g_theirs = jax.value_and_grad(whole, (0, 1), has_aux=True)(
+        feats, kernel)
+    assert ours[0].shape == (3, 7, 1, 1) and ours[1].shape == (3, 7, 1)
+    for a, b in zip(ours + g_ours, theirs + g_theirs):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+    assert logits.shape[-1] == 50
+
+
+# -- what is refused at build ---------------------------------------------------
+
+def _learner_args(**train):
+    return {
+        "env_args": dict(ENV_ARGS),
+        "train_args": dict(
+            TRAIN, update_episodes=12, minimum_episodes=8,
+            maximum_episodes=64, epochs=1, num_batchers=1, eval_rate=0.0,
+            worker={"num_parallel": 2}, seed=2, batch_size=2, **train),
+        "worker_args": {"num_parallel": 2, "server_address": ""},
+    }
+
+
+@pytest.mark.parametrize("train,sentence", [
+    ({"burn_in_steps": 2}, "burn_in_steps must be 0"),
+    ({"forward_steps": 16}, "must hold the whole sequence"),
+    ({"mesh": {"dp": 2}}, "no axis to be divided over"),
+])
+def test_what_a_sequence_net_is_refused_with(train, sentence, tmp_path,
+                                             monkeypatch):
+    from handyrl_tpu.learner import Trainer
+
+    monkeypatch.chdir(tmp_path)
+    args = _learner_args(**train)["train_args"]
+    env = make_env(ENV_ARGS)
+    net = TPUModel(env.net())
+    net.init_params(env.observation(0), seed=0)
+    with pytest.raises(ValueError, match=sentence):
+        Trainer(args, net)
+
+
+# -- the normal path ---------------------------------------------------------------
+
+def test_one_epoch_of_the_training_path_ends_with_a_saved_model(
+        tmp_path, monkeypatch):
+    """``main.py --train``'s path (``Learner(args).run()``) with the
+    tiny preset: two actor processes play the token task through the
+    one-token step and its cache, the learner trains whole sequences
+    through the ring and the fused replay step."""
+    from handyrl_tpu.learner import Learner
+
+    monkeypatch.chdir(tmp_path)
+    learner = Learner(_learner_args(metrics_path="metrics.jsonl"))
+    assert learner.trainer._replay_step is not None
+    assert learner.trainer.train_mesh is None
+    learner.run()
+    assert learner.trainer.failure is None
+    assert learner.model_epoch == 1 and learner.trainer.steps > 0
+    assert os.path.exists(tmp_path / "models" / "1.ckpt")
+    replay = learner.trainer.device_replay
+    assert replay.buffers["obs"] is None and replay.num_actions == 0

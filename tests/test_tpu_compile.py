@@ -7,7 +7,8 @@ whether the main path's programs are accepted at the flagship shapes
 (HungryGeese / GeeseNet 32f x 12, batch 256 x 8 steps, bf16 compute,
 uint8 wire, the ring at the capacity the learner picks under the
 default ``device_replay_mb``; the fused step at Geister's recurrent
-geometry too), whether they fit the chip's 16 GB, whether the ring's
+geometry and at the sparse-expert sequence net's published widths
+too), whether they fit the chip's 16 GB, whether the ring's
 own byte estimate matches what the compiler lays out — the estimate
 sizes the ring, and tile padding is exactly what it exists to get
 right (staging.py docstring) — and whether the step's gather reads the
@@ -140,6 +141,45 @@ def geister():
         128, 202), temp_share=0.25)
 
 
+@pytest.fixture(scope="module")
+def sequence():
+    """``trinity_mini_ep8``: the sparse-expert sequence net at published
+    widths, one chip's share of eight; windows of 4,096 tokens, batch 2,
+    every action legal (no mask in the ring's row, the token riding the
+    packed channel), the ring at the configuration's 1,024 slots.
+    Nothing is played and no weight is made: the shapes alone."""
+    import jax
+
+    from handyrl_tpu.environment import make_env
+    from handyrl_tpu.models import TPUModel
+    from handyrl_tpu.ops.losses import LossConfig
+    from handyrl_tpu.ops.update import DEFAULT_LR, make_optimizer
+    from handyrl_tpu.staging import DeviceReplay
+
+    steps = 4096
+    cfg = {"turn_based_training": False, "observation": True,
+           "forward_steps": steps, "burn_in_steps": 0, "gamma": 1.0,
+           "lambda": 0.95, "policy_target": "TD", "value_target": "TD",
+           "entropy_regularization": 0.01,
+           "entropy_regularization_decay": 0.1,
+           "compute_dtype": "bfloat16"}
+    env = make_env({"env": "TokenTask", "net": "trinity_mini_ep8"})
+    model = TPUModel(env.net())
+    params = jax.eval_shape(
+        lambda: model.module.init(
+            jax.random.PRNGKey(0), np.zeros((1,), np.int32),
+            model.init_hidden([1]))["params"])
+    replay = DeviceReplay(cfg, 1024, 512 << 20)
+    replay.t_max = steps
+    col = {"players": [0], "obs": np.zeros((steps, 1), np.int32),
+           "amask": np.zeros((steps, 1, 0), np.float32)}
+    optimizer = make_optimizer(DEFAULT_LR * 2 * steps)
+    return {"model": model, "replay": replay, "params": params,
+            "buffers": replay._plan_buffers(col), "optimizer": optimizer,
+            "opt_state": jax.eval_shape(optimizer.init, params),
+            "loss_cfg": LossConfig.from_config(cfg), "batch": 2}
+
+
 def _on(tree, sharding):
     import jax
 
@@ -164,8 +204,7 @@ def _compile_replay_step(v5e, f):
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from handyrl_tpu.staging import (
-        _RUN_ROUND, epoch_sums, make_replay_update_step)
+    from handyrl_tpu.staging import epoch_sums, make_replay_update_step
 
     chip = SingleDeviceSharding(v5e[0])
     replay = f["replay"]
@@ -192,7 +231,7 @@ def _compile_replay_step(v5e, f):
     # ring, but for the compiler's one asynchronous prefetch across
     # calls (copy-start / copy-done) of a channel that fits its fast
     # memory whole
-    rows = replay.capacity * replay.t_max + _RUN_ROUND
+    rows = replay.capacity * replay.t_max + replay.run_round
     ring_long = {}
     for line in compiled.as_text().splitlines():
         m = _DEFINED.match(line)
@@ -209,8 +248,6 @@ def _compile_ring_append(v5e, f):
     import jax
     from jax.sharding import SingleDeviceSharding
 
-    from handyrl_tpu.staging import _MAX_RUN
-
     replay = f["replay"]
     replay._build_jits()
     append = replay._append_fn
@@ -221,7 +258,7 @@ def _compile_ring_append(v5e, f):
     replay._append_fn = lambda buffers, *run: (seen.update(run=run)
                                                or buffers)
     try:
-        replay._append_run([f["col"]] * _MAX_RUN)
+        replay._append_run([f["col"]] * replay.max_run)
     finally:
         replay._append_fn = append
     chip = SingleDeviceSharding(v5e[0])
@@ -308,12 +345,51 @@ def _compile_dp4_step(v5e, f):
     assert _footprint(compiled.memory_analysis()) < HBM_BYTES
 
 
+def _compile_sequence_step(v5e, f):
+    """The fused step over whole 4,096-token windows: 16 B a parameter
+    of train state beside the step's temporaries must fit the chip, and
+    the policy head's logits must never exist whole."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from handyrl_tpu.staging import epoch_sums, make_replay_update_step
+
+    chip = SingleDeviceSharding(v5e[0])
+    replay = f["replay"]
+    assert f["buffers"]["obs"] is None          # the token rides `steps`
+    assert f["buffers"]["steps"].shape == (1024 * 4096 + 4096, 8)
+    assert (replay.run_round, replay.max_run) == (4096, 4)
+    step = make_replay_update_step(
+        replay, f["model"], f["loss_cfg"], f["optimizer"],
+        "bfloat16", batch_size=f["batch"])
+    compiled = step.lower(
+        *_on((f["params"], f["opt_state"], f["buffers"],
+              (jax.ShapeDtypeStruct((3,), jnp.int32), epoch_sums(replay))),
+             chip)).compile()
+    mem = compiled.memory_analysis()
+    n_params = sum(int(np.prod(leaf.shape))
+                   for leaf in jax.tree.leaves(f["params"]))
+    assert 700e6 < n_params < 710e6
+    # parameters and Adam's moments in float32, the ring beside them
+    assert mem.argument_size_in_bytes >= 12 * n_params
+    # 13.6 GB when this was written (arguments 8.6, temporaries 5.0)
+    assert _footprint(mem) < 0.9 * HBM_BYTES, _footprint(mem)
+    positions, vocab = 2 * 4096, 25024
+    whole = [m.group(1) for m in map(_DEFINED.match,
+                                     compiled.as_text().splitlines())
+             if m and int(m.group(2)) == positions
+             and f",{vocab}]" in m.group(0)]
+    assert not whole, whole
+
+
 @pytest.mark.parametrize("program,geometry", [
     (_compile_replay_step, "flagship"), (_compile_replay_step, "geister"),
     (_compile_ring_append, "flagship"),
     (_compile_service_forward, "flagship"), (_compile_dp4_step, "flagship"),
+    (_compile_sequence_step, "sequence"),
 ], ids=["replay_step", "replay_step_geister", "ring_append",
-        "service_forward", "dp4_step"])
+        "service_forward", "dp4_step", "replay_step_sequence"])
 def test_main_path_compiles_for_a_described_v5e(
         program, geometry, v5e, request):
     program(v5e, request.getfixturevalue(geometry))
