@@ -1138,15 +1138,17 @@ class Trainer:
         on the live params and ring under a private profiler session,
         reduced by ``telemetry.devtrace.step_phases`` to ``{steps,
         step_ms, phases: {gather, forward, targets, backward, optimizer,
-        unscoped}, scopes, counters}`` (ms per step; ``scopes``: the
-        net's own named scopes, forward and transpose together;
-        ``counters``: what the profiled steps counted beside their
-        losses, ``ops.losses.SEQUENCE_COUNTERS``; both empty for a net
-        that has none); the trace is deleted, the answer
-        cached.  Only once the trainer thread has ended, or from it: the
-        step donates the state that thread owns.  Never raises: a
-        failure (no fused step, no TPU plane in the trace, a profiler
-        session already open) prints one line and returns None."""
+        unscoped}, scopes, kernel_ms, counters}`` (ms per step;
+        ``scopes``: the net's own named scopes, forward and transpose
+        together; ``kernel_ms``: what of them ran in hand-written
+        kernels; ``counters``: what the profiled steps counted beside
+        their losses, ``ops.losses.SEQUENCE_COUNTERS``; all three empty
+        for a net that has none); the phases are printed, the trace is
+        deleted, the answer cached.  Only once the trainer thread has
+        ended, or from it: the step donates the state that thread owns.
+        Never raises: a failure (no fused step, no TPU plane in the
+        trace, a profiler session already open) prints one line and
+        returns None."""
         if self._step_profile is None:
             try:
                 self._step_profile = self._capture_step_profile(steps)
@@ -1188,6 +1190,7 @@ class Trainer:
         finally:
             shutil.rmtree(trace_dir, ignore_errors=True)
         profile = devtrace.step_phases(trace)
+        print("step phases = %s" % devtrace.format_phases(profile))
         counted = jax.device_get(counted)
         # the fullest expert of any profiled step; the others as means
         profile["counters"] = {

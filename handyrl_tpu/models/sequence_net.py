@@ -22,10 +22,14 @@ window's padding past its episode's end (token -1) takes no expert.
 Two call shapes, one set of parameters:
 
   * ``module(tokens (B, T), None)`` -- the learner's pass over whole
-    windows.  Attention runs in query blocks that skip what causality
-    and the window hide; layers and blocks are rematerialised; the policy comes back FACTORED (``ops.losses.
-    FactoredPolicy``: trunk features and the head's kernel), so that the
-    ``(B * T, vocab)`` logits never exist whole.
+    windows.  Attention skips what causality and the window hide: on a
+    TPU, at lane-wide shapes, as ONE fused kernel a layer whose scores
+    never leave the chip's fast memory (``fused_attention``); everywhere
+    else in query blocks of plain XLA (``blocked_attention``, the
+    statement the kernel is held to).  Layers are rematerialised (all
+    but the kernel's output); the policy comes back FACTORED
+    (``ops.losses.FactoredPolicy``: trunk features and the head's
+    kernel), so that the ``(B * T, vocab)`` logits never exist whole.
   * ``module(token (N,), hidden)`` -- the actor's one-token step through
     a key-value cache carried as the seat's ``hidden`` (``init_hidden``):
     dense logits for that position, the cache advanced by one.
@@ -35,7 +39,7 @@ the module declares itself a sequence net: ``TPUModel.is_sequence``.
 """
 
 import math
-from functools import partial
+from functools import lru_cache, partial
 from typing import NamedTuple, Tuple
 
 import jax
@@ -68,7 +72,7 @@ class Sizes(NamedTuple):
     sequence_length: int
     rope_theta: float = 10000.0
     eps: float = 1e-5
-    attention_block: int = 512     # queries attended together
+    attention_block: int = 512     # queries attended together (XLA path)
 
 
 PRESETS = {
@@ -178,6 +182,91 @@ def blocked_attention(q, k, v, window, block):
     return jnp.concatenate(out, axis=1)
 
 
+LANES = 128                     # a TPU vector register's minor axis
+# what a rematerialised layer keeps of its fused attention going
+# forward: the kernel's output and one log-sum-exp a query (68 MB a
+# layer at the published widths), so that the backward pass does not
+# run the forward kernel again
+KEPT = "fused_attention_out"
+# the kernel's blocks, read on the chip at 4,096 positions, 4 x 8 heads
+# of 128 (``PERF.md`` section 6, PR 34)
+FUSED_BLOCK = 1024              # queries, and keys fetched, a grid step
+FUSED_COMPUTE = 512             # keys a product inside one
+
+
+@lru_cache(maxsize=None)
+def _fused_kernel(T, window, groups, block, compute, interpret):
+    """The library's fused attention (splash attention, multi-query
+    form) for ONE key-value head's ``groups`` query heads over ``T``
+    positions under the layer's own rule, ``_visible``: causal, and
+    ``window - 1`` keys to the left of a query in a window layer; dq,
+    dk and dv come from ONE backward kernel."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as kernel, splash_attention_mask as masks)
+
+    mask = (masks.LocalMask((T, T), (window - 1, 0), 0) if window
+            else masks.CausalMask((T, T)))
+    blocks = kernel.BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=compute,
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=compute,
+        use_fused_bwd_kernel=True)
+    # the kernel's tables of which blocks to visit become arrays as it
+    # is built: concrete ones, whatever trace asked first, since every
+    # later trace is handed the same kernel
+    with jax.ensure_compile_time_eval():
+        return kernel.make_splash_mqa_single_device(
+            masks.MultiHeadMask([mask] * groups), block_sizes=blocks,
+            residual_checkpoint_name=KEPT, interpret=interpret)
+
+
+def _fused_blocks(T, D, block=None):
+    """The kernel's ``(block, keys a product)`` over ``T`` positions of
+    ``D``-wide heads (``block``: the module's constant unless given), or
+    None where the shapes are not whole lanes and whole blocks."""
+    block = block or min(FUSED_BLOCK, T)
+    compute = min(FUSED_COMPUTE, block)
+    if T % block or block % compute or compute % LANES or D % LANES:
+        return None
+    return block, compute
+
+
+def fused_attention(q, k, v, window, block=None, interpret=False):
+    """The same attention as ``blocked_attention`` over the same
+    ``q (B, T, KV, G, D)`` and ``k, v (B, T, KV, D)``, as one kernel a
+    layer: online softmax in float32 in the chip's fast memory, blocks
+    of ``block`` x ``block`` that causality or the window hide whole
+    skipped, the probabilities meeting ``v`` in ``v``'s dtype, and a
+    backward kernel of its own that makes the scores again from q, k
+    and one log-sum-exp a query.  No array of score size is written in
+    either direction.  ``T`` is a multiple of ``block`` (the module's
+    constant unless given), ``block`` and ``D`` multiples of 128;
+    ``interpret`` runs the kernel's body as plain JAX (tier-1, on the
+    CPU)."""
+    B, T, KV, G, D = q.shape
+    attend = _fused_kernel(T, window, G, *_fused_blocks(T, D, block),
+                           interpret)
+    # the kernel takes its scores unscaled
+    q = (q * (1.0 / math.sqrt(D))).astype(q.dtype)
+    o = jax.vmap(jax.vmap(attend))(          # over batch and kv head
+        q.transpose(0, 2, 3, 1, 4), k.transpose(0, 2, 1, 3),
+        v.transpose(0, 2, 1, 3))             # (B, KV, G, T, D)
+    return o.transpose(0, 3, 1, 2, 4)
+
+
+def window_attention(q, k, v, window, block):
+    """Attention over a whole window by the path the program can take
+    where it is lowered: the fused kernel on a TPU when the shapes are
+    whole lanes, query blocks of ``block`` in plain XLA everywhere else
+    (a CPU, a head narrower than a lane).  Chosen per lowering
+    platform, so a program compiled for a described chip from a CPU
+    process takes the chip's path."""
+    plain = partial(blocked_attention, window=window, block=block)
+    if _fused_blocks(q.shape[1], q.shape[-1]) is None:
+        return plain(q, k, v)
+    return lax.platform_dependent(
+        q, k, v, default=plain, tpu=partial(fused_attention, window=window))
+
+
 class Attention(nn.Module):
     sizes: Sizes
     kind: str
@@ -208,7 +297,7 @@ class Attention(nn.Module):
                     q = rotate(q, positions, z.rope_theta)
                     k = rotate(k, positions, z.rope_theta)
                 q = q.reshape(B, T, z.kv_heads, groups, z.head_dim)
-                o = blocked_attention(q, k, v, window, z.attention_block)
+                o = window_attention(q, k, v, window, z.attention_block)
                 o = o.reshape(B, T, z.heads * z.head_dim)
             else:
                 # one token a row, through the cache: (N, ...)
@@ -380,7 +469,9 @@ class SequencePolicyNet(nn.Module):
         # mup_enabled: the embedding is scaled by sqrt(hidden_size)
         h = table[jnp.maximum(tokens, 0)] * jnp.asarray(
             math.sqrt(z.hidden), table.dtype)
-        layer = nn.remat(Layer) if whole else Layer
+        layer = nn.remat(
+            Layer, policy=jax.checkpoint_policies.save_only_these_names(
+                KEPT)) if whole else Layer
         pos = None if whole else hidden["pos"]
         keys, values, counts = [], [], []
         for i, kind in enumerate(z.layer_types):

@@ -57,6 +57,7 @@ _NET_SCOPE = re.compile(r"(?:^|[/(])(net\.[\w.]+)(?=[/)]|$)")
 _INSTRUCTION = re.compile(r"\s*(?:ROOT )?%?([\w.\-]+)(?: = |$)")
 _DEFINITION = re.compile(r"\s*(?:ROOT )?%?([\w.\-]+) = ")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
+KERNEL_SUFFIX = "pallas_call"    # the last part of a kernel's op_name
 _NS_SLACK = 2.0             # event times are rounded to whole ns
 
 
@@ -76,13 +77,21 @@ def instruction(text):
 
 def op_names(hlo_text):
     """Compiled HLO text -> ``{instruction name: op_name}`` for every
-    instruction of the text ("" where the compiler gave it none)."""
-    table = {}
+    instruction of the text ("" where the compiler gave it none).  An
+    instruction's text runs from the line that defines it to the next
+    definition: a kernel's custom call is written over three lines (its
+    ``kernel_metadata`` holds newlines) and bears its ``op_name`` on
+    the last."""
+    table, name = {}, None
     for line in (hlo_text or "").splitlines():
         m = _DEFINITION.match(line)
         if m is not None:
+            name = m.group(1)
+            table[name] = ""
+        if name is not None and not table[name]:
             scope = _OP_NAME.search(line)
-            table[m.group(1)] = scope.group(1) if scope else ""
+            if scope is not None:
+                table[name] = scope.group(1)
     return table
 
 
@@ -185,7 +194,10 @@ def step_phases(trace, module=None):
     whose instruction the HLO text does not hold (a text of another
     compile).  ``scopes`` tells the same time apart by the net's own
     scopes (``net_scope_of``; ms a step, an op under ``transpose(``
-    counted with its forward; empty for a net that names none).  A
+    counted with its forward; empty for a net that names none), and
+    ``kernel_ms`` what of it ran in hand-written kernels (ops whose
+    ``op_name`` ends in ``pallas_call``; by the same scopes, ``other``
+    outside them; empty for a step that runs none).  A
     text that names no scope of the step at all (none
     was kept, or the executable came from a compile-cache entry that a
     build without the scopes wrote) is an error, not a step that is
@@ -203,7 +215,7 @@ def step_phases(trace, module=None):
             f"the text of {module} names no scope of the step (none "
             "kept, or a compile-cache entry of a build without them)")
     total = dict.fromkeys(PHASES, 0.0)
-    scopes = {}
+    scopes, kernels = {}, {}
     unmatched, covered, at = 0.0, 0.0, 0
     for name, start, dur in _top_level(_line(plane, OPS_LINE)):
         while at < len(steps) and steps[at][1] <= start:
@@ -215,18 +227,24 @@ def step_phases(trace, module=None):
         covered += dur
         if name not in names:
             unmatched += dur
-        phase = phase_of(names.get(name, ""))
+        op_name = names.get(name, "")
+        phase = phase_of(op_name)
         if phase is not None:
             total[phase] += dur
-        part = net_scope_of(names.get(name, ""))
+        part = net_scope_of(op_name)
         if part is not None:
             scopes[part] = scopes.get(part, 0.0) + dur
+        if op_name.endswith(KERNEL_SUFFIX):
+            part = part or "other"
+            kernels[part] = kernels.get(part, 0.0) + dur
     step_ns = sum(b - a for a, b in steps)
     total["unscoped"] = step_ns - sum(total.values())
     per_step = 1e-6 / len(steps)
     return {"steps": len(steps), "step_ms": step_ns * per_step,
             "phases": {k: v * per_step for k, v in total.items()},
             "scopes": {k: v * per_step for k, v in sorted(scopes.items())},
+            "kernel_ms": {k: v * per_step
+                          for k, v in sorted(kernels.items())},
             "op_gap_ms": (step_ns - covered) * per_step,
             "unmatched_ms": unmatched * per_step}
 
@@ -298,8 +316,10 @@ def idle_gaps(trace, top=5):
 
 
 def format_phases(step):
+    kernels = step.get("kernel_ms") or {}
     return ("steps:%d step:%.3fms " % (step["steps"], step["step_ms"])
-            + " ".join("%s:%.3f" % (k, step["phases"][k]) for k in PHASES))
+            + " ".join("%s:%.3f" % (k, step["phases"][k]) for k in PHASES)
+            + "".join(" kernel[%s]:%.3f" % kv for kv in kernels.items()))
 
 
 def format_gaps(idle):

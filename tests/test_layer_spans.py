@@ -565,6 +565,86 @@ def test_the_text_is_read_by_instruction_name():
          "phases": dict.fromkeys(devtrace.PHASES, 0.15)})
 
 
+# a kernel's custom call as the TPU compiler writes it (jax 0.9, libtpu
+# 0.0.34; operands and the body's bytes cut): its ``kernel_metadata``
+# holds newlines, so ONE instruction lies over three lines of the text,
+# its ``op_name`` on the last; the tuple's elements repeat the attribute
+KERNEL_HLO = """HloModule jit_step, entry_computation_layout={()}
+
+ENTRY %main () -> f32[8] {
+  %fusion.1 = bf16[2,4096,2048]{2,1,0} fusion(%a), kind=kOutput, metadata={op_name="jit(step)/jvp(net.forward)/checkpoint/layer_1/attn/net.attention.window/dot_general"}
+  %splash_mqa_fwd_residuals.2 = (f32[2,4,512,128]{3,2,1,0:T(8,128)}, bf16[2,4,8,4096,128]{4,3,2,1,0:T(8,128)(2,1)}) custom-call(%copy-done.2, %fusion.1), custom_call_target="tpu_custom_call", operand_layout_constraints={s8[1,8,5]{2,1,0}}, frontend_attributes={kernel_metadata={
+"xprof_metadata":"{\\"block_q\\": 512, \\"block_kv\\": 512, \\"q_layout\\": 1}"
+}}, metadata={op_name="jit(step)/jvp(net.forward)/checkpoint/layer_1/attn/net.attention.window/cond/branch_0_fun/vmap(vmap(jit(_splash_attention)))/splash_mqa_fwd_residuals/splash_mqa_fwd_residuals/pallas_call" stack_frame_id=8}, backend_config={"custom_call_config":{"body":"TUzvUgFNTElS"}}
+  %get-tuple-element.3 = bf16[2,4,8,4096,128]{4,3,2,1,0:T(8,128)(2,1)} get-tuple-element(%splash_mqa_fwd_residuals.2), index=1, frontend_attributes={kernel_metadata={
+"xprof_metadata":"{\\"block_q\\": 512, \\"block_kv\\": 512, \\"q_layout\\": 1}"
+}}, metadata={op_name="jit(step)/jvp(net.forward)/checkpoint/layer_1/attn/net.attention.window/cond/branch_0_fun/vmap(vmap(jit(_splash_attention)))/splash_mqa_fwd_residuals/splash_mqa_fwd_residuals/pallas_call" stack_frame_id=8}
+  %copy.4 = f32[8] copy(%w)
+  %splash_mqa_dq_no_residuals.5 = (f32[2,4,512,128]{3,2,1,0:T(8,128)}, bf16[2,4,8,4096,128]{4,3,2,1,0:T(8,128)(2,1)}) custom-call(%constant.8), custom_call_target="tpu_custom_call", frontend_attributes={kernel_metadata={
+"xprof_metadata":"{\\"block_q_dq\\": 512, \\"block_kv_dq\\": 512}"
+}}, metadata={op_name="jit(step)/transpose(jvp(net.forward))/checkpoint/layer_1/attn/net.attention.window/cond/branch_0_fun/vmap(vmap(jit(_splash_attention)))/splash_mqa_dq_no_residuals/splash_mqa_dq_no_residuals/pallas_call" stack_frame_id=8}, backend_config={"custom_call_config":{"body":"TUzvUgFN"}}
+  %splash_mqa_dkv_no_residuals.6 = (f32[2,4,512,128]{3,2,1,0:T(8,128)}, bf16[2,4,4096,128]{3,2,1,0:T(8,128)(2,1)}) custom-call(%constant.11), custom_call_target="tpu_custom_call", frontend_attributes={kernel_metadata={
+"xprof_metadata":"{\\"block_q_dkv\\": 512, \\"block_kv_dkv\\": 512}"
+}}, metadata={op_name="jit(step)/transpose(jvp(net.forward))/checkpoint/layer_2/attn/net.attention.full/cond/branch_0_fun/vmap(vmap(jit(_splash_attention)))/splash_mqa_dkv_no_residuals/splash_mqa_dkv_no_residuals/pallas_call" stack_frame_id=8}, backend_config={"custom_call_config":{"body":"TUzvUgFN"}}
+  ROOT %fusion.7 = f32[8] fusion(%c), metadata={op_name="jit(step)/optimizer/sub"}
+}
+"""
+
+
+def test_a_kernel_written_over_three_lines_keeps_its_op_name():
+    names = devtrace.op_names(KERNEL_HLO)
+    assert set(names) == {
+        "fusion.1", "splash_mqa_fwd_residuals.2", "get-tuple-element.3",
+        "copy.4", "splash_mqa_dq_no_residuals.5",
+        "splash_mqa_dkv_no_residuals.6", "fusion.7"}
+    # an instruction that has none takes none from its neighbours
+    assert names["copy.4"] == ""
+    forward = names["splash_mqa_fwd_residuals.2"]
+    assert forward.endswith("splash_mqa_fwd_residuals/pallas_call")
+    assert names["get-tuple-element.3"] == forward
+    assert (devtrace.phase_of(forward), devtrace.net_scope_of(forward)) == (
+        "forward", "net.attention.window")
+    dq = names["splash_mqa_dq_no_residuals.5"]
+    assert (devtrace.phase_of(dq), devtrace.net_scope_of(dq)) == (
+        "backward", "net.attention.window")
+    dkv = names["splash_mqa_dkv_no_residuals.6"]
+    assert (devtrace.phase_of(dkv), devtrace.net_scope_of(dkv)) == (
+        "backward", "net.attention.full")
+    # as the profiler names the kernel's event: by that same text
+    assert devtrace.instruction(KERNEL_HLO.split("\n  ")[2]) == \
+        "splash_mqa_fwd_residuals.2"
+
+
+def test_step_phases_count_a_kernels_time_for_its_scope_and_as_kernel_ms():
+    t = 10_000.0
+    ops = [("fusion.1", t, 100.0),
+           ("splash_mqa_fwd_residuals.2", t + 100, 200.0),
+           ("copy.4", t + 300, 50.0),
+           ("splash_mqa_dq_no_residuals.5", t + 350, 300.0),
+           ("splash_mqa_dkv_no_residuals.6", t + 650, 250.0),
+           ("fusion.7", t + 900, 100.0)]
+    trace = {"planes": [{"name": "/device:TPU:0", "lines": [
+        {"name": "XLA Modules", "events": [("jit_step(1)", t, 1000.0)]},
+        {"name": "XLA Ops", "events": ops}]}],
+        "op_names": devtrace.op_names(KERNEL_HLO)}
+    out = devtrace.step_phases(trace)
+    ns = lambda d: {k: round(v * 1e6) for k, v in d.items()}  # noqa: E731
+    assert ns(out["phases"]) == {
+        "gather": 0, "forward": 300, "targets": 0, "backward": 550,
+        "optimizer": 100, "unscoped": 50}             # copy.4 alone
+    assert ns(out["scopes"]) == {"net.attention.full": 250,
+                                 "net.attention.window": 600}
+    assert ns(out["kernel_ms"]) == {"net.attention.full": 250,
+                                    "net.attention.window": 500}
+    assert out["unmatched_ms"] == 0.0
+    assert "kernel[net.attention.window]:0.001" in \
+        devtrace.format_phases(out)
+    # a step that runs no kernel reports none
+    plain = devtrace.step_phases(_plain_trace())
+    assert plain["kernel_ms"] == {}
+    assert "kernel" not in devtrace.format_phases(plain)
+
+
 # -- Trainer.step_profile -------------------------------------------------
 
 def test_step_profile_is_none_not_an_exception_without_a_tpu(

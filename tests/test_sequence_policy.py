@@ -255,6 +255,104 @@ def test_a_key_beyond_the_window_moves_only_a_full_layer(kind, moved):
     assert float(jnp.abs(base - other)[0, 1:TINY.window].max()) > 1e-4
 
 
+# -- the fused kernel against the path it replaces --------------------------
+
+# lane-wide and small: two key-value heads of two query heads each, 128
+# wide, a window of 256 in 512 positions, the kernel in blocks of 128
+FUSED = dict(B=1, T=512, KV=2, G=2, D=128, window=256, block=128)
+
+
+def _qkv(seed=4, **over):
+    z = dict(FUSED, **over)
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(kq, (z["B"], z["T"], z["KV"], z["G"], z["D"])),
+            jax.random.normal(kk, (z["B"], z["T"], z["KV"], z["D"])),
+            jax.random.normal(kv, (z["B"], z["T"], z["KV"], z["D"])))
+
+
+@pytest.mark.parametrize("window", [FUSED["window"], 0],
+                         ids=["window_layer", "full_layer"])
+def test_the_fused_kernel_equals_blocked_attention_out_and_back(window):
+    """The kernel's body run as plain JAX (``interpret``), float32:
+    output and the gradients of q, k and v against the XLA path's."""
+    q, k, v = _qkv()
+    weight = jax.random.normal(jax.random.PRNGKey(9), q.shape)
+
+    def scalar(attend):
+        return lambda q, k, v: (attend(q, k, v) * weight).sum()
+
+    def fused(q, k, v):
+        return sn.fused_attention(q, k, v, window, FUSED["block"],
+                                  interpret=True)
+
+    def plain(q, k, v):
+        return sn.blocked_attention(q, k, v, window, FUSED["block"])
+
+    np.testing.assert_allclose(fused(q, k, v), plain(q, k, v), atol=1e-5)
+    got = jax.grad(scalar(fused), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(scalar(plain), argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=1e-5)
+
+
+@pytest.mark.parametrize("distance", [
+    FUSED["window"] - 1, FUSED["window"], FUSED["window"] + 1])
+def test_the_kernels_mask_is_the_layers_own_rule_at_the_windows_edge(
+        distance):
+    """One key moved at ``distance`` from a query moves that query in a
+    window layer exactly where ``_visible`` says the key is seen."""
+    q, k, v = _qkv(seed=11)
+    window, t = FUSED["window"], 400      # across two of the kernel's blocks
+    s = t - distance
+
+    def out(k, v):
+        return sn.fused_attention(q, k, v, window, FUSED["block"],
+                                  interpret=True)[0, t]
+
+    moved = float(jnp.abs(
+        out(k.at[:, s].add(1.0), v.at[:, s].add(1.0)) - out(k, v)).max())
+    seen = bool(sn._visible(t, s, window))
+    assert seen == (distance < window)
+    assert (moved > 1e-4) == seen, moved
+
+
+def test_a_kernel_first_asked_for_inside_a_trace_serves_the_next_trace():
+    """Every later trace is handed the same kernel (its tables of which
+    blocks to visit are made once): were its arrays tracers of the
+    first trace, the second would fail with ``UnexpectedTracerError``,
+    as the program's second trace of its step did on the chip."""
+    q, k, v = _qkv()
+    for _ in range(2):              # two functions: two traces
+        out = jax.jit(lambda q, k, v: sn.window_attention(
+            q, k, v, FUSED["window"], 128))(q, k, v)
+    np.testing.assert_allclose(
+        out, sn.blocked_attention(q, k, v, FUSED["window"], 128), atol=2e-6)
+    made = []
+    jax.jit(lambda x: made.append(
+        sn._fused_kernel(256, 128, 2, 128, 128, False)) or x).lower(1.0)
+    leaves = jax.tree.leaves(made[0])
+    assert leaves and not any(
+        isinstance(leaf, jax.core.Tracer) for leaf in leaves)
+
+
+def test_the_tiny_preset_and_a_cpu_lowering_take_the_xla_path(model):
+    """Which path runs is read from what the program can observe: a
+    head narrower than a lane never reaches the kernel, and at
+    lane-wide shapes a program lowered for the CPU holds none."""
+    tokens = _tokens()
+    tiny = jax.jit(lambda p, x: _logits(
+        model.module.apply({"params": p}, x, None))).lower(
+            model.params, tokens).as_text()
+    assert "tpu_custom_call" not in tiny and "stablehlo.case" not in tiny
+    q, k, v = _qkv()
+    wide = jax.jit(jax.grad(lambda q, k, v: sn.window_attention(
+        q, k, v, FUSED["window"], 128).sum(), argnums=(0, 1, 2)))
+    assert "tpu_custom_call" not in wide.lower(q, k, v).as_text()
+    np.testing.assert_array_equal(
+        sn.window_attention(q, k, v, FUSED["window"], 128),
+        sn.blocked_attention(q, k, v, FUSED["window"], 128))
+
+
 # -- wire, ring and gather without a mask -----------------------------------
 
 def test_an_all_legal_episode_crosses_wire_ring_and_gather_without_a_mask(

@@ -347,8 +347,10 @@ def _compile_dp4_step(v5e, f):
 
 def _compile_sequence_step(v5e, f):
     """The fused step over whole 4,096-token windows: 16 B a parameter
-    of train state beside the step's temporaries must fit the chip, and
-    the policy head's logits must never exist whole."""
+    of train state beside the step's temporaries must fit the chip, the
+    policy head's logits must never exist whole, and attention runs as
+    the fused kernel in every layer: no float32 block of scores is
+    defined anywhere in the step."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -373,14 +375,42 @@ def _compile_sequence_step(v5e, f):
     assert 700e6 < n_params < 710e6
     # parameters and Adam's moments in float32, the ring beside them
     assert mem.argument_size_in_bytes >= 12 * n_params
-    # 13.6 GB when this was written (arguments 8.6, temporaries 5.0)
-    assert _footprint(mem) < 0.9 * HBM_BYTES, _footprint(mem)
+    # 13.17 GB (arguments 8.6, temporaries 4.6); 13.68 GB while
+    # attention wrote its float32 score blocks to HBM
+    assert _footprint(mem) < 13.3e9, _footprint(mem)
+    text = compiled.as_text()
     positions, vocab = 2 * 4096, 25024
-    whole = [m.group(1) for m in map(_DEFINED.match,
-                                     compiled.as_text().splitlines())
+    whole = [m.group(1) for m in map(_DEFINED.match, text.splitlines())
              if m and int(m.group(2)) == positions
              and f",{vocab}]" in m.group(0)]
     assert not whole, whole
+    # five layers (four window, one full), each a forward kernel going
+    # forward (its output kept: the layer's rematerialisation runs none
+    # again) and ONE backward kernel; the reducer finds each under its
+    # layer's scope
+    from handyrl_tpu.telemetry import devtrace
+
+    kernels = {}
+    for name, op_name in devtrace.op_names(text).items():
+        if name.startswith("splash_mqa") and op_name.endswith(
+                devtrace.KERNEL_SUFFIX):
+            key = (devtrace.net_scope_of(op_name), devtrace.phase_of(op_name),
+                   name.split(".")[0])
+            kernels[key] = kernels.get(key, 0) + 1
+    assert kernels == {
+        (scope, phase, "splash_mqa_" + kind): layers
+        for scope, layers in (("net.attention.window", 4),
+                              ("net.attention.full", 1))
+        for phase, kind in (("forward", "fwd_residuals"),
+                            ("backward", "dkv_no_residuals"))}, kernels
+    assert text.count('custom_call_target="tpu_custom_call"') == 10
+    # batch 2, 4 key-value heads of 8 query heads: a block of scores
+    # was f32[2,4,8,512,Tk], queries by keys (a log-sum-exp is one a
+    # query, written 128 lanes wide)
+    scores = [found for found in re.findall(
+        r"= (f32\[2,4,8,(\d+),(\d+)\])", text)
+        if int(found[1]) >= 512 and int(found[2]) >= 512]
+    assert not scores, scores[:4]
 
 
 @pytest.mark.parametrize("program,geometry", [
