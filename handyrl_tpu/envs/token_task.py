@@ -14,7 +14,11 @@ thing, for post-training a language-model policy on tasks whose reward
 can be checked.
 
 ``env_args``: ``net`` names the policy's size (``models/sequence_net.py::
-PRESETS``) and with it the episode lengths below.
+PRESETS``) and with it the episode lengths below (``LENGTHS``): the
+sparse-expert net ``trinity_mini_ep8`` plays episodes of up to 4,096
+positions (about 2,200 on average), the latent-attention net
+``joyai_flash_ep16`` of up to 8,192 (about 4,400); ``tiny`` and
+``tiny_latent`` are the two at test size, up to 32.
 """
 
 import math
@@ -32,7 +36,11 @@ from ..staging import fit_runs_to_window
 LENGTHS = {
     "trinity_mini_ep8": {"prompt": (128, 1024), "median": 1536,
                          "sigma": 0.7, "least": 64},
+    "joyai_flash_ep16": {"prompt": (256, 2048), "median": 3072,
+                         "sigma": 0.7, "least": 128},
     "tiny": {"prompt": (3, 8), "median": 12, "sigma": 0.7, "least": 4},
+    "tiny_latent": {"prompt": (3, 8), "median": 12, "sigma": 0.7,
+                    "least": 4},
 }
 
 

@@ -10,6 +10,19 @@ layer equations follow the ``afmoe`` family (Trinity-Mini's
 ``benchmarks/configs/trinity_mini_ep8.yaml`` and written once more in
 the plain reference, ``benchmarks/reference/trinity_net.py``.
 
+A preset may declare a second form of the same decoder, and each part
+of it is chosen at trace time from what its ``Sizes`` say, never from
+its name: layers of ``LATENT`` attention (``LatentAttention``: a
+latent rank, a rotary part and a value width that are not nought), no
+norm after a branch, no embedding scale, pairs rotated interleaved,
+and a next-next-token module after the trunk (``NextNext``), whose
+second prediction a position comes back factored under ``mtp`` from
+the whole-window pass and takes a cross-entropy term beside the RL
+loss (``ops.losses.nextn_term``).  Those equations follow the
+DeepSeek-V3 family (JoyAI-LLM-Flash's ``config.json``; ASSUMED items in
+``benchmarks/configs/joyai_flash_ep16.yaml``, the plain reference
+``benchmarks/reference/joyai_net.py``).
+
 The expert layer is TOLD which experts it holds (``first_expert``,
 ``experts_held``): it routes over all ``experts``, normalises the
 weights over every selected expert, held or not, and computes its own
@@ -31,8 +44,10 @@ Two call shapes, one set of parameters:
     (``ops.losses.FactoredPolicy``: trunk features and the head's
     kernel), so that the ``(B * T, vocab)`` logits never exist whole.
   * ``module(token (N,), hidden)`` -- the actor's one-token step through
-    a key-value cache carried as the seat's ``hidden`` (``init_hidden``):
-    dense logits for that position, the cache advanced by one.
+    a cache carried as the seat's ``hidden`` (``init_hidden``: every
+    key-value head's keys and values, or a latent layer's latent and
+    rotated key): dense logits for that position, the cache advanced by
+    one.  The next-next-token module is not run: an actor drafts nothing.
 
 ``sequence_length`` (the cache's positions, the longest episode) is how
 the module declares itself a sequence net: ``TPUModel.is_sequence``.
@@ -47,9 +62,10 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax import lax
 
-from ..ops.losses import FactoredPolicy
+from ..ops.losses import FactoredPolicy, rows_on
 
 SLIDING, FULL = "sliding_attention", "full_attention"
+LATENT = "latent_attention"
 
 
 class Sizes(NamedTuple):
@@ -73,6 +89,16 @@ class Sizes(NamedTuple):
     rope_theta: float = 10000.0
     eps: float = 1e-5
     attention_block: int = 512     # queries attended together (XLA path)
+    # what a latent-attention net declares beside (``head_dim`` is then
+    # the part of a query-key head that takes no rotation)
+    latent_q: int = 0              # rank of the queries' latent
+    latent_kv: int = 0             # rank of the keys' and values' latent
+    rope_dim: int = 0              # a head's rotary part; ONE key for all
+    value_dim: int = 0             # a value head's width
+    rope_interleave: bool = False  # rotate pairs (2i, 2i+1), not (i, i+D/2)
+    post_norms: bool = True        # an RMSNorm after each branch too
+    embed_scale: bool = True       # h = E[tokens] * sqrt(hidden)
+    nextn_modules: int = 0         # next-next-token modules (0 or 1)
 
 
 PRESETS = {
@@ -87,7 +113,30 @@ PRESETS = {
         experts_held=16, first_expert=0, experts_per_token=8,
         shared_experts=1, route_scale=2.826, window=2048,
         sequence_length=4096),
-    # the same module at test size (tier-1, CPU)
+    # JoyAI-LLM-Flash (jdopensource, joyai_llm_flash, 48B-A2.7B) at
+    # published widths: one chip's share of sixteen -- experts 0-15 of
+    # 256, rows 0-16,159 of the vocabulary, the leading dense layer, four
+    # expert layers and the next-next-token module
+    "joyai_flash_ep16": Sizes(
+        vocab=16160, hidden=2048, layer_types=(LATENT,) * 5,
+        dense_layers=1, heads=32, kv_heads=32, head_dim=128,
+        dense_width=7168, expert_width=768, experts=256,
+        experts_held=16, first_expert=0, experts_per_token=8,
+        shared_experts=1, route_scale=2.5, window=0,
+        sequence_length=8192, rope_theta=32e6, eps=1e-6,
+        latent_q=1536, latent_kv=512, rope_dim=64, value_dim=128,
+        rope_interleave=True, post_norms=False, embed_scale=False,
+        nextn_modules=1),
+    # the same modules at test size (tier-1, CPU)
+    "tiny_latent": Sizes(
+        vocab=64, hidden=64, layer_types=(LATENT,) * 3, dense_layers=1,
+        heads=4, kv_heads=4, head_dim=16, dense_width=128,
+        expert_width=32, experts=8, experts_held=2, first_expert=0,
+        experts_per_token=2, shared_experts=1, route_scale=2.5, window=0,
+        sequence_length=32, rope_theta=32e6, eps=1e-6,
+        attention_block=16, latent_q=48, latent_kv=32, rope_dim=8,
+        value_dim=16, rope_interleave=True, post_norms=False,
+        embed_scale=False, nextn_modules=1),
     "tiny": Sizes(
         vocab=64, hidden=64, layer_types=(SLIDING, FULL, SLIDING),
         dense_layers=1, heads=4, kv_heads=2, head_dim=16,
@@ -130,17 +179,27 @@ def _project(x, features, name):
     return jnp.dot(x, Kernel((x.shape[-1], features), name=name)())
 
 
-def rotate(x, positions, theta):
-    """Rotary positions on ``x (..., T, H, D)``, ``positions (..., T)``:
-    the half-split convention, computed in float32."""
+def rotate(x, positions, theta, interleave=False):
+    """Rotary positions on ``x (..., T, H, D)``, ``positions (..., T)``,
+    computed in float32: pair ``i`` is ``(i, i + D/2)`` (the half-split
+    convention) or, with ``interleave``, ``(2i, 2i + 1)``; either way it
+    turns by ``position * theta^(-2i/D)``."""
     d = x.shape[-1]
     freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angle = positions[..., None].astype(jnp.float32) * freq  # (..., T, D/2)
-    cos = jnp.concatenate([jnp.cos(angle)] * 2, -1)[..., None, :]
-    sin = jnp.concatenate([jnp.sin(angle)] * 2, -1)[..., None, :]
     x32 = x.astype(jnp.float32)
-    half = jnp.concatenate([-x32[..., d // 2:], x32[..., :d // 2]], -1)
-    return (x32 * cos + half * sin).astype(x.dtype)
+    if interleave:
+        cos = jnp.repeat(jnp.cos(angle), 2, -1)[..., None, :]
+        sin = jnp.repeat(jnp.sin(angle), 2, -1)[..., None, :]
+        pairs = x32.reshape(x.shape[:-1] + (d // 2, 2))
+        partner = jnp.stack([-pairs[..., 1], pairs[..., 0]], -1).reshape(
+            x.shape)
+    else:
+        cos = jnp.concatenate([jnp.cos(angle)] * 2, -1)[..., None, :]
+        sin = jnp.concatenate([jnp.sin(angle)] * 2, -1)[..., None, :]
+        partner = jnp.concatenate(
+            [-x32[..., d // 2:], x32[..., :d // 2]], -1)
+    return (x32 * cos + partner * sin).astype(x.dtype)
 
 
 def _visible(t, s, window):
@@ -221,27 +280,31 @@ def _fused_kernel(T, window, groups, block, compute, interpret):
 
 def _fused_blocks(T, D, block=None):
     """The kernel's ``(block, keys a product)`` over ``T`` positions of
-    ``D``-wide heads (``block``: the module's constant unless given), or
-    None where the shapes are not whole lanes and whole blocks."""
+    ``D``-wide query-key heads (``block``: the module's constant unless
+    given), or None where the positions are not whole blocks of whole
+    lanes or a head is not whole half-lanes, one lane at least (a
+    latent head's 192 goes in as it is: zero-padded to 256 the kernels
+    took 26.8 ms a layer on the chip where they take 26.0)."""
     block = block or min(FUSED_BLOCK, T)
     compute = min(FUSED_COMPUTE, block)
-    if T % block or block % compute or compute % LANES or D % LANES:
+    if (T % block or block % compute or compute % LANES
+            or D < LANES or D % (LANES // 2)):
         return None
     return block, compute
 
 
 def fused_attention(q, k, v, window, block=None, interpret=False):
     """The same attention as ``blocked_attention`` over the same
-    ``q (B, T, KV, G, D)`` and ``k, v (B, T, KV, D)``, as one kernel a
-    layer: online softmax in float32 in the chip's fast memory, blocks
-    of ``block`` x ``block`` that causality or the window hide whole
-    skipped, the probabilities meeting ``v`` in ``v``'s dtype, and a
-    backward kernel of its own that makes the scores again from q, k
-    and one log-sum-exp a query.  No array of score size is written in
-    either direction.  ``T`` is a multiple of ``block`` (the module's
-    constant unless given), ``block`` and ``D`` multiples of 128;
-    ``interpret`` runs the kernel's body as plain JAX (tier-1, on the
-    CPU)."""
+    ``q (B, T, KV, G, D)``, ``k (B, T, KV, D)`` and ``v (B, T, KV, Dv)``,
+    as one kernel a layer: online softmax in float32 in the chip's fast
+    memory, blocks of ``block`` x ``block`` that causality or the window
+    hide whole skipped, the probabilities meeting ``v`` in ``v``'s
+    dtype, and a backward kernel of its own that makes the scores again
+    from q, k and one log-sum-exp a query.  No array of score size is
+    written in either direction.  ``T`` is a multiple of ``block`` (the
+    module's constant unless given), ``block`` of 128, ``D`` and ``Dv``
+    of 64 and 128 at least; ``interpret`` runs the kernel's body as
+    plain JAX (tier-1, on the CPU)."""
     B, T, KV, G, D = q.shape
     attend = _fused_kernel(T, window, G, *_fused_blocks(T, D, block),
                            interpret)
@@ -322,6 +385,88 @@ class Attention(nn.Module):
                                values.astype(jnp.float32))
                 o = o.reshape(-1, z.heads * z.head_dim).astype(a.dtype)
             o = _project(o * gate, z.hidden, "o")
+        return o, cache
+
+
+class LatentAttention(nn.Module):
+    """Attention through two low-rank latents: queries from a normed
+    latent of ``latent_q``, keys and values from ONE normed latent of
+    ``latent_kv`` a position, and a rotary key of ``rope_dim`` that all
+    heads share.  A head's query and key are ``head_dim`` numbers that
+    take no rotation beside ``rope_dim`` that do; its value is
+    ``value_dim`` wide.  Full causal attention, no gate.
+
+    The whole-window pass makes every head's keys and values from the
+    latent and attends as any layer does (``window_attention``: each
+    head its own key-value head).  The one-token step caches what a
+    position IS -- its normed latent and its rotated key, ``latent_kv +
+    rope_dim`` numbers -- and attends in the latent: the query is taken
+    through the keys' half of ``kv_b`` first and the result through the
+    values' half after, the same sums in another order."""
+    sizes: Sizes
+
+    @nn.compact
+    def __call__(self, a, cache=None, pos=None):
+        z = self.sizes
+        nope, rope, wide = z.head_dim, z.rope_dim, z.value_dim
+        with jax.named_scope("net.attention.latent"):
+            lead = a.shape[:-1]
+            cq = RMSNorm(z.eps, name="q_norm")(
+                _project(a, z.latent_q, "q_a"))
+            q = _project(cq, z.heads * (nope + rope), "q_b").reshape(
+                lead + (z.heads, nope + rope))
+            kv_a = _project(a, z.latent_kv + rope, "kv_a")
+            latent = RMSNorm(z.eps, name="kv_norm")(kv_a[..., :z.latent_kv])
+            key = kv_a[..., None, z.latent_kv:]        # (..., 1, rope)
+            kv_b = Kernel((z.latent_kv, z.heads * (nope + wide)),
+                          name="kv_b")()
+            turn = partial(rotate, theta=z.rope_theta,
+                           interleave=z.rope_interleave)
+            if cache is None:
+                # a whole window: (B, T, ...)
+                B, T = lead
+                positions = jnp.arange(T)[None]
+                q = jnp.concatenate(
+                    [q[..., :nope], turn(q[..., nope:], positions)], -1)
+                kv = jnp.dot(latent, kv_b).reshape(
+                    B, T, z.heads, nope + wide)
+                k = jnp.concatenate(
+                    [kv[..., :nope], jnp.broadcast_to(
+                        turn(key, positions), (B, T, z.heads, rope))], -1)
+                o = window_attention(q[:, :, :, None], k, kv[..., nope:],
+                                     0, z.attention_block)
+                o = o.reshape(B, T, z.heads * wide)
+            else:
+                # one token a row, through the cache: (N, ...)
+                q_rope = turn(q[:, None, :, nope:], pos[:, None])[:, 0]
+                key = turn(key[:, None], pos[:, None])[:, 0, 0]
+                latents, keys = cache        # (N, S, latent_kv), (N, S, rope)
+                s = jnp.arange(latents.shape[1])
+                here = (s[None] == pos[:, None])[..., None]
+                latents = jnp.where(
+                    here, latent[:, None].astype(latents.dtype), latents)
+                keys = jnp.where(
+                    here, key[:, None].astype(keys.dtype), keys)
+                cache = (latents, keys)
+                kv_b = kv_b.astype(jnp.float32).reshape(
+                    z.latent_kv, z.heads, nope + wide)
+                q_latent = jnp.einsum(
+                    "nhd,chd->nhc", q[..., :nope].astype(jnp.float32),
+                    kv_b[..., :nope])
+                scores = (
+                    jnp.einsum("nhc,nsc->nhs", q_latent,
+                               latents.astype(jnp.float32))
+                    + jnp.einsum("nhr,nsr->nhs", q_rope.astype(jnp.float32),
+                                 keys.astype(jnp.float32))
+                ) / math.sqrt(nope + rope)
+                seen = _visible(pos[:, None], s[None], 0)
+                p = jax.nn.softmax(
+                    jnp.where(seen[:, None], scores, -1e30), axis=-1)
+                o = jnp.einsum("nhs,nsc->nhc", p,
+                               latents.astype(jnp.float32))
+                o = jnp.einsum("nhc,chd->nhd", o, kv_b[..., nope:])
+                o = o.reshape(-1, z.heads * wide).astype(a.dtype)
+            o = _project(o, z.hidden, "o")
         return o, cache
 
 
@@ -424,9 +569,16 @@ class Layer(nn.Module):
     @nn.compact
     def __call__(self, h, cache=None, pos=None, valid=None):
         z = self.sizes
+
+        def branch(y, name):
+            # the net declares whether a branch is normed coming out
+            return RMSNorm(z.eps, name=name)(y) if z.post_norms else y
+
         a = RMSNorm(z.eps, name="pre_attn_norm")(h)
-        o, cache = Attention(z, self.kind, name="attn")(a, cache, pos)
-        h = h + RMSNorm(z.eps, name="post_attn_norm")(o)
+        attend = LatentAttention(z, name="attn") if self.kind == LATENT \
+            else Attention(z, self.kind, name="attn")
+        o, cache = attend(a, cache, pos)
+        h = h + branch(o, "post_attn_norm")
         m = RMSNorm(z.eps, name="pre_mlp_norm")(h)
         if self.dense:
             with jax.named_scope("net.mlp"):
@@ -434,8 +586,41 @@ class Layer(nn.Module):
             counts = jnp.zeros((0,), jnp.int32)
         else:
             y, counts = SparseExperts(z, name="moe")(m, valid)
-        h = h + RMSNorm(z.eps, name="post_mlp_norm")(y)
+        h = h + branch(y, "post_mlp_norm")
         return h, cache, counts
+
+
+# a layer over a whole window is made again coming back, all but its
+# fused attention's output
+RematLayer = nn.remat(
+    Layer, policy=jax.checkpoint_policies.save_only_these_names(KEPT))
+
+
+class NextNext(nn.Module):
+    """The next-next-token module over a whole window: at position
+    ``t`` the trunk's last state and the embedding of the token at
+    ``t + 1``, each normed, joined and projected back to the residual
+    width, through one expert layer of the trunk's own form, normed:
+    features that the model's own head turns into a second prediction,
+    of the token at ``t + 2``.  ``following (B, T)`` is the window
+    moved one position on (-1 where no token follows); the embedding
+    and the head are the model's, shared."""
+    sizes: Sizes
+
+    @nn.compact
+    def __call__(self, h, table, following):
+        z = self.sizes
+        with jax.named_scope("net.mtp"):
+            joined = jnp.concatenate([
+                RMSNorm(z.eps, name="state_norm")(h),
+                RMSNorm(z.eps, name="token_norm")(
+                    table[jnp.maximum(following, 0)])], -1)
+            u = _project(joined, z.hidden, "join")
+        # the layer's attention and experts lie under their own scopes
+        u, _, counts = RematLayer(z, z.layer_types[-1], False, name="layer")(
+            u, None, None, following >= 0)
+        with jax.named_scope("net.mtp"):
+            return RMSNorm(z.eps, name="final_norm")(u), counts
 
 
 class SequencePolicyNet(nn.Module):
@@ -445,15 +630,23 @@ class SequencePolicyNet(nn.Module):
     def sequence_length(self):
         return self.sizes.sequence_length
 
-    def init_hidden(self, batch_shape=()):
-        """The actor's key-value cache, empty: the position to write
-        next and every layer's keys and values."""
+    def _cache(self):
+        """What the actor's cache holds of one position of one layer,
+        by name: each key-value head's key and value, or the latent
+        and the one rotated key that every head's are made from."""
         z = self.sizes
-        shape = tuple(batch_shape) + (
-            len(z.layer_types), z.sequence_length, z.kv_heads, z.head_dim)
-        return {"pos": jnp.zeros(tuple(batch_shape), jnp.int32),
-                "k": jnp.zeros(shape, jnp.float32),
-                "v": jnp.zeros(shape, jnp.float32)}
+        if z.latent_kv:
+            return {"latent": (z.latent_kv,), "rope": (z.rope_dim,)}
+        return {"k": (z.kv_heads, z.head_dim), "v": (z.kv_heads, z.head_dim)}
+
+    def init_hidden(self, batch_shape=()):
+        """The actor's cache, empty: the position to write next and
+        every layer's entries (``_cache``)."""
+        z = self.sizes
+        lead = tuple(batch_shape) + (len(z.layer_types), z.sequence_length)
+        hidden = {name: jnp.zeros(lead + shape, jnp.float32)
+                  for name, shape in self._cache().items()}
+        return dict(hidden, pos=jnp.zeros(tuple(batch_shape), jnp.int32))
 
     @nn.compact
     def __call__(self, tokens, hidden=None):
@@ -466,22 +659,21 @@ class SequencePolicyNet(nn.Module):
         # (``ops.losses.forward_prediction``): they read token 0 and
         # take no expert
         valid = (tokens >= 0) if whole else None
-        # mup_enabled: the embedding is scaled by sqrt(hidden_size)
-        h = table[jnp.maximum(tokens, 0)] * jnp.asarray(
-            math.sqrt(z.hidden), table.dtype)
-        layer = nn.remat(
-            Layer, policy=jax.checkpoint_policies.save_only_these_names(
-                KEPT)) if whole else Layer
+        h = table[jnp.maximum(tokens, 0)]
+        if z.embed_scale:
+            # mup_enabled: the embedding is scaled by sqrt(hidden_size)
+            h = h * jnp.asarray(math.sqrt(z.hidden), table.dtype)
+        layer = RematLayer if whole else Layer
         pos = None if whole else hidden["pos"]
-        keys, values, counts = [], [], []
+        names = tuple(self._cache())
+        caches, counts = [], []
         for i, kind in enumerate(z.layer_types):
-            cache = None if whole else (hidden["k"][:, i], hidden["v"][:, i])
+            cache = None if whole else tuple(
+                hidden[name][:, i] for name in names)
             h, cache, c = layer(z, kind, i < z.dense_layers,
                                 name=f"layer_{i}")(h, cache, pos, valid)
             counts.append(c)
-            if not whole:
-                keys.append(cache[0])
-                values.append(cache[1])
+            caches.append(cache)
         with jax.named_scope("net.head"):
             feats = RMSNorm(z.eps, name="final_norm")(h)
             kernel = Kernel((z.hidden, z.vocab), name="head")()
@@ -493,6 +685,16 @@ class SequencePolicyNet(nn.Module):
             else:
                 policy = jnp.dot(feats, kernel).astype(jnp.float32)
         out = {"policy": policy, "value": value}
+        if z.nextn_modules and (whole or self.is_initializing()):
+            # the module runs over whole windows only (an actor drafts
+            # nothing); a net initialised through the one-token step
+            # makes its parameters on windows of one position
+            window = tokens if whole else tokens[:, None]
+            drafted, c = NextNext(z, name="mtp")(
+                h if whole else h[:, None], table, rows_on(window, 1))
+            counts.append(c)
+            if whole:
+                out["mtp"] = FactoredPolicy(drafted, kernel)
         if whole:
             # positions routed to each held expert, by expert layer
             out["expert_load"] = jnp.stack(
@@ -500,10 +702,10 @@ class SequencePolicyNet(nn.Module):
             out["expert_picks"] = (
                 valid.sum() * z.experts_per_token).astype(jnp.float32)
         else:
-            out["hidden"] = {
-                "pos": pos + 1,
-                "k": jnp.stack(keys, 1).astype(hidden["k"].dtype),
-                "v": jnp.stack(values, 1).astype(hidden["v"].dtype)}
+            out["hidden"] = {"pos": pos + 1} | {
+                name: jnp.stack([cache[j] for cache in caches], 1).astype(
+                    hidden[name].dtype)
+                for j, name in enumerate(names)}
         return out
 
 

@@ -11,7 +11,10 @@ Semantic parity with /root/reference/handyrl/train.py:128-268:
   * sequence nets (``SEQUENCE``) take the window AS the sequence: one
     causal pass over ``(B, T)`` tokens, no carried state, the policy
     factored (``FactoredPolicy``) so that logits of vocabulary width
-    exist a chunk of positions at a time;
+    exist a chunk of positions at a time; a net with a next-next-token
+    module hands back a second factored prediction, whose
+    cross-entropy against the window's own token two rows on is added
+    to the total (``nextn_term``);
   * losses: V-Trace/UPGO/TD/MC targets on detached values, importance
     ratios clipped at ``rho_clip``/``c_clip`` (both 1 by default, the
     reference behavior), two-player zero-sum value symmetrization,
@@ -46,6 +49,9 @@ CLIP_C = 1.0
 # carries no state from step to step (``TPUModel.is_sequence``)
 SEQUENCE = "sequence"
 POLICY_CHUNK = 1024     # positions whose logits exist together
+# weight of a net's next-next-token term beside the RL loss (the
+# family's own, late in its training; the net's config gives none)
+NEXTN_WEIGHT = 0.1
 
 
 @jax.tree_util.register_pytree_node_class
@@ -64,6 +70,13 @@ class FactoredPolicy:
     @classmethod
     def tree_unflatten(cls, _aux, children):
         return cls(*children)
+
+
+def rows_on(tokens, n):
+    """A window's tokens ``(B, T)`` moved ``n`` rows on: at row ``t``
+    the token of row ``t + n``, -1 where the window has none."""
+    return jnp.concatenate(
+        [tokens[:, n:], jnp.full_like(tokens[:, :n], -1)], 1)
 
 
 class LossConfig(NamedTuple):
@@ -145,7 +158,7 @@ def forward_prediction(apply_fn: Callable, params, hidden, batch,
         policy = out["policy"]
         # no bias in the head: masking the features masks the logits;
         # an all-legal batch has no action mask to take off (width 0)
-        return {
+        result = {
             "policy": FactoredPolicy(
                 policy.features[:, :, None] * batch["turn_mask"].astype(
                     policy.features.dtype), policy.kernel),
@@ -153,6 +166,13 @@ def forward_prediction(apply_fn: Callable, params, hidden, batch,
             "expert_load": out["expert_load"],
             "expert_picks": out["expert_picks"],
         }
+        if "mtp" in out:
+            # a net with a next-next-token module: its second
+            # prediction at ``t`` is of the window's own token two rows
+            # on (-1 where the episode has none: no term there)
+            result["mtp"] = out["mtp"]
+            result["mtp_target"] = rows_on(tokens, 2)
+        return result
     if hidden is None:
         obs_flat = _flatten_lead(observations, 3)  # (B*T*P_in, ...)
         out = apply_fn(params, obs_flat, None)
@@ -248,20 +268,16 @@ def _masked_entropy(logits, axis=-1):
     return -jnp.sum(p * jnp.clip(lsm, -1e32, 0.0), axis=axis)
 
 
-def policy_terms(policy, actions):
-    """``(log-probability of the actions taken (..., 1), entropy (...)
-    or None)`` of ``policy``.  Dense logits: the log-softmax's entry,
-    and the entropy is left to ``compose_losses`` as it always was.  A
-    ``FactoredPolicy``: both, from logits made ``POLICY_CHUNK``
-    positions at a time and made again coming back, never whole."""
-    if not isinstance(policy, FactoredPolicy):
-        log_policy = jax.nn.log_softmax(policy, axis=-1)
-        return jnp.take_along_axis(log_policy, actions, axis=-1), None
+def _head_in_chunks(policy, taken, scope):
+    """``(log-probability of the entries taken, entropy)`` of a
+    ``FactoredPolicy`` over flat positions: logits made
+    ``POLICY_CHUNK`` positions at a time and made again coming back,
+    never whole, under the net scope ``scope``."""
     feats = policy.features.reshape(-1, policy.features.shape[-1])
-    taken = actions.reshape(-1)
-    pad = -feats.shape[0] % POLICY_CHUNK
+    n = taken.size
+    pad = -n % POLICY_CHUNK
     feats = jnp.pad(feats, [(0, pad), (0, 0)])
-    taken = jnp.pad(taken, (0, pad))
+    taken = jnp.pad(taken.reshape(-1), (0, pad))
 
     @jax.checkpoint
     def chunk(kernel, xs):
@@ -272,14 +288,42 @@ def policy_terms(policy, actions):
         selected = jnp.take_along_axis(logits, a[:, None], axis=-1)[:, 0]
         return selected - lse, lse - (p * logits).sum(-1)
 
-    with jax.named_scope("net.forward"), jax.named_scope("net.head"):
+    with jax.named_scope("net.forward"), jax.named_scope(scope):
         selected, entropy = lax.map(
             partial(chunk, policy.kernel),
             (feats.reshape(-1, POLICY_CHUNK, feats.shape[-1]),
              taken.reshape(-1, POLICY_CHUNK)))
-    n = actions.size
-    return (selected.reshape(-1)[:n].reshape(actions.shape),
-            entropy.reshape(-1)[:n].reshape(actions.shape[:-1]))
+    return selected.reshape(-1)[:n], entropy.reshape(-1)[:n]
+
+
+def policy_terms(policy, actions):
+    """``(log-probability of the actions taken (..., 1), entropy (...)
+    or None)`` of ``policy``.  Dense logits: the log-softmax's entry,
+    and the entropy is left to ``compose_losses`` as it always was.  A
+    ``FactoredPolicy``: both, from logits made ``POLICY_CHUNK``
+    positions at a time and made again coming back, never whole."""
+    if not isinstance(policy, FactoredPolicy):
+        log_policy = jax.nn.log_softmax(policy, axis=-1)
+        return jnp.take_along_axis(log_policy, actions, axis=-1), None
+    selected, entropy = _head_in_chunks(policy, actions, "net.head")
+    return (selected.reshape(actions.shape),
+            entropy.reshape(actions.shape[:-1]))
+
+
+def nextn_term(policy, target):
+    """A net's next-next-token term: ``policy`` (a ``FactoredPolicy``
+    over ``(B, T)`` positions, the module's features through the
+    model's own head) against ``target (B, T)``, the token two rows on
+    or -1 where the window holds none.  Each row's mean cross-entropy
+    over the positions that have a target, summed over the rows as
+    every term of the loss is; and the share of positions that have
+    one."""
+    there = target >= 0
+    log_p, _ = _head_in_chunks(policy, jnp.maximum(target, 0), "net.mtp")
+    with jax.named_scope("net.forward"), jax.named_scope("net.mtp"):
+        log_p = jnp.where(there, log_p.reshape(target.shape), 0.0)
+        term = (-log_p.sum(-1) / jnp.maximum(there.sum(-1), 1)).sum()
+    return term, there.mean()
 
 
 def compose_losses(outputs, log_selected_policies, total_advantages,
@@ -473,13 +517,20 @@ def compute_loss(apply_fn: Callable, params, batch, hidden, cfg: LossConfig,
     if "expert_load" in outputs:
         losses.update(sequence_counters(
             outputs["expert_load"], outputs["expert_picks"], emasks))
+    if "mtp" in outputs:
+        with jax.named_scope("loss.terms"):
+            losses["mtp_loss"], losses["mtp_target_share"] = nextn_term(
+                outputs["mtp"], outputs["mtp_target"])
+            losses["total"] = (
+                losses["total"] + NEXTN_WEIGHT * losses["mtp_loss"])
     return losses, dcnt
 
 
 # what a sequence net's step counts beside its losses: they ride the
 # step's ``metrics`` and ``Trainer.step_profile`` reads them
 SEQUENCE_COUNTERS = ("expert_load_max", "expert_load_mean",
-                     "held_pick_share", "window_fill")
+                     "held_pick_share", "window_fill",
+                     "mtp_loss", "mtp_target_share")
 
 
 def sequence_counters(expert_load, expert_picks, episode_mask):

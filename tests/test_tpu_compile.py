@@ -141,13 +141,9 @@ def geister():
         128, 202), temp_share=0.25)
 
 
-@pytest.fixture(scope="module")
-def sequence():
-    """``trinity_mini_ep8``: the sparse-expert sequence net at published
-    widths, one chip's share of eight; windows of 4,096 tokens, batch 2,
-    every action legal (no mask in the ring's row, the token riding the
-    packed channel), the ring at the configuration's 1,024 slots.
-    Nothing is played and no weight is made: the shapes alone."""
+def _sequence_fixture(preset, steps, batch, slots):
+    """A sequence preset's fused step, by shapes alone: nothing is
+    played and no weight is made."""
     import jax
 
     from handyrl_tpu.environment import make_env
@@ -156,28 +152,45 @@ def sequence():
     from handyrl_tpu.ops.update import DEFAULT_LR, make_optimizer
     from handyrl_tpu.staging import DeviceReplay
 
-    steps = 4096
     cfg = {"turn_based_training": False, "observation": True,
            "forward_steps": steps, "burn_in_steps": 0, "gamma": 1.0,
            "lambda": 0.95, "policy_target": "TD", "value_target": "TD",
            "entropy_regularization": 0.01,
            "entropy_regularization_decay": 0.1,
            "compute_dtype": "bfloat16"}
-    env = make_env({"env": "TokenTask", "net": "trinity_mini_ep8"})
+    env = make_env({"env": "TokenTask", "net": preset})
     model = TPUModel(env.net())
     params = jax.eval_shape(
         lambda: model.module.init(
             jax.random.PRNGKey(0), np.zeros((1,), np.int32),
             model.init_hidden([1]))["params"])
-    replay = DeviceReplay(cfg, 1024, 512 << 20)
+    replay = DeviceReplay(cfg, slots, 512 << 20)
     replay.t_max = steps
     col = {"players": [0], "obs": np.zeros((steps, 1), np.int32),
            "amask": np.zeros((steps, 1, 0), np.float32)}
-    optimizer = make_optimizer(DEFAULT_LR * 2 * steps)
+    optimizer = make_optimizer(DEFAULT_LR * batch * steps)
     return {"model": model, "replay": replay, "params": params,
             "buffers": replay._plan_buffers(col), "optimizer": optimizer,
             "opt_state": jax.eval_shape(optimizer.init, params),
-            "loss_cfg": LossConfig.from_config(cfg), "batch": 2}
+            "loss_cfg": LossConfig.from_config(cfg), "batch": batch}
+
+
+@pytest.fixture(scope="module")
+def sequence():
+    """``trinity_mini_ep8``: the sparse-expert sequence net at published
+    widths, one chip's share of eight; windows of 4,096 tokens, batch 2,
+    every action legal (no mask in the ring's row, the token riding the
+    packed channel), the ring at the configuration's 1,024 slots."""
+    return _sequence_fixture("trinity_mini_ep8", 4096, 2, 1024)
+
+
+@pytest.fixture(scope="module")
+def latent_sequence():
+    """``joyai_flash_ep16``: the latent-attention sequence net with its
+    next-next-token module at published widths, one chip's share of
+    sixteen; ONE window of 8,192 tokens a step, the ring at the
+    configuration's 512 slots."""
+    return _sequence_fixture("joyai_flash_ep16", 8192, 1, 512)
 
 
 def _on(tree, sharding):
@@ -345,6 +358,21 @@ def _compile_dp4_step(v5e, f):
     assert _footprint(compiled.memory_analysis()) < HBM_BYTES
 
 
+def _fused_kernels(text):
+    """The step's fused attention kernels, counted by (net scope,
+    phase, kernel): the reducer finds each under its layer's scope."""
+    from handyrl_tpu.telemetry import devtrace
+
+    kernels = {}
+    for name, op_name in devtrace.op_names(text).items():
+        if name.startswith("splash_mqa") and op_name.endswith(
+                devtrace.KERNEL_SUFFIX):
+            key = (devtrace.net_scope_of(op_name), devtrace.phase_of(op_name),
+                   name.split(".")[0])
+            kernels[key] = kernels.get(key, 0) + 1
+    return kernels
+
+
 def _compile_sequence_step(v5e, f):
     """The fused step over whole 4,096-token windows: 16 B a parameter
     of train state beside the step's temporaries must fit the chip, the
@@ -386,17 +414,8 @@ def _compile_sequence_step(v5e, f):
     assert not whole, whole
     # five layers (four window, one full), each a forward kernel going
     # forward (its output kept: the layer's rematerialisation runs none
-    # again) and ONE backward kernel; the reducer finds each under its
-    # layer's scope
-    from handyrl_tpu.telemetry import devtrace
-
-    kernels = {}
-    for name, op_name in devtrace.op_names(text).items():
-        if name.startswith("splash_mqa") and op_name.endswith(
-                devtrace.KERNEL_SUFFIX):
-            key = (devtrace.net_scope_of(op_name), devtrace.phase_of(op_name),
-                   name.split(".")[0])
-            kernels[key] = kernels.get(key, 0) + 1
+    # again) and ONE backward kernel
+    kernels = _fused_kernels(text)
     assert kernels == {
         (scope, phase, "splash_mqa_" + kind): layers
         for scope, layers in (("net.attention.window", 4),
@@ -413,13 +432,72 @@ def _compile_sequence_step(v5e, f):
     assert not scores, scores[:4]
 
 
+def _compile_latent_step(v5e, f):
+    """The fused step over ONE whole 8,192-token window of the
+    latent-attention net: the train state (16 B a parameter) beside
+    the step's temporaries fits the chip, neither the policy's nor the
+    module's logits exist whole, and all six attentions (the module's
+    among them) run as the fused kernel at query-key heads of 192
+    against value heads of 128, under the latent scope."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from handyrl_tpu.staging import epoch_sums, make_replay_update_step
+    from handyrl_tpu.telemetry import devtrace
+
+    chip = SingleDeviceSharding(v5e[0])
+    replay = f["replay"]
+    assert f["buffers"]["obs"] is None          # the token rides `steps`
+    assert f["buffers"]["steps"].shape == (512 * 8192 + 8192, 8)
+    assert (replay.run_round, replay.max_run) == (8192, 2)
+    step = make_replay_update_step(
+        replay, f["model"], f["loss_cfg"], f["optimizer"],
+        "bfloat16", batch_size=f["batch"])
+    compiled = step.lower(
+        *_on((f["params"], f["opt_state"], f["buffers"],
+              (jax.ShapeDtypeStruct((3,), jnp.int32), epoch_sums(replay))),
+             chip)).compile()
+    mem = compiled.memory_analysis()
+    n_params = sum(int(np.prod(leaf.shape))
+                   for leaf in jax.tree.leaves(f["params"]))
+    assert n_params == 680_441_856
+    assert mem.argument_size_in_bytes >= 12 * n_params
+    # 13.08 GB (arguments 8.3, temporaries 4.8)
+    assert _footprint(mem) < 13.3e9, _footprint(mem)
+    text = compiled.as_text()
+    positions, vocab = 8192, 16160
+    whole = [m.group(1) for m in map(_DEFINED.match, text.splitlines())
+             if m and int(m.group(2)) == positions
+             and f",{vocab}]" in m.group(0)]
+    assert not whole, whole
+    assert _fused_kernels(text) == {
+        ("net.attention.latent", "forward", "splash_mqa_fwd_residuals"): 6,
+        ("net.attention.latent", "backward",
+         "splash_mqa_dkv_no_residuals"): 6}
+    assert text.count('custom_call_target="tpu_custom_call"') == 12
+    assert any("/mtp/layer/attn/net.attention.latent/" in op_name
+               for op_name in devtrace.op_names(text).values())
+    # batch 1, 32 heads each its own key-value head: a block of scores
+    # would be f32[1,32,1,512,Tk]
+    scores = [found for found in re.findall(
+        r"= (f32\[1,32,1,(\d+),(\d+)\])", text)
+        if int(found[1]) >= 512 and int(found[2]) >= 512]
+    assert not scores, scores[:4]
+    # the module's own operations have their scope
+    assert any(devtrace.net_scope_of(op_name) == "net.mtp"
+               for op_name in devtrace.op_names(text).values())
+
+
 @pytest.mark.parametrize("program,geometry", [
     (_compile_replay_step, "flagship"), (_compile_replay_step, "geister"),
     (_compile_ring_append, "flagship"),
     (_compile_service_forward, "flagship"), (_compile_dp4_step, "flagship"),
     (_compile_sequence_step, "sequence"),
+    (_compile_latent_step, "latent_sequence"),
 ], ids=["replay_step", "replay_step_geister", "ring_append",
-        "service_forward", "dp4_step", "replay_step_sequence"])
+        "service_forward", "dp4_step", "replay_step_sequence",
+        "replay_step_latent"])
 def test_main_path_compiles_for_a_described_v5e(
         program, geometry, v5e, request):
     program(v5e, request.getfixturevalue(geometry))
