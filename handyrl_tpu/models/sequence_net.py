@@ -28,9 +28,11 @@ The expert layer is TOLD which experts it holds (``first_expert``,
 weights over every selected expert, held or not, and computes its own
 experts' part of the result for the positions routed to them -- the
 chip's share under expert parallelism, on one chip without the
-exchange.  No token is dropped: every held expert runs over every
-position and is weighted by the router where it was selected; only a
-window's padding past its episode's end (token -1) takes no expert.
+exchange.  No token is dropped and no capacity exists: every pick that
+falls on a held expert is computed (``held_experts``: over the picks
+alone where the step is lowered for a TPU, by every held expert over
+every position elsewhere); only a window's padding past its episode's
+end (token -1) takes no expert.
 
 Two call shapes, one set of parameters:
 
@@ -509,33 +511,257 @@ def route(m, router, sizes):
     return selected, weights
 
 
+def dense_experts(m, here, weights, w1, w3, w2):
+    """Every held expert over every position, its part weighted by the
+    router's weight where it was picked (``here (N, k, held)``) and by
+    nought where not: the held stack as ONE SwiGLU of width ``held *
+    expert_width``, three dense products."""
+    gate = jnp.where(here, weights[..., None], 0.0).sum(1)    # (N, held)
+    h = (jax.nn.silu(jnp.einsum("nd,edf->nef", m, w1))
+         * jnp.einsum("nd,edf->nef", m, w3))
+    h = (h * gate[..., None]).astype(m.dtype)
+    return jnp.einsum("nef,efd->nd", h, w2)
+
+
+# the grouped products' tiles, read on the chip (``PERF.md`` section 6,
+# PR 39): rows of picks a grid step, which the passes between the
+# products take a turn as well (one expert layer alone, forward and
+# backward, 4,438 live rows of 65,536: 15.2 ms at 512, 14.7 at 256 and
+# 128 while gathers over all 65,536 rows stood beside the kernels;
+# 1,024 run out of a kernel's 16 MB of fast memory; the passes by
+# turns of 512 / 1,024 / 2,048 / 4,096 rows: 5.27 / 5.43 / 7.22 / 6.98
+# ms, and 9.74 / 9.59 / 12.95 / 11.60 at 14,176 live rows), and the
+# most of a product's contracted and output widths (at 512: 15.9 ms
+# where 1,024 read 15.2; a width under it goes in whole: an expert's
+# 768)
+GROUPED_ROWS = 512
+GROUPED_WIDTH = 1024
+
+
+def _grouped_tiles(rows, d, f):
+    """``{width: tile}`` for the grouped products over ``rows`` picks
+    at residual width ``d`` and expert width ``f``, or None where the
+    rows are not whole tiles or a width is not whole tiles of whole
+    lanes."""
+    tiles = {width: min(GROUPED_WIDTH, width) for width in (d, f)}
+    if rows % GROUPED_ROWS or any(
+            tile % LANES or width % tile for width, tile in tiles.items()):
+        return None
+    return tiles
+
+
+def _sorted_picks(here, weights):
+    """The picks ``here (N, k, held)`` in the order the grouped
+    products read them: ``src (N * k,)``, the pick in each row of a
+    buffer that holds the live picks first, grouped by expert, and
+    everything else behind them; each row's router weight, which rides
+    the sort; ``live (N, k)``."""
+    held = here.shape[-1]
+    live = here.any(-1)
+    key = jnp.where(live, jnp.argmax(here, -1), held).reshape(-1)
+    rows = jnp.arange(key.shape[0], dtype=jnp.int32)
+    _, src, by_row = lax.sort(
+        (key.astype(jnp.int32), rows, weights.reshape(-1)), num_keys=1)
+    return src, by_row[:, None], live
+
+
+def _to_picks(src, numbers):
+    """One number a row of the sorted buffer, back in the picks' own
+    order: a permutation's transpose is its inverse, and sorting by
+    ``src`` is that inverse (sixteen times sooner on the chip than a
+    gather of 65,536 scalars)."""
+    return lax.sort((src, numbers), num_keys=1)[1]
+
+
+def _products(tiles, interpret):
+    """The library's grouped product and its transpose (``megablox``)
+    at this module's tiles: ``product(lhs (rows, a), rhs (groups, a,
+    b) or, ``transposed``, (groups, b, a))`` and ``outer(lhs (rows, a),
+    rhs (rows, b)) -> (groups, a, b)``, each row with its group's
+    matrix, float32 sums, rows past the groups' end never visited (a
+    product's rows there hold whatever the memory held)."""
+    # (the package's own ``gmm`` is these two under one tiling for both
+    # directions; an expert's 768 against 2,048 takes one each)
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+    def product(lhs, rhs, sizes, dtype, transposed=False):
+        a, b = lhs.shape[1], rhs.shape[1 if transposed else 2]
+        return gmm(
+            lhs, rhs, sizes, dtype, (GROUPED_ROWS, tiles[a], tiles[b]),
+            transpose_rhs=transposed, interpret=interpret)
+
+    def outer(lhs, rhs, sizes, dtype):
+        a, b = lhs.shape[1], rhs.shape[1]
+        return tgmm(
+            lhs.swapaxes(0, 1), rhs, sizes, dtype,
+            (GROUPED_ROWS, tiles[a], tiles[b]), interpret=interpret)
+
+    return product, outer
+
+
+def _live_chunks(count, body, carry):
+    """``body(start, carry)`` over each chunk of ``GROUPED_ROWS`` rows
+    of a sorted buffer that holds any of its first ``count`` rows, the
+    live ones: as the kernels, time by tiles of live rows."""
+    return lax.fori_loop(
+        0, (count + GROUPED_ROWS - 1) // GROUPED_ROWS,
+        lambda turn, carry: body(turn * GROUPED_ROWS, carry), carry)
+
+
+def _row_by_row(count, fn, buffers, like):
+    """``fn`` of the ``buffers``' live rows, chunk by chunk, as new
+    buffers of ``like``'s ``(width, dtype)``s; the rows behind the live
+    ones are left as the memory was (nothing reads them: a pass over
+    the worst case's 65,536 rows, or a memset of them, costs 0.4-1.0 ms
+    on the chip, sixteen times a layer)."""
+    def body(start, out):
+        made = fn(*(lax.dynamic_slice_in_dim(buffer, start, GROUPED_ROWS)
+                    for buffer in buffers))
+        return tuple(
+            lax.dynamic_update_slice_in_dim(o, r.astype(o.dtype), start, 0)
+            for o, r in zip(out, made))
+
+    return _live_chunks(count, body, tuple(
+        lax.empty((buffers[0].shape[0], width), dtype)
+        for width, dtype in like))
+
+
+def _spread(source, position, count):
+    """``source (N, width)``'s row ``position[r]`` in each live row
+    ``r`` of a buffer as long as ``position``."""
+    return _row_by_row(count, lambda at: (source[at],), [position],
+                       [(source.shape[1], source.dtype)])[0]
+
+
+def _collect(buffers, position, count, N):
+    """``_spread``'s transpose: the sum of the ``buffers``' live rows
+    ``r`` into row ``position[r]`` of ``(N, width)``, in float32.  The
+    chip adds a live row where it belongs in ~0.14 us, and gathers one
+    of the worst case's 65,536 rows, live or not, in ~0.04."""
+    width = buffers[0].shape[1]
+
+    def body(start, out):
+        rows = sum(lax.dynamic_slice_in_dim(
+            buffer, start, GROUPED_ROWS).astype(jnp.float32)
+            for buffer in buffers)
+        live = (start + jnp.arange(GROUPED_ROWS) < count)[:, None]
+        at = lax.dynamic_slice_in_dim(position, start, GROUPED_ROWS)
+        return out.at[at].add(jnp.where(live, rows, 0))
+
+    return _live_chunks(count, body, jnp.zeros((N, width), jnp.float32))
+
+
+def _silu_and_slope(g):
+    s = jax.nn.sigmoid(g)
+    return g * s, s * (1 + g * (1 - s))
+
+
+def _float32(fn):
+    return lambda *rows: fn(*(r.astype(jnp.float32) for r in rows))
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _grouped(m, here, weights, w1, w3, w2, interpret):
+    return _grouped_forward(m, here, weights, w1, w3, w2, interpret)[0]
+
+
+def _grouped_forward(m, here, weights, w1, w3, w2, interpret):
+    N, k = weights.shape
+    d, f = w1.shape[1:]
+    product, _ = _products(_grouped_tiles(N * k, d, f), interpret)
+    sizes = here.sum((0, 1)).astype(jnp.int32)
+    src, by_row, live = _sorted_picks(here, weights)
+    position, count = src // k, sizes.sum()
+    x = _spread(m, position, count)                      # (N * k, d)
+    g1 = product(x, w1, sizes, m.dtype)
+    g3 = product(x, w3, sizes, m.dtype)
+    # as the dense path: the weight meets the hidden, in float32
+    h, = _row_by_row(
+        count, _float32(lambda g1, g3, w: (jax.nn.silu(g1) * g3 * w,)),
+        [g1, g3, by_row], [(f, m.dtype)])
+    y = product(h, w2, sizes, m.dtype)
+    out = _collect([y], position, count, N).astype(m.dtype)
+    return out, (x, g1, g3, h, by_row, w1, w3, w2, sizes, src, live)
+
+
+def _grouped_backward(interpret, kept, ct):
+    x, g1, g3, h, by_row, w1, w3, w2, sizes, src, live = kept
+    N, k = live.shape
+    f = h.shape[1]
+    product, outer = _products(
+        _grouped_tiles(src.shape[0], x.shape[1], f), interpret)
+    position, count = src // k, sizes.sum()
+    d_y = _spread(ct, position, count)
+    d_h = product(d_y, w2, sizes, x.dtype, transposed=True)
+
+    def back(d_h, g1, g3, w):
+        silu, slope = _silu_and_slope(g1)
+        return (d_h * w * g3 * slope, d_h * w * silu,
+                (d_h * silu * g3).sum(-1, keepdims=True))
+
+    d_g1, d_g3, d_by_row = _row_by_row(
+        count, _float32(back), [d_h, g1, g3, by_row],
+        [(f, x.dtype), (f, x.dtype), (1, by_row.dtype)])
+    d_weights = jnp.where(
+        live, _to_picks(src, d_by_row[:, 0]).reshape(live.shape), 0)
+    d_m = _collect(
+        [product(d_g1, w1, sizes, x.dtype, transposed=True),
+         product(d_g3, w3, sizes, x.dtype, transposed=True)],
+        position, count, N)
+    return (d_m.astype(x.dtype), None, d_weights,
+            outer(x, d_g1, sizes, w1.dtype), outer(x, d_g3, sizes, w3.dtype),
+            outer(h, d_y, sizes, w2.dtype))
+
+
+_grouped.defvjp(_grouped_forward, _grouped_backward)
+
+
+def grouped_experts(m, here, weights, w1, w3, w2, interpret=False):
+    """The same sum as ``dense_experts`` over the picks that fall on
+    held experts ALONE.  The picks are sorted by expert, the live ones
+    first (``_sorted_picks``), into buffers of the worst case's ``N *
+    k`` rows of which only the live rows are ever written or read: the
+    live picks' rows of ``m`` are spread there (``_spread``), three
+    grouped products run over them whose kernels visit only tiles of
+    live rows (``_products``), the hidden between them is made chunk by
+    live chunk, and each live row's result is added to its position
+    (``_collect``).  Coming back every step is its transpose, by hand
+    (``_grouped_backward``).  No capacity, no pick dropped, and a time
+    that turns on the live rows alone.  ``interpret`` runs the kernels'
+    bodies as plain JAX (tier-1, on the CPU)."""
+    # one dtype through the products, as the dense path's promotion
+    m, w1, w3, w2 = (a.astype(jnp.result_type(m, w1, w3, w2))
+                     for a in (m, w1, w3, w2))
+    return _grouped(m, here, weights, w1, w3, w2, interpret)
+
+
 def held_experts(m, selected, weights, kernels, sizes, valid=None):
     """What this chip's experts add: ``sum over selected e HELD HERE of
     w_e * SwiGLU_e(m)`` for ``m (N, d)``, and the positions routed to
     each held expert ``(experts_held,)``.  Rows that are not ``valid
     (N,)`` (padding past an episode's end, which reaches no loss term
-    and no real position) take no expert.
+    and no real position) take no expert.  No pick is dropped.
 
-    Every held expert runs over every position, its part weighted by
-    the router's weight where it was selected and by nought where not:
-    the held stack as ONE SwiGLU of width ``held * expert_width``, three
-    dense products.  At sixteen held experts of 1,024 that is sixteen
-    times the arithmetic the picks need (an eighth of all fall here by
-    expectation), and the chip still does it sooner and, above all,
-    in the same time whatever the picks were: sorted picks through
-    ``lax.ragged_dot`` with a gather before and a scatter-add after
-    cost 180-234 ms a step by the seed's router, row by row
-    (``PERF.md`` section 6, PR 33).  No pick is dropped."""
+    Two paths, chosen where the program is lowered and by shapes
+    alone.  For a TPU, over a whole window at lane-wide shapes:
+    ``grouped_experts``, the arithmetic the picks need.  Everywhere
+    else (a CPU, the actors' one-token step, the tiny presets):
+    ``dense_experts``, sixteen times that arithmetic at sixteen held
+    experts of which an eighth of the picks fall here, in a time that
+    does not turn on the picks; it stays as the statement the grouped
+    path is held to.  (Sorted picks through ``lax.ragged_dot`` between
+    a gather and a scatter-add cost 180-234 ms a step: PR 33.)"""
     held = sizes.experts_held
-    w1, w3, w2 = kernels                       # (held, d, f) x 2, (held, f, d)
     here = selected[..., None] == sizes.first_expert + jnp.arange(held)
     if valid is not None:
         here = here & valid[:, None, None]
-    gate = jnp.where(here, weights[..., None], 0.0).sum(1)    # (N, held)
-    h = (jax.nn.silu(jnp.einsum("nd,edf->nef", m, w1))
-         * jnp.einsum("nd,edf->nef", m, w3))
-    h = (h * gate[..., None]).astype(m.dtype)
-    return jnp.einsum("nef,efd->nd", h, w2), here.sum((0, 1))
+    counts = here.sum((0, 1))
+    operands = (m, here, weights) + tuple(kernels)
+    d, f = kernels[0].shape[1:]
+    if _grouped_tiles(weights.size, d, f) is None:
+        return dense_experts(*operands), counts
+    return lax.platform_dependent(
+        *operands, default=dense_experts, tpu=grouped_experts), counts
 
 
 class SparseExperts(nn.Module):

@@ -28,3 +28,15 @@ def _disarm_telemetry():
 
     telemetry.configure(enabled=False)
     yield
+
+
+@pytest.fixture()
+def chips_path(monkeypatch):
+    """What ``lax.platform_dependent`` would pick for a TPU, taken here
+    in a sequence net's module: the chip's kernels, their bodies run as
+    plain JAX (``interpret``)."""
+    from handyrl_tpu.models import sequence_net
+
+    monkeypatch.setattr(
+        sequence_net.lax, "platform_dependent",
+        lambda *operands, default, tpu: tpu(*operands, interpret=True))

@@ -226,34 +226,88 @@ def test_the_last_two_rows_of_an_episode_and_all_padding_take_no_term(
 
 # -- the share ---------------------------------------------------------------
 
-def test_the_shares_of_all_chips_add_up_to_the_uncut_layer(tiny_geometry):
+# an expert layer of this preset's form at lane-wide widths, which the
+# grouped path takes: 2 x 128 positions x 2 picks are one tile of rows
+LANE_WIDE = TINY._replace(hidden=128, expert_width=128)
+
+
+@pytest.mark.parametrize("body", ["dense", "grouped"])
+def test_the_shares_of_all_chips_add_up_to_the_uncut_layer(
+        body, tiny_geometry, request):
     """Every chip's held experts' part of one expert layer of this
     preset, the shared expert counted once, is what the uncut reference
-    gives for the whole layer."""
-    shares = TINY.experts // TINY.experts_held
-    whole = sn.SparseExperts(TINY._replace(experts_held=TINY.experts))
-    m = jax.random.normal(jax.random.PRNGKey(0), (2, 16, TINY.hidden))
+    gives for the whole layer: by the dense stack at the tiny preset's
+    widths, and by the grouped products' bodies at lane-wide ones."""
+    z, positions = TINY, 16
+    if body == "grouped":
+        request.getfixturevalue("chips_path")
+        z, positions = LANE_WIDE, 128
+        assert sn._grouped_tiles(
+            2 * positions * z.experts_per_token, z.hidden, z.expert_width)
+    shares = z.experts // z.experts_held
+    whole = sn.SparseExperts(z._replace(experts_held=z.experts))
+    m = jax.random.normal(jax.random.PRNGKey(0), (2, positions, z.hidden))
     shapes = jax.eval_shape(
         lambda: whole.init(jax.random.PRNGKey(0), m))["params"]
     params = weights.make_params(shapes, 11, (), ["experts"], ["router"])
-    flat = m.reshape(-1, TINY.hidden)
+    flat = m.reshape(-1, z.hidden)
     uncut = joyai_net.experts(flat, params, None, joyai_net.GEOMETRY)
     shared = joyai_net.swiglu(
         flat, *(params["shared"][k]["kernel"] for k in ("w1", "w3", "w2")),
         None)
     total, picks = 0.0, 0
     for share in range(shares):
-        first = share * TINY.experts_held
+        first = share * z.experts_held
         held = dict(params, experts=jax.tree.map(
-            lambda k: k[first:first + TINY.experts_held],
+            lambda k: k[first:first + z.experts_held],
             params["experts"]))
         y, load = sn.SparseExperts(
-            TINY._replace(first_expert=first)).apply({"params": held}, m)
-        total = total + y.reshape(-1, TINY.hidden) - shared
+            z._replace(first_expert=first)).apply({"params": held}, m)
+        total = total + y.reshape(-1, z.hidden) - shared
         picks += int(load.sum())
     np.testing.assert_allclose(total + shared, uncut, atol=5e-6)
-    assert picks == 32 * TINY.experts_per_token
-    assert joyai_net.GEOMETRY["route_scale"] == TINY.route_scale == 2.5
+    assert picks == 2 * positions * z.experts_per_token
+    assert joyai_net.GEOMETRY["route_scale"] == z.route_scale == 2.5
+
+
+def test_an_expert_three_quarters_of_a_tile_wide_takes_its_own_tiles(
+        monkeypatch):
+    """This net's experts are 768 wide against a residual of 2,048: a
+    product's contracted and output widths take each its own tile (768
+    whole, 2,048 in two), going forward and coming back.  Held here at
+    an eighth of those widths in tiles of 128: 384 in three, 256 in
+    two, the kernels' bodies as plain JAX against the dense stack."""
+    z = sn.PRESETS["joyai_flash_ep16"]
+    assert sn._grouped_tiles(
+        z.sequence_length * z.experts_per_token, z.hidden,
+        z.expert_width) == {2048: 1024, 768: 768}
+    monkeypatch.setattr(sn, "GROUPED_WIDTH", 128)
+    N, k, held, d, f = 128, 4, 4, 256, 384
+    assert sn._grouped_tiles(N * k, d, f) == {d: 128, f: 128}
+    keys = jax.random.split(jax.random.PRNGKey(6), 7)
+    m = jax.random.normal(keys[0], (N, d))
+    kernels = tuple(
+        jax.random.normal(key, shape) / np.sqrt(shape[1])
+        for key, shape in zip(keys[1:4], [
+            (held, d, f), (held, d, f), (held, f, d)]))
+    selected = jax.lax.top_k(jax.random.uniform(keys[4], (N, 16)), k)[1]
+    router = jax.random.uniform(keys[5], (N, k), minval=0.1)
+    weight = jax.random.normal(keys[6], m.shape)
+    here = selected[..., None] == jnp.arange(held)
+    assert 0 < int(here.sum()) < N * k
+
+    def both(experts):
+        def scalar(m, router, *kernels):
+            return (experts(m, here, router, *kernels) * weight).sum()
+        return (experts(m, here, router, *kernels),
+                jax.grad(scalar, argnums=range(5))(m, router, *kernels))
+
+    (got, got_back), (want, want_back) = both(
+        lambda *operands: sn.grouped_experts(*operands, interpret=True)), \
+        both(sn.dense_experts)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    for g, w in zip(got_back, want_back):       # the router's reach ~10
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
 
 
 # -- the rotation ------------------------------------------------------------

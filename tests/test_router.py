@@ -268,7 +268,10 @@ def test_announcer_registers_beats_and_reregisters_after_eviction():
         router.registry.sweep(now=router.clock() + 100.0)
         _wait(lambda: router.registry.generation("r0") == 1,
               msg="re-register never landed")
-        assert ann.registrations >= 2
+        # the announcer counts a registration once the answer is back,
+        # a moment after the registry has recorded it
+        _wait(lambda: ann.registrations >= 2,
+              msg="the announcer never counted its re-registration")
         # graceful close sends the drain goodbye: the entry survives
         # (in-flight completes) but is never picked again
         ann.close()
@@ -462,6 +465,9 @@ def test_per_replica_sheds_reroute_but_pool_wide_sheds_escalate():
         # jam BOTH: every attempted replica sheds — the POOL breached,
         # and the escalation is typed pool_overload (counted)
         fe1 = pool.frontends[1]
+        # the replica lowers its count a moment AFTER it has answered:
+        # jam it only once the four requests above have left it
+        _wait(lambda: fe1.inflight == 0, msg="a request never left")
         fe1.inflight = fe1.cfg.max_inflight
         with pytest.raises(ShedError) as err:
             client.infer_batch(batch)

@@ -166,33 +166,49 @@ def test_the_one_sequence_loss_is_the_general_reference_loss(
                                    atol=1e-6 * float(jnp.abs(b).max()))
 
 
-def test_the_shares_of_all_chips_add_up_to_the_uncut_layer(tiny_geometry):
+# an expert layer of the tiny preset's form at lane-wide widths, which
+# the grouped path takes: 2 x 128 positions x 2 picks are one tile of rows
+LANE_WIDE = TINY._replace(hidden=128, expert_width=128)
+
+
+@pytest.mark.parametrize("body", ["dense", "grouped"])
+def test_the_shares_of_all_chips_add_up_to_the_uncut_layer(
+        body, tiny_geometry, request):
     """Every chip's held experts' part, the shared expert counted once,
-    is what the uncut reference gives for the whole layer."""
-    shares = TINY.experts // TINY.experts_held
-    whole = sn.SparseExperts(TINY._replace(experts_held=TINY.experts))
-    m = jax.random.normal(jax.random.PRNGKey(0), (2, 16, TINY.hidden))
+    is what the uncut reference gives for the whole layer: by the dense
+    stack at the tiny preset's widths, and by the grouped products'
+    bodies at lane-wide ones."""
+    z, positions = TINY, 16
+    if body == "grouped":
+        request.getfixturevalue("chips_path")
+        z, positions = LANE_WIDE, 128
+        assert sn._grouped_tiles(
+            2 * positions * z.experts_per_token, z.hidden, z.expert_width)
+    shares = z.experts // z.experts_held
+    whole = sn.SparseExperts(z._replace(experts_held=z.experts))
+    m = jax.random.normal(jax.random.PRNGKey(0), (2, positions, z.hidden))
     shapes = jax.eval_shape(
         lambda: whole.init(jax.random.PRNGKey(0), m))["params"]
     params = weights.make_params(shapes, 11, (), ["experts"], ["router"])
     uncut = trinity_net.experts(
-        m.reshape(-1, TINY.hidden), params, None, trinity_net.GEOMETRY)
+        m.reshape(-1, z.hidden), params, None, trinity_net.GEOMETRY)
     shared = trinity_net.swiglu(
-        m.reshape(-1, TINY.hidden), *(params["shared"][k]["kernel"]
-                                      for k in ("w1", "w3", "w2")), None)
+        m.reshape(-1, z.hidden), *(params["shared"][k]["kernel"]
+                                   for k in ("w1", "w3", "w2")), None)
     total, loads = 0.0, []
     for share in range(shares):
-        first = share * TINY.experts_held
+        first = share * z.experts_held
         held = dict(params, experts=jax.tree.map(
-            lambda k: k[first:first + TINY.experts_held],
+            lambda k: k[first:first + z.experts_held],
             params["experts"]))
         y, load = sn.SparseExperts(
-            TINY._replace(first_expert=first)).apply({"params": held}, m)
-        total = total + y.reshape(-1, TINY.hidden) - shared
+            z._replace(first_expert=first)).apply({"params": held}, m)
+        total = total + y.reshape(-1, z.hidden) - shared
         loads.append(load)
     np.testing.assert_allclose(total + shared, uncut, atol=5e-6)
     # every pick fell on some chip's expert
-    assert int(sum(l.sum() for l in loads)) == 32 * TINY.experts_per_token
+    assert int(sum(l.sum() for l in loads)) == (
+        2 * positions * z.experts_per_token)
 
 
 def test_padding_takes_no_expert_and_moves_no_real_position(model):
@@ -351,6 +367,169 @@ def test_the_tiny_preset_and_a_cpu_lowering_take_the_xla_path(model):
     np.testing.assert_array_equal(
         sn.window_attention(q, k, v, FUSED["window"], 128),
         sn.blocked_attention(q, k, v, FUSED["window"], 128))
+
+
+# -- the grouped products against the dense stack they replace -------------
+
+# lane-wide and small: 256 positions of 4 picks (two tiles of 512 rows),
+# 4 of 16 experts held, 256 wide against experts of 128
+GROUPED = dict(N=256, k=4, held=4, experts=16, d=256, f=128)
+
+
+def _routing(case):
+    """``selected (N, k)``, ``first_expert`` and ``valid (N,)`` of a
+    case, and how many picks it must leave live (None: as they fall)."""
+    z = GROUPED
+    N, k, held, E = z["N"], z["k"], z["held"], z["experts"]
+    n, j = jnp.arange(N)[:, None], jnp.arange(k)[None]
+    drawn = jax.lax.top_k(
+        jax.random.uniform(jax.random.PRNGKey(12), (N, E)), k)[1]
+    valid = jnp.ones((N,), bool)
+    if case == "even":                  # every expert as often as any
+        return (n + j * (E // k)) % E, 0, valid, N * k * held // E
+    if case == "one_expert":            # every position on held expert 2
+        return jnp.broadcast_to(
+            jnp.asarray([E - 1, 2, E - 2, E - 3]), (N, k)), 0, valid, N
+    if case == "none_held":
+        return jnp.broadcast_to(held + j, (N, k)), 0, valid, 0
+    if case == "all_held":              # the worst case fills the buffer
+        return (n + j) % held, 0, valid, N * k
+    if case == "held_in_the_middle":    # experts 6-9: routed below, above
+        assert int((drawn < 6).sum()) and int((drawn > 9).sum())
+        return drawn, 6, valid, None
+    if case == "padded_tail":
+        return drawn, 0, valid.at[N - 56:].set(False), None
+    assert case == "part_of_a_tile"
+    return drawn, 0, valid, None
+
+
+@pytest.mark.parametrize("case", [
+    "even", "one_expert", "none_held", "all_held", "held_in_the_middle",
+    "padded_tail", "part_of_a_tile"])
+def test_the_grouped_products_equal_the_dense_stack_out_and_back(case):
+    """The kernels' bodies run as plain JAX (``interpret``), float32:
+    the output and the gradient of every operand (``m``, the router's
+    ``weights``, each expert's three kernels) against the dense
+    stack's, and the positions counted for each held expert."""
+    z = GROUPED
+    keys = jax.random.split(jax.random.PRNGKey(5), 6)
+    m = jax.random.normal(keys[0], (z["N"], z["d"]))
+    kernels = tuple(
+        jax.random.normal(key, shape) / np.sqrt(shape[1])
+        for key, shape in zip(keys[1:4], [
+            (z["held"], z["d"], z["f"]), (z["held"], z["d"], z["f"]),
+            (z["held"], z["f"], z["d"])]))
+    weights_ = jax.random.uniform(keys[4], (z["N"], z["k"]), minval=0.1)
+    weight = jax.random.normal(keys[5], m.shape)
+    selected, first, valid, live = _routing(case)
+    sizes = TINY._replace(experts=z["experts"], experts_held=z["held"],
+                          first_expert=first, experts_per_token=z["k"])
+    here = (selected[..., None] == first + jnp.arange(z["held"])) \
+        & valid[:, None, None]
+    if live is not None:
+        assert int(here.sum()) == live
+    if case == "part_of_a_tile":
+        assert int(here.sum()) % sn.GROUPED_ROWS
+    assert sn._grouped_tiles(z["N"] * z["k"], z["d"], z["f"])
+
+    def grouped(*operands):
+        return sn.grouped_experts(*operands, interpret=True)
+
+    def both(experts):
+        def scalar(m, weights_, *kernels):
+            return (experts(m, here, weights_, *kernels) * weight).sum()
+        return (experts(m, here, weights_, *kernels),
+                jax.grad(scalar, argnums=range(5))(m, weights_, *kernels))
+
+    (got, got_back), (want, want_back) = both(grouped), both(sn.dense_experts)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    for g, w in zip(got_back, want_back):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(g, w, atol=1e-5)
+    out, counts = sn.held_experts(
+        m, selected, weights_, kernels, sizes, valid)
+    np.testing.assert_array_equal(out, want)    # a CPU lowering: dense
+    np.testing.assert_array_equal(counts, here.sum((0, 1)))
+    if case == "none_held":
+        assert not np.asarray(got).any()
+        assert not any(np.asarray(g).any() for g in got_back[2:])
+    if case == "padded_tail":
+        # rows that are not valid take no expert and move nothing real
+        moved = grouped(m.at[~valid].add(1.0), here, weights_, *kernels)
+        np.testing.assert_array_equal(moved[valid], got[valid])
+        assert not np.asarray(got[~valid]).any()
+        assert not np.asarray(got_back[0][~valid]).any()
+
+
+def test_the_grouped_path_serves_the_second_trace_of_a_step(chips_path):
+    """PR 34's fault, held for this kernel too: tier-1 and
+    ``step_profile()`` trace the step twice, and whatever the first
+    trace built must serve the second."""
+    layer = sn.SparseExperts(LANE_WIDE)
+    m = jax.random.normal(jax.random.PRNGKey(0), (2, 128, LANE_WIDE.hidden))
+    params = layer.init(jax.random.PRNGKey(1), m)
+
+    def loss(params, m):
+        y, counts = layer.apply(params, m)
+        return (y * y).sum(), counts
+
+    texts, got = [], []
+    for _ in range(2):                  # two functions: two traces
+        step = jax.jit(jax.value_and_grad(
+            lambda p, m: loss(p, m), has_aux=True))
+        texts.append(step.lower(params, m).as_text())
+        got.append(step(params, m))
+    assert texts[0] == texts[1]
+    for a, b in zip(jax.tree.leaves(got[0]), jax.tree.leaves(got[1])):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_a_whole_net_by_the_grouped_body_equals_the_dense_body(request):
+    """The net's whole-window pass with rematerialised layers, padding
+    past an episode's end and every gradient leaf: the chip's path of
+    the expert layers (bodies as plain JAX) against the dense stack."""
+    net = sn.SequencePolicyNet(LANE_WIDE._replace(sequence_length=128))
+    tokens = jax.random.randint(
+        jax.random.PRNGKey(2), (2, 128), 0, TINY.vocab).at[1, 100:].set(-1)
+    params = net.init(jax.random.PRNGKey(3), tokens, None)
+
+    def loss(params):
+        out = net.apply(params, tokens, None)
+        return (jnp.square(_logits(out)).mean() + out["value"].sum(),
+                out["expert_load"])
+
+    want = jax.value_and_grad(loss, has_aux=True)(params)
+    request.getfixturevalue("chips_path")
+    got = jax.value_and_grad(loss, has_aux=True)(params)
+    np.testing.assert_array_equal(got[0][1], want[0][1])
+    assert int(got[0][1].sum()) > 0
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
+
+
+def test_the_tiny_presets_and_a_cpu_lowering_take_the_dense_stack(model):
+    """Which path runs is read from what the program can observe: an
+    expert narrower than a lane, or rows that are no whole tile (the
+    actors' one-token step), never reach the grouped products, and at
+    lane-wide shapes a program lowered for the CPU holds none."""
+    for preset in ("tiny", "tiny_latent"):
+        z = sn.PRESETS[preset]
+        assert sn._grouped_tiles(
+            4 * 32 * z.experts_per_token, z.hidden, z.expert_width) is None
+    for preset in ("trinity_mini_ep8", "joyai_flash_ep16"):
+        z = sn.PRESETS[preset]
+        assert sn._grouped_tiles(
+            8192 * z.experts_per_token, z.hidden, z.expert_width) == {
+                z.hidden: 1024, z.expert_width: min(1024, z.expert_width)}
+        # one token a seat: the dense stack
+        assert sn._grouped_tiles(
+            4 * z.experts_per_token, z.hidden, z.expert_width) is None
+    layer = sn.SparseExperts(LANE_WIDE)
+    m = jax.random.normal(jax.random.PRNGKey(0), (2, 128, LANE_WIDE.hidden))
+    params = layer.init(jax.random.PRNGKey(1), m)
+    wide = jax.jit(jax.grad(
+        lambda p, m: layer.apply(p, m)[0].sum())).lower(params, m).as_text()
+    assert "tpu_custom_call" not in wide
 
 
 # -- wire, ring and gather without a mask -----------------------------------
