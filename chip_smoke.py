@@ -160,8 +160,8 @@ def check_training(learner, records, platform):
     steps_last = steps[-1] - steps[-2]
     # epoch 0's step section holds the cold compile (the cost harvest's
     # and the call's own); what steady steps cost is read off the last
-    per_step = last["device_step_sec"] / max(steps_last, 1)
-    compile_sec = first["device_step_sec"] - steps[0] * per_step
+    per_step = last["profile_update_sec"] / max(steps_last, 1)
+    compile_sec = first["profile_update_sec"] - steps[0] * per_step
     mean_len = float(replay.ep_len[:replay.size].mean())
     ring_mb = sum(leaf.nbytes for leaf in jax.tree.leaves(
         replay.buffers)) / 2**20
@@ -170,14 +170,17 @@ def check_training(learner, records, platform):
           f"{compile_sec:.1f}s")
     print(f"last epoch: {steps_last} steps in {last['epoch_wall_sec']}s "
           f"wall = {steps_last / last['epoch_wall_sec']:.2f} steps/s end "
-          f"to end (intake-paced); {last['device_step_sec']}s in step "
-          f"dispatch, {last.get('profile_ingest_sec')}s in ring ingest")
+          f"to end (intake-paced); {last['profile_update_sec']}s in step "
+          f"dispatch, {last.get('profile_ingest_sec')}s in ring ingest; a "
+          f"step in flight for {last['device_step_sec']}s, the device "
+          f"starved for {last['starved_sec']}s, median run-ahead "
+          f"{last['run_ahead_p50']} steps")
     print(f"steps by epoch: {steps}; retrace_count {retraces}, ring "
           f"growths {replay.growths} (t_max {replay.t_max}, capacity "
           f"{replay.capacity}, {ring_mb:.0f} MiB of arrays)")
     print(f"mfu {last['mfu']} achieved_tflops {last['achieved_tflops']} "
-          f"roofline {last['roofline_verdict']} (cost model, dispatch "
-          f"seconds)")
+          f"roofline {last['roofline_verdict']} (cost model, over the "
+          f"seconds a step was in flight)")
     print(f"peak HBM: {stats.get('peak_bytes_in_use')} bytes of "
           f"{stats.get('bytes_limit')}")
     print(f"episodes received {learner.episodes_received}, into the ring "

@@ -33,6 +33,7 @@ import random
 import threading
 import time
 from collections import deque
+from contextlib import contextmanager
 
 import jax
 import numpy as np
@@ -449,6 +450,10 @@ class Trainer:
         self._replicate_jit = None
         self.prefetcher = None
         self.timers = SectionTimers()
+        # how far ahead of the device this thread runs, and when the
+        # device had no step (telemetry.inflight): fed where a loop
+        # dispatches a step, polled at edges the thread already has
+        self.inflight = telemetry.InFlight()
         self.trace = TraceWindow(self.args.get("profile_dir") or "",
                                  hlo_text=self._step_hlo_text)
         self._run_thread = None       # the thread inside run(), if any
@@ -464,8 +469,8 @@ class Trainer:
         # guard's on_compile hook harvests XLA's own flops/bytes for
         # each step program at its (rare) new-signature moments, and
         # train() reduces them into per-epoch mfu/achieved_tflops/
-        # roofline keys next to the guard counters, every run (read
-        # them as upper bounds: they divide by dispatch seconds)
+        # roofline keys next to the guard counters, every run, over
+        # the seconds in which a step was in flight
         from .telemetry.costmodel import CostModel, PerfConfig
 
         self.costmodel = CostModel(
@@ -540,6 +545,9 @@ class Trainer:
 
         self.device_replay = (None if self.anakin is not None
                               else self._maybe_device_replay())
+        if self.device_replay is not None:
+            # ingest runs on this thread, between two dispatches
+            self.device_replay.inflight = self.inflight
         self._replay_step = None
         if self.device_replay is not None and not self.multihost:
             # ONE jitted program per step: draw + gather + loss + grad
@@ -1006,8 +1014,17 @@ class Trainer:
                 if self.failure is not None or self.shutdown_flag:
                     return None, self.steps
 
+    @contextmanager
+    def _dispatching(self):
+        """The ``update`` section of a loop: the dispatch timed as
+        ever, its span given the ledger's ``depth`` and ``done``."""
+        attrs = {}
+        with self.timers.section("update", attrs=attrs), \
+                self.inflight.watch("update", attrs):
+            yield
+
     def _do_update(self, batch):
-        with self.timers.section("update"):
+        with self._dispatching():
             if self.target_params is not None:
                 (self.params, self.opt_state, metrics,
                  self.target_params) = self.update_step(
@@ -1016,6 +1033,7 @@ class Trainer:
             else:
                 self.params, self.opt_state, metrics = self.update_step(
                     self.params, self.opt_state, batch)
+            self.inflight.launch(metrics["total"])
         self.trace.tick()
         self.steps += 1
         return metrics
@@ -1075,6 +1093,7 @@ class Trainer:
             if cap and batch_cnt >= cap:
                 # epoch budget spent: idle until the learner asks for
                 # the snapshot, releasing host CPU to the actors
+                self.inflight.poll("cap")
                 time.sleep(0.01)
                 continue
             if state is None:
@@ -1087,10 +1106,13 @@ class Trainer:
                 # changes the whole state lives on device and rides
                 # the jit
                 state = replay.device_state(self.steps), state[1]
-            with self.timers.section("update"):
+            with self._dispatching():
                 # the step's own metrics are dropped as they return:
-                # the boundary reads the sums the step carries
+                # the boundary reads the sums the step carries; the
+                # ledger keeps one scalar of them, which no call
+                # donates, until the device has run the step
                 metrics, state = self._fused_step(state)
+                self.inflight.launch(metrics["total"])
             self.trace.tick()
             batch_cnt += 1
         # of the sums, those this step program adds to (a net with no
@@ -1216,7 +1238,7 @@ class Trainer:
                 time.sleep(0.01)
                 continue
             t0 = telemetry.span_begin()
-            with self.timers.section("update"):
+            with self._dispatching():
                 if self.target_params is not None:
                     (self.params, self.opt_state, metrics,
                      self.anakin_carry,
@@ -1228,6 +1250,7 @@ class Trainer:
                      self.anakin_carry) = self._anakin_step(
                         self.params, self.opt_state, self.anakin_carry,
                         self.anakin_pool)
+                self.inflight.launch(metrics["total"])
             # static attrs only: the committed frame count is a device
             # scalar, and fetching it here would be a per-step host
             # sync (it rides the metrics fetch at the epoch boundary)
@@ -1329,7 +1352,10 @@ class Trainer:
 
     def _drain(self, metrics):
         """The epoch's metric sums on the host, by ONE ``device_get``
-        that waits for every step still queued.  The fused replay
+        once every step still queued has run (the ledger waits them
+        out poll by poll, so that it knows to half a millisecond when
+        the device ran dry; with telemetry off the ``device_get``
+        itself does the waiting).  The fused replay
         step carried them on the device (``staging.epoch_sums``): a
         dozen scalars whatever the epoch's length.  The other loops'
         steps are other programs and hand over a list of per-step
@@ -1338,6 +1364,7 @@ class Trainer:
         per_step = isinstance(metrics, list)
         with telemetry.trace_span("boundary.drain") as span:
             span.attrs["arrays"] = len(jax.tree.leaves(metrics))
+            self.inflight.drain("boundary.drain")
             sums = jax.device_get(metrics)
             if per_step:
                 # releasing the device scalars is part of the drain,
@@ -1355,8 +1382,10 @@ class Trainer:
         print("loss = %s" % " ".join(
             [k + ":" + "%.3f" % (l / data_cnt) for k, l in loss_sum.items()]))
         prof = self.timers.snapshot()
+        flight = self.inflight.epoch()   # boundary to boundary, as prof
         if prof:
-            # batch_wait = feed starvation; update = device dispatch+step
+            # batch_wait = feed starvation; update = the dispatch and
+            # the wait on the runtime's full queue
             print("profile = %s" % self.timers.format(prof))
 
         self.data_cnt_ema = (
@@ -1384,14 +1413,21 @@ class Trainer:
         for name, v in prof.items():
             self.last_metrics[f"profile_{name}_sec"] = v["sec"]
         # pipeline telemetry, canonical keys (docs/observability.md):
-        # seconds the hot loop starved for its feed, seconds inside the
-        # device step dispatch, and the feed backlog at the epoch
-        # boundary.  Always present — the device-replay path simply has
-        # no batch wait (its draw rides the fused step)
+        # seconds the hot loop starved for its feed, seconds in which
+        # the device had a step in flight (since the last boundary's
+        # drain; the ledger's account), and the feed backlog at the
+        # epoch boundary.  Always present — the device-replay path
+        # simply has no batch wait (its draw rides the fused step), and
+        # with telemetry off the ledger holds nothing: device_step_sec
+        # is then the update section's seconds, the dispatch and the
+        # wait on the runtime's queue, as it was before the ledger
         self.last_metrics["batch_wait_sec"] = \
             prof.get("batch_wait", {}).get("sec", 0.0)
-        self.last_metrics["device_step_sec"] = \
-            prof.get("update", {}).get("sec", 0.0)
+        in_flight = flight.pop("in_flight_sec")
+        self.last_metrics["device_step_sec"] = (
+            in_flight if in_flight is not None
+            else prof.get("update", {}).get("sec", 0.0))
+        self.last_metrics.update(flight)   # starved_sec, run_ahead_p50
         self.last_metrics["queue_depth"] = self._queue_depth()
         # roofline/MFU keys (telemetry.costmodel): the harvested step
         # program's flops over this epoch's device-step seconds,

@@ -63,6 +63,7 @@ import numpy as np
 
 from .batch import BF16, ILLEGAL, _build_columnar
 from .telemetry import spans as _telemetry
+from .telemetry.inflight import InFlight
 from .utils.tree import tree_map
 
 
@@ -401,6 +402,10 @@ class DeviceReplay:
         self.dropped = 0
         self._lock = threading.Lock()
         self._state_dirty = True   # ring changed since last device_state
+        # the steps in flight, polled between the parts of an ingest:
+        # the ledger of the trainer whose thread calls ``ingest`` (it
+        # hands its own over); until then one that never holds a step
+        self.inflight = InFlight()
 
     def device_state(self, step_idx):
         """Device int32 ``[size, oldest, step_idx]``: the ring's half
@@ -473,7 +478,10 @@ class DeviceReplay:
             if not raw:
                 return
             with _telemetry.trace_span("ingest.decompress"):
-                cols = [_decompress_episode(ep) for ep in raw]
+                cols = []
+                for ep in raw:
+                    cols.append(_decompress_episode(ep))
+                    self.inflight.poll("ingest.decompress")
             for col, stamp in zip(cols, stamps):
                 col["offered_at"] = stamp
             done += len(cols)
@@ -709,7 +717,10 @@ class DeviceReplay:
         with _telemetry.trace_span("ingest.pad"):
             lens = [len(c["turn_idx"]) for c in cols]
             rows = [_round_up(t) for t in lens]
-            eps = [self._pad_episode(c, r) for c, r in zip(cols, rows)]
+            eps = []
+            for c, r in zip(cols, rows):
+                eps.append(self._pad_episode(c, r))
+                self.inflight.poll("ingest.pad")
             slots = [(self.write_ptr + i) % self.capacity
                      for i in range(k)]
             total = sum(rows)
@@ -741,8 +752,11 @@ class DeviceReplay:
             ep = {key: jax.tree.map(
                 cat_slots if key in _PER_SLOT else cat_steps,
                 *[e[key] for e in eps]) for key in eps[0]}
-        with _telemetry.trace_span("ingest.append") as span:
-            # the dispatch and the implicit upload of ``ep``
+        with _telemetry.trace_span("ingest.append") as span, \
+                self.inflight.watch("ingest.append", span.attrs):
+            # the dispatch and the implicit upload of ``ep``, and the
+            # wait wherever the runtime's queue is full of steps: the
+            # span's ``depth`` and ``done`` tell a held call apart
             self.buffers = self._append_fn(
                 self.buffers, ep, flat_idx, slot_idx)
             for s, t in zip(slots, lens):

@@ -26,7 +26,11 @@ Public surface (see :mod:`.spans` for the design notes):
     :mod:`.attribution` (the per-epoch self-time tree + the
     ``untracked_residual_sec`` wall-time reconciliation), surfaced in
     metrics.jsonl, the status ``perf`` section, and flight-recorder
-    dumps via ``register_dump_extra``.
+    dumps via ``register_dump_extra``;
+  * the in-flight ledger: :class:`.inflight.InFlight`, by which the
+    trainer thread knows how far ahead of the device it runs
+    (``device.starved`` spans, ``depth`` / ``done`` on its dispatch
+    spans, the seconds ``mfu`` divides by).
 """
 
 from .attribution import (  # noqa: F401
@@ -36,6 +40,7 @@ from .attribution import (  # noqa: F401
 )
 from .costmodel import CostModel, PerfConfig  # noqa: F401
 from .histogram import LatencyHistogram  # noqa: F401
+from .inflight import InFlight  # noqa: F401
 from .spans import (  # noqa: F401
     TRACE_HEAD,
     add_event,

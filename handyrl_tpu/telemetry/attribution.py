@@ -38,6 +38,13 @@ from . import spans as _spans
 # to 1e-6, so a child's rounded end may trail its parent's by an ulp
 _EPS = 2e-6
 
+# spans that lie OVER a thread's timeline without nesting in it: the
+# in-flight ledger's account of the device (telemetry/inflight.py),
+# recorded by the trainer thread about stretches that begin and end in
+# the middle of its own spans.  They keep their count and their total;
+# they are nobody's parent or child, and own no self time
+OVERLAYS = ("device.starved",)
+
 
 def self_time_tree(records):
     """Fold span records into ``{"role/name": {count, total_sec,
@@ -47,7 +54,8 @@ def self_time_tree(records):
     clock: a span is a child of the innermost still-open span of its
     thread that fully covers it, and each child's duration is
     subtracted from that parent's self time exactly once.  Zero-
-    duration instants (events) aggregate with zero time.  Records from
+    duration instants (events) aggregate with zero time; an overlay
+    (``OVERLAYS``) with its total and no self time.  Records from
     different processes never nest (per-thread stacks), they just
     share the timeline.
     """
@@ -84,8 +92,8 @@ def self_time_tree(records):
                 closed = stack.pop()
                 _fold(closed[0], closed[2],
                       max(0.0, closed[2] - closed[3]))
-            if dur <= 0.0:
-                _fold(key, 0.0, 0.0)  # instant event
+            if dur <= 0.0 or rec["name"] in OVERLAYS:
+                _fold(key, dur, 0.0)  # instant event, or an overlay
                 continue
             if stack and end <= stack[-1][1] + _EPS:
                 # fully inside the innermost open span: its child

@@ -3,10 +3,12 @@
 XLA's own flops and bytes per guarded program, reduced once per epoch
 into metrics.jsonl keys, every run (Podracer, arXiv:2104.06272, treats
 this decomposition as the primary dataflow-design signal).  The
-seconds it divides by are the trainer thread's DISPATCH seconds, on a
-device that runs steps ahead of the host: read ``mfu`` and
-``achieved_tflops`` as upper bounds, not as a utilization (the
-benchmark's device-time roofline is benchmarks/harness/roofline.py).
+seconds it divides by are those in which the device had a step in
+flight, from the trainer thread's in-flight ledger (:mod:`.inflight`:
+an epoch's wall less its ``device.starved`` spans; with telemetry off,
+the dispatch seconds as before): ``mfu`` and ``achieved_tflops`` read
+the device's rate while it has work (the benchmark's device-time
+roofline, from a trace, is benchmarks/harness/roofline.py).
 
 Three pieces:
 
@@ -35,7 +37,7 @@ Three pieces:
     their ``jax.named_scope`` (:mod:`.devtrace`).
 
   * **The epoch reduction** — :meth:`CostModel.epoch_metrics` turns
-    (steps this epoch, seconds inside the device step) into the
+    (steps this epoch, seconds with a step in flight) into the
     metrics.jsonl keys ``achieved_tflops`` / ``mfu`` /
     ``arithmetic_intensity`` / ``roofline_verdict``.  The verdict
     compares the program's arithmetic intensity (flops per HBM byte)

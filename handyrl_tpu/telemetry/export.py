@@ -19,6 +19,8 @@ import glob
 import json
 import os
 
+from .attribution import OVERLAYS
+
 
 def read_span_log(path):
     """One ``spans-*.jsonl`` file -> (meta dict, [span records])."""
@@ -66,7 +68,9 @@ def build_trace(spans, roles=None):
         ev = {
             "name": rec.get("name", "?"),
             "pid": rec.get("pid", 0),
-            "tid": rec.get("tid", 0),
+            # an overlay (the ledger's device.starved) nests in no
+            # thread's spans: it gets the process's track 0 to itself
+            "tid": 0 if rec.get("name") in OVERLAYS else rec.get("tid", 0),
             "ts": round(rec.get("ts", 0.0) * 1e6, 1),   # seconds -> us
         }
         dur = rec.get("dur", 0.0)

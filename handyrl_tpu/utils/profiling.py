@@ -54,7 +54,9 @@ class SectionTimers:
     read once per edge for both.  ``span=False`` keeps the seconds and
     records no span: for a section whose work records its own
     (``DeviceReplay.ingest`` writes ``trainer.ingest`` only when it
-    appended something)."""
+    appended something).  ``attrs`` is a dict the span takes as its
+    attrs; it is read when the section closes, so the block may fill
+    it in while it runs."""
 
     def __init__(self, span_prefix="trainer."):
         self.totals = defaultdict(float)
@@ -62,7 +64,7 @@ class SectionTimers:
         self.span_prefix = span_prefix
 
     @contextmanager
-    def section(self, name, span=True):
+    def section(self, name, span=True, attrs=None):
         mirror = _telemetry.mirror(self.span_prefix + name) if span \
             else None
         if mirror is not None:
@@ -77,7 +79,8 @@ class SectionTimers:
             self.totals[name] += dur
             self.counts[name] += 1
             if span:
-                _telemetry.record_span(self.span_prefix + name, t0, dur)
+                _telemetry.record_span(self.span_prefix + name, t0, dur,
+                                       **(attrs or {}))
 
     def snapshot(self, reset=True):
         """{name: {"sec": total, "n": count}}, optionally resetting."""

@@ -62,6 +62,7 @@ def epoch_trend(records):
             "roofline_verdict": rec.get("roofline_verdict"),
         }
         for key, share in (("batch_wait_sec", "batch_wait_share"),
+                           ("starved_sec", "starved_share"),
                            ("untracked_residual_sec",
                             "residual_share")):
             value = rec.get(key)
@@ -71,7 +72,7 @@ def epoch_trend(records):
         rows.append(row)
     medians = {}
     for key in ("mfu", "achieved_tflops", "batch_wait_share",
-                "residual_share", "epoch_wall_sec"):
+                "starved_share", "residual_share", "epoch_wall_sec"):
         values = [r[key] for r in rows
                   if isinstance(r.get(key), (int, float))]
         if values:
@@ -128,13 +129,15 @@ def render(report, diff=None, baseline_dir=None, top_n=15):
     trend = report["epoch_trend"]
     if trend:
         lines.append("")
-        lines.append("epoch trend (mfu / batch-wait share / "
+        lines.append("epoch trend (mfu while a step is in flight / "
+                     "batch-wait share / device-starved share / "
                      "residual share):")
         for row in trend[-10:]:
             lines.append(
                 f"  epoch {row['epoch']}: wall="
                 f"{row['epoch_wall_sec']}s mfu={row['mfu']} "
                 f"wait={row['batch_wait_share']} "
+                f"starved={row['starved_share']} "
                 f"residual={row['residual_share']} "
                 f"[{row['roofline_verdict']}]")
     if diff is not None:

@@ -210,15 +210,16 @@ def plot(epochs, out_prefix):
     # pipeline telemetry (handyrl_tpu.telemetry via the metrics jsonl):
     # policy_lag_* is the off-policy staleness of the consumed episodes
     # (an IMPALA learner's central health signal — a climbing lag means
-    # the actors cannot keep up with the update rate); batch_wait_sec
-    # vs device_step_sec splits each epoch's wall time into feed
-    # starvation vs device work, and queue_depth is the feed backlog at
-    # the epoch boundary
+    # the actors cannot keep up with the update rate); of each
+    # epoch's wall time batch_wait_sec is the feed's starvation,
+    # device_step_sec the seconds the device had a step in flight and
+    # starved_sec those it had none (the trainer thread's in-flight
+    # ledger); queue_depth is the feed backlog at the epoch boundary
     lag_keys = [k for k in ("policy_lag_mean", "policy_lag_p95",
                             "policy_lag_max", "queue_depth")
                 if any(k in e for e in epochs)]
     sec_keys = [k for k in ("batch_wait_sec", "device_step_sec",
-                            "epoch_wall_sec")
+                            "starved_sec", "epoch_wall_sec")
                 if any(k in e for e in epochs)]
     if lag_keys or sec_keys:
         fig, ax = plt.subplots(figsize=(8, 5))
@@ -437,9 +438,10 @@ def plot(epochs, out_prefix):
 
     # perf attribution (telemetry.costmodel/.attribution via the
     # metrics jsonl): mfu and achieved_tflops are the roofline
-    # accounting — flat-and-low with a memory-bound verdict means the
-    # batch/fusion shape caps throughput, not scheduling; the right
-    # axis shows each epoch's wall decomposed into the batch-wait and
+    # accounting over the seconds a step was in flight — flat-and-low
+    # with a memory-bound verdict means the batch/fusion shape caps
+    # throughput, not scheduling; the right axis shows each epoch's
+    # wall decomposed into the batch-wait, device-starved and
     # untracked-residual SHARES (fractions of epoch_wall_sec), so a
     # perf regression shows as one of the shares growing.  mfu is None
     # on hosts with no peak table row and no perf.* override — the
@@ -448,6 +450,7 @@ def plot(epochs, out_prefix):
                      if any(e.get(k) is not None for e in epochs)]
     perf_share_pairs = [
         ("batch_wait_sec", "batch_wait share"),
+        ("starved_sec", "device starved share"),
         ("untracked_residual_sec", "residual share"),
     ]
     have_shares = any(
@@ -460,7 +463,8 @@ def plot(epochs, out_prefix):
             if pts:
                 ax.plot(*zip(*pts), label=k, marker=".")
         ax.set_xlabel("epoch")
-        ax.set_ylabel("MFU (fraction) / achieved TFLOP/s")
+        ax.set_ylabel("MFU (fraction) / achieved TFLOP/s, "
+                      "while a step is in flight")
         ax2 = ax.twinx()
         for k, label in perf_share_pairs:
             pts = [(x, e[k] / e["epoch_wall_sec"])

@@ -170,6 +170,103 @@ def test_one_epoch_leaves_boundary_handoff_and_server_update_spans(
             "trainer.ingest", "ingest.append"} <= names
 
 
+# -- the in-flight ledger, end to end -----------------------------------
+
+def test_two_epochs_leave_the_ledgers_account_in_the_log_and_the_metrics(
+        tmp_path, monkeypatch):
+    """A tiny train on the CPU: ``trainer.update`` carries ``depth`` and
+    ``done``, every boundary's drain leaves nothing in flight and starts
+    a ``device.starved`` stretch that the next epoch's first launch
+    closes, and metrics.jsonl carries the epoch's account."""
+    monkeypatch.chdir(tmp_path)
+    from test_durability import _train_args
+
+    from handyrl_tpu.learner import Learner
+
+    _, _, episodes = _ttt(12)
+    learner = Learner(_train_args(extra_train={
+        "mesh": {"dp": 1}, "device_replay": "on",
+        "perf": {"peak_tflops": 1.0, "peak_hbm_gbs": 100.0}}))
+    trainer = learner.trainer
+    assert trainer.device_replay.inflight is trainer.inflight
+    left_in_flight = []
+    real_drain = trainer._drain
+
+    def drain(metrics):
+        sums = real_drain(metrics)
+        left_in_flight.append(len(trainer.inflight._tokens))
+        return sums
+
+    trainer._drain = drain
+    thread = threading.Thread(target=trainer.run, name="trainer")
+    try:
+        trainer.device_replay.offer(episodes)
+        thread.start()
+        for epoch in (1, 2):
+            deadline = time.time() + 120
+            while trainer.steps < 3 * epoch and time.time() < deadline:
+                time.sleep(0.01)
+            assert trainer.steps >= 3 * epoch, trainer.failure
+            learner.update()
+        deadline = time.time() + 120
+        while trainer.steps < 8 and time.time() < deadline:
+            time.sleep(0.01)        # epoch three's first launches
+    finally:
+        trainer.request_shutdown()
+        thread.join(timeout=60)
+        if learner.stall_watchdog is not None:
+            learner.stall_watchdog.stop()
+        if learner.wal is not None:
+            learner.wal.close()
+    assert trainer.failure is None
+    assert left_in_flight == [0, 0]
+    telemetry.flush()
+    from handyrl_tpu.telemetry.export import collect_run
+
+    _roles, logged = collect_run(str(tmp_path))
+    updates = [r for r in logged if r["name"] == "trainer.update"]
+    assert len(updates) == trainer.steps
+    assert all(set(r["attrs"]) == {"depth", "done"} for r in updates)
+    assert updates[0]["attrs"]["depth"] == 0      # nothing launched yet
+    appends = [r for r in logged if r["name"] == "ingest.append"]
+    assert appends and all(
+        {"depth", "done", "wait_ms"} <= set(r["attrs"]) for r in appends)
+    starved = [r for r in logged if r["name"] == "device.starved"]
+    drains = [r for r in logged if r["name"] == "boundary.drain"]
+    assert len(drains) == 2
+    for drain_span in drains:
+        # one stretch holds each boundary: begun at the drain's own
+        # poll or, where the step is so short that the queue was dry
+        # before, at the last dispatch's exit ...
+        drained = drain_span["ts"] + drain_span["dur"]
+        (stretch,) = [r for r in starved
+                      if r["ts"] <= drained < r["ts"] + r["dur"]]
+        assert stretch["attrs"]["at"] in ("boundary.drain", "update")
+        # ... and lasts through snapshot, checkpoint and hand-over, to
+        # the return of the next epoch's first dispatch
+        after = min(r["ts"] + r["dur"] for r in updates
+                    if r["ts"] > drain_span["ts"])
+        assert stretch["ts"] + stretch["dur"] == pytest.approx(
+            after, abs=5e-3)
+    # the run's first stretch begins at the ledger's first poll (the
+    # warm-up's ingest) and holds the step's compile
+    assert starved[0]["attrs"]["since_ms"] == 0.0
+    assert starved[0]["ts"] + starved[0]["dur"] == pytest.approx(
+        updates[0]["ts"] + updates[0]["dur"], abs=5e-3)
+    assert starved[0]["dur"] >= updates[0]["dur"] - 5e-3
+    with open("metrics.jsonl") as f:
+        records = [json.loads(line) for line in f if line.strip()]
+    assert len(records) == 2
+    for record in records:
+        assert record["starved_sec"] > 0
+        assert isinstance(record["run_ahead_p50"], int)
+        # seconds with a step in flight: no more than the epoch's wall,
+        # and with the starved ones the ledger's own wall
+        assert 0 < record["device_step_sec"] <= record["epoch_wall_sec"] + 0.05
+        assert record["profile_update_sec"] > 0      # dispatch, where it was
+        assert record["mfu"] > 0 and record["achieved_tflops"] > 0
+
+
 # -- the mirror onto the profiler's clock -------------------------------
 
 class _Annotation:
@@ -643,6 +740,76 @@ def test_step_phases_count_a_kernels_time_for_its_scope_and_as_kernel_ms():
     plain = devtrace.step_phases(_plain_trace())
     assert plain["kernel_ms"] == {}
     assert "kernel" not in devtrace.format_phases(plain)
+
+
+# -- the ledger held to a trace (scripts/inflight_check.py) ---------------
+
+def test_the_ledger_is_held_to_a_trace_on_one_clock():
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts"))
+    import inflight_check
+
+    ms = 1e6                                     # the trace counts in ns
+    # six steps of 3 ms; after the third the device waits 12 ms (an
+    # ingest call outlasted the queue), after the fifth 0.5 ms
+    starts = [0.0, 3.0, 6.0, 21.0, 24.0, 27.5]
+    trace = {"module": "jit_step", "op_names": {}, "planes": [
+        {"name": "/device:TPU:0", "lines": [
+            {"name": "XLA Modules", "events": [
+                ("jit_step(1)", 1e9 + t * ms, 3.0 * ms) for t in starts]
+             + [("jit_append(2)", 1e9 + 16.0 * ms, 0.2 * ms)]}]},
+        {"name": "/host:CPU", "lines": [{"name": "trainer", "events": [
+            ("hrl:trainer.update", 1e9 + t * ms, 0.4 * ms)
+            for t in (-3.0, 0.1, 2.0, 19.5, 22.0, 26.9)]
+            + [("hrl:trainer.ingest", 1e9 + 4.0 * ms, 15.0 * ms),
+               ("hrl:ingest.decompress", 1e9 + 4.0 * ms, 12.0 * ms),
+               ("hrl:ingest.append", 1e9 + 16.5 * ms, 2.0 * ms)]}]}]}
+    off = 500.0 - 1.0            # the telemetry clock reads 500 s at 1e9 ns
+
+    def rec(name, at_ms, dur_ms, **attrs):
+        out = {"name": name, "ts": 1.0 + off + at_ms * 1e-3,
+               "dur": dur_ms * 1e-3, "pid": 1, "tid": 7}
+        if attrs:
+            out["attrs"] = attrs
+        return out
+
+    records = [rec("trainer.update", t, 0.4, depth=1, done=0)
+               for t in (-9.0, -6.0, -3.0, 0.1, 2.0, 19.5, 22.0, 26.9, 30.0)]
+    records += [rec("trainer.ingest", 4.0, 15.0, episodes=3),
+                rec("ingest.decompress", 4.0, 12.0),
+                rec("ingest.append", 16.5, 2.0, depth=0, done=0),
+                # found dry at 10 ms, a poll after the one at 8: the
+                # device ran dry at 9; the launch returned at 19.9, the
+                # device had started at 21 by the trace's own latency
+                rec("device.starved", 10.0, 9.9, since_ms=2.0,
+                    at="ingest.decompress"),
+                # a dispatch that found the queue dry as it returned
+                rec("device.starved", 27.3, 0.0, since_ms=0.4, at="update")]
+    out = inflight_check.compare(trace, records, gap_ms=10.0)
+    assert out["clock_offset_s"] == pytest.approx(off)
+    assert out["steps"] == 6 and out["stretch_s"] == pytest.approx(30.5e-3)
+    assert out["trace_idle_s"] == pytest.approx(12.5e-3)
+    assert out["trace_idle_back_to_back_s"] == 0.0
+    assert out["ledger_spans_s"] == pytest.approx(9.9e-3)
+    assert out["ledger_upper_s"] == pytest.approx(12.3e-3)
+    # less the 0.4 ms of the stretch inside the dispatch that closed it
+    assert out["ledger_lower_s"] == pytest.approx(9.5e-3)
+    assert out["bracket_points"] == pytest.approx(100 * 2.8 / 30.5, abs=1e-3)
+    # 12.5 ms of idle lie 0.2 ms above the bracket: after the launch's
+    # return the device still takes the dispatch's hand-over, which no
+    # poll sees, and the script says so as it is
+    assert out["inside_bracket"] is False
+    assert (out["long_gaps"], out["long_gaps_covered"],
+            out["long_gaps_named_alike"]) == (1, 1, 1)
+    (gap,) = out["gaps"]
+    assert gap["gap_ms"] == pytest.approx(12.0)
+    assert gap["trace_names"] == gap["ledger_names"] == "ingest.decompress"
+    assert gap["found_at"] == ["ingest.decompress"]
+    # a log whose dispatches the trace's do not follow is refused
+    with pytest.raises(ValueError, match="no alignment"):
+        inflight_check.compare(
+            trace, [dict(r, ts=r["ts"] + 0.005 * k)
+                    for k, r in enumerate(records)])
 
 
 # -- Trainer.step_profile -------------------------------------------------

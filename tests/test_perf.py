@@ -300,6 +300,22 @@ def test_self_time_tree_aggregates_counts_and_instants():
     assert len(tree) == 2
 
 
+def test_self_time_tree_keeps_the_ledgers_overlay_out_of_the_nesting():
+    # device.starved begins inside one span of the trainer thread and
+    # ends inside another: it is no span's parent and no span's child
+    plain = [
+        _span("trainer.ingest", 0.0, 4.0),
+        _span("ingest.decompress", 0.5, 3.0),
+        _span("trainer.update", 4.0, 2.0),
+    ]
+    tree = self_time_tree(plain + [_span("device.starved", 1.0, 4.5)])
+    assert tree["learner/device.starved"] == {
+        "count": 1, "total_sec": 4.5, "self_sec": 0.0}
+    del tree["learner/device.starved"]
+    assert tree == self_time_tree(plain)
+    assert tree["learner/trainer.ingest"]["self_sec"] == pytest.approx(1.0)
+
+
 def test_top_self_orders_by_self_time_then_name():
     tree = self_time_tree([
         _span("big", 0.0, 5.0),

@@ -649,9 +649,12 @@ def test_client_degrades_after_repeated_reply_timeouts():
         for _ in range(client.DEGRADE_AFTER):
             assert client.request(leaves) is None
         assert client.degraded
-        t0 = _time.monotonic()
-        assert client.request(leaves) is None   # short-circuits now
-        assert _time.monotonic() - t0 < 0.04    # no deadline burned
+        took = []
+        for _ in range(3):     # the best of three: five other test
+            t0 = _time.monotonic()   # workers share this host's cores
+            assert client.request(leaves) is None   # short-circuits now
+            took.append(_time.monotonic() - t0)
+        assert min(took) < 0.04                 # no deadline burned
         svc.board.bump_generation()             # "respawn"
         assert client.usable()                  # re-probes next time
         assert not client.degraded

@@ -344,3 +344,254 @@ def test_healthz_answers_without_the_snapshot():
         assert calls["n"] == 1
     finally:
         server.close()
+
+
+# -- the in-flight ledger (telemetry/inflight.py) -------------------------
+
+class _Clock:
+    """A clock the test moves by hand."""
+
+    def __init__(self, t=100.0):
+        self.t = t
+
+    def __call__(self):
+        return self.t
+
+
+class _Token:
+    """What the ledger asks of a step's output: ``is_ready()``."""
+
+    def __init__(self, ready=False, error=None):
+        self.ready, self.error, self.asked = ready, error, 0
+
+    def is_ready(self):
+        self.asked += 1
+        if self.error is not None:
+            raise self.error
+        return self.ready
+
+
+def _ledger(clock=None):
+    clock = clock or _Clock()
+    telemetry.configure(enabled=True, clock=clock)
+    return telemetry.InFlight(), clock
+
+
+def _starved():
+    return [r for r in telemetry.ring_snapshot()
+            if r["name"] == "device.starved"]
+
+
+def test_the_ledger_pops_finished_steps_from_the_head_in_order():
+    ledger, clock = _ledger()
+    a, b, c = _Token(), _Token(), _Token()
+    for token in (a, b, c):
+        ledger.launch(token)
+    assert ledger.poll("update") == 3
+    # steps finish in order: a ready step BEHIND an unready one stays
+    b.ready = True
+    asked = c.asked
+    assert ledger.poll("update") == 3 and ledger.completed == 0
+    assert c.asked == asked           # a poll costs what finished, no more
+    a.ready = True
+    assert ledger.poll("update") == 1 and ledger.completed == 2
+    c.ready = True
+    assert ledger.poll("update") == 0 and ledger.completed == 3
+
+
+@pytest.mark.parametrize("site", ["update", "ingest.decompress",
+                                  "boundary.drain"])
+def test_a_starved_span_runs_from_the_poll_that_found_nothing_to_the_launch(
+        site):
+    ledger, clock = _ledger()
+    ledger.poll("update")             # the run's first poll: never launched
+    clock.t = 101.0
+    first = _Token()
+    ledger.launch(first)              # closes the stretch before step one
+    clock.t = 102.0
+    assert ledger.poll("update") == 1     # in flight: nothing starts
+    first.ready = True
+    clock.t = 102.5
+    if site == "boundary.drain":
+        ledger.drain(site)            # (c) the boundary waited the queue out
+    else:
+        # (a) found empty at the dispatch's entry, (b) between two
+        # episodes of ingest.decompress
+        assert ledger.poll(site) == 0
+    clock.t = 103.0
+    ledger.poll("ingest.append")      # a later poll moves nothing
+    clock.t = 104.0
+    ledger.launch(_Token())
+    before, span = _starved()
+    assert before["ts"] == 100.0 and before["dur"] == 1.0
+    assert before["attrs"] == {"since_ms": 0.0, "at": "update"}
+    assert span["ts"] == 102.5 and span["dur"] == pytest.approx(1.5)
+    # the device went idle somewhere in the half second before ts
+    assert span["attrs"] == {"since_ms": 500.0, "at": site}
+
+
+def test_a_queue_that_never_emptied_records_no_span():
+    ledger, clock = _ledger()
+    tokens = [_Token() for _ in range(6)]
+    ledger.poll("update")
+    ledger.launch(tokens[0])
+    (first,) = _starved()             # the stretch before step one only
+    for k, token in enumerate(tokens[1:], 1):
+        clock.t += 1.0
+        ledger.launch(token)          # queued behind the step before ...
+        tokens[k - 1].ready = True    # ... which then finishes
+        assert ledger.poll("ingest.decompress") == 1
+    assert _starved() == [first]
+
+
+def test_a_queue_that_ran_dry_inside_a_dispatch_is_a_span_of_no_length():
+    ledger, clock = _ledger()
+    ledger.poll("update")
+    one = _Token()
+    ledger.launch(one)
+    clock.t = 101.0
+    assert ledger.poll("update") == 1     # in flight at the entry
+    one.ready = True                      # finishes while the call runs
+    clock.t = 101.004
+    ledger.launch(_Token())
+    span = _starved()[-1]
+    # nothing says how long the device waited: at least 0, at most 4 ms
+    assert span["ts"] == 101.004 and span["dur"] == 0.0
+    assert span["attrs"] == {"since_ms": 4.0, "at": "update"}
+
+
+def test_watch_writes_depth_at_entry_and_steps_done_by_exit():
+    ledger, clock = _ledger()
+    tokens = [_Token() for _ in range(4)]
+    for token in tokens:
+        ledger.launch(token)
+    tokens[0].ready = True
+    attrs = {}
+    with ledger.watch("ingest.append", attrs):
+        assert attrs == {"depth": 3}      # the entry poll popped one
+        tokens[1].ready = tokens[2].ready = True
+    assert attrs == {"depth": 3, "done": 2}
+
+
+def test_the_ledger_holds_no_token_with_telemetry_off():
+    telemetry.configure(enabled=False)
+    ledger = telemetry.InFlight()
+    token = _Token()
+    attrs = {}
+    with ledger.watch("update", attrs):
+        ledger.launch(token)
+    ledger.drain("boundary.drain")
+    assert len(ledger._tokens) == 0 and token.asked == 0
+    assert attrs == {"depth": 0, "done": 0}
+    assert ledger.epoch() == {"starved_sec": None, "run_ahead_p50": None,
+                              "in_flight_sec": None}
+    assert telemetry.ring_snapshot() == []
+    # and one that was live lets go of what it held
+    ledger, _ = _ledger()
+    ledger.launch(_Token())
+    telemetry.configure(enabled=False)
+    assert ledger.poll("update") == 0 and len(ledger._tokens) == 0
+
+
+def test_a_token_that_raises_is_dropped_and_counted_never_raised():
+    ledger, clock = _ledger()
+    bad = _Token(error=RuntimeError("Array has been deleted"))
+    good = _Token(ready=True)
+    ledger.launch(bad)
+    ledger.launch(good)               # launch polls: no raise here either
+    assert ledger.poll("update") == 0
+    assert ledger.dropped == 1 and ledger.completed == 1
+
+
+def test_an_epochs_account_splits_an_open_stretch_at_the_boundary():
+    ledger, clock = _ledger()
+    ledger.poll("update")             # 100: the account begins, starved
+    clock.t = 102.0
+    one = _Token()
+    ledger.launch(one)                # 2 s starved (the compile)
+    clock.t = 105.0
+    two = _Token()
+    ledger.launch(two)                # depth 1 at this launch
+    one.ready = two.ready = True
+    clock.t = 108.0
+    ledger.drain("boundary.drain")    # runs dry at 108
+    clock.t = 109.0
+    first = ledger.epoch()
+    assert first == {"starved_sec": 3.0, "run_ahead_p50": 1,
+                     "in_flight_sec": 6.0}
+    clock.t = 111.0
+    ledger.launch(_Token())           # the stretch closes in epoch two
+    clock.t = 112.0
+    second = ledger.epoch()
+    # of the stretch's 3 s, the one before the mark was counted already
+    assert second == {"starved_sec": 2.0, "run_ahead_p50": 0,
+                      "in_flight_sec": 1.0}
+    assert _starved()[-1]["dur"] == pytest.approx(3.0)
+
+
+def test_drain_polls_until_the_queue_is_empty(monkeypatch):
+    ledger, clock = _ledger()
+    token = _Token()
+    ledger.launch(token)
+    naps = []
+
+    def nap(seconds):
+        naps.append(seconds)
+        clock.t += seconds
+        if len(naps) == 3:
+            token.ready = True
+
+    monkeypatch.setattr("handyrl_tpu.telemetry.inflight.time.sleep", nap)
+    clock.t = 101.0
+    ledger.drain("boundary.drain")
+    assert len(naps) == 3 and ledger.poll("update") == 0
+    ledger.launch(_Token())
+    span = _starved()[-1]
+    # known to the grain of the polling, not to the length of the wait
+    assert span["attrs"]["since_ms"] == pytest.approx(1e3 * naps[-1])
+    assert span["attrs"]["at"] == "boundary.drain"
+
+
+def test_section_timers_hand_their_span_the_attrs_filled_in_the_block():
+    from handyrl_tpu.utils.profiling import SectionTimers
+
+    telemetry.configure(enabled=True, clock=_ticker())
+    timers = SectionTimers()
+    attrs = {}
+    with timers.section("update", attrs=attrs):
+        attrs["depth"] = 7
+    with timers.section("batch_wait"):
+        pass
+    update, wait = telemetry.ring_snapshot()
+    assert update["name"] == "trainer.update"
+    assert update["attrs"] == {"depth": 7}
+    assert "attrs" not in wait
+    assert timers.snapshot()["update"]["n"] == 1
+
+
+def test_the_exporter_gives_a_starved_stretch_a_track_of_its_own():
+    recs = [{"name": "trainer.update", "ts": 1.0, "dur": 0.5, "pid": 9,
+             "tid": 77},
+            {"name": "device.starved", "ts": 1.2, "dur": 0.6, "pid": 9,
+             "tid": 77, "attrs": {"since_ms": 1.0, "at": "update"}}]
+    update, starved = build_trace(recs)["traceEvents"]
+    # it begins inside one span of the thread and ends after it: on the
+    # thread's own track the viewer could not nest it
+    assert update["tid"] == 77 and starved["tid"] == 0
+    assert starved["args"] == {"since_ms": 1.0, "at": "update"}
+
+
+def test_the_ledger_imports_no_jax():
+    import subprocess
+    import sys
+
+    code = ("import sys; import handyrl_tpu.telemetry.inflight; "
+            "sys.exit(int(any(m == 'jax' or m.startswith('jax.') "
+            "for m in sys.modules)))")
+    # the package's __init__ imports what it imports; the module itself
+    # must not add jax to it
+    base = ("import sys; import handyrl_tpu.telemetry.spans; "
+            "sys.exit(int(any(m == 'jax' or m.startswith('jax.') "
+            "for m in sys.modules)))")
+    assert (subprocess.run([sys.executable, "-c", code]).returncode
+            == subprocess.run([sys.executable, "-c", base]).returncode)
