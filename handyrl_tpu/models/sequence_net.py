@@ -14,7 +14,9 @@ A preset may declare a second form of the same decoder, and each part
 of it is chosen at trace time from what its ``Sizes`` say, never from
 its name: layers of ``LATENT`` attention (``LatentAttention``: a
 latent rank, a rotary part and a value width that are not nought), no
-norm after a branch, no embedding scale, pairs rotated interleaved,
+norm after a branch, no embedding scale, kernels PUBLISHED for pairs
+rotated interleaved (the parameters keep that convention; their
+columns are put half-split where they are read, ``half_split``),
 and a next-next-token module after the trunk (``NextNext``), whose
 second prediction a position comes back factored under ``mtp`` from
 the whole-window pass and takes a cross-entropy term beside the RL
@@ -39,9 +41,11 @@ Two call shapes, one set of parameters:
   * ``module(tokens (B, T), None)`` -- the learner's pass over whole
     windows.  Attention skips what causality and the window hide: on a
     TPU, at lane-wide shapes, as ONE fused kernel a layer whose scores
-    never leave the chip's fast memory (``fused_attention``); everywhere
-    else in query blocks of plain XLA (``blocked_attention``, the
-    statement the kernel is held to).  Layers are rematerialised (all
+    never leave the chip's fast memory (``fused_attention``), each of
+    its operands going from its projection to the kernel in ONE pass
+    in the compute dtype, norm and rotation inside it (``_turn_pass``);
+    everywhere else in query blocks of plain XLA (``turned``,
+    ``blocked_attention``: the statement the kernels are held to).  Layers are rematerialised (all
     but the kernel's output); the policy comes back FACTORED
     (``ops.losses.FactoredPolicy``: trunk features and the head's
     kernel), so that the ``(B * T, vocab)`` logits never exist whole.
@@ -57,12 +61,15 @@ the module declares itself a sequence net: ``TPUModel.is_sequence``.
 
 import math
 from functools import lru_cache, partial
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..ops.losses import FactoredPolicy, rows_on
 
@@ -181,27 +188,59 @@ def _project(x, features, name):
     return jnp.dot(x, Kernel((x.shape[-1], features), name=name)())
 
 
-def rotate(x, positions, theta, interleave=False):
-    """Rotary positions on ``x (..., T, H, D)``, ``positions (..., T)``,
-    computed in float32: pair ``i`` is ``(i, i + D/2)`` (the half-split
-    convention) or, with ``interleave``, ``(2i, 2i + 1)``; either way it
-    turns by ``position * theta^(-2i/D)``."""
+def _turns(positions, theta, rope):
+    """Cosine and sine ``(..., rope / 2)`` of each pair's angle at
+    ``positions (...)``: pair ``i`` of ``rope`` rotary numbers turns by
+    ``position * theta^(-2i/rope)``, in float32."""
+    freq = theta ** (-jnp.arange(0, rope, 2, dtype=jnp.float32) / rope)
+    angle = positions[..., None].astype(jnp.float32) * freq
+    return jnp.cos(angle), jnp.sin(angle)
+
+
+def rotate(x, positions, theta, rope=None):
+    """Rotary positions on the last ``rope`` numbers (all, unless given)
+    of each head of ``x (..., T, H, D)``, ``positions (..., T)``, in the
+    half-split convention: pair ``i`` is ``(i, i + rope/2)`` of them
+    and turns by ``position * theta^(-2i/rope)``; the numbers before
+    them stay as they are.  Float32 inside, ``x``'s dtype out.  This is
+    the ONE convention the module rotates in: a net whose published
+    kernels pair ``(2i, 2i + 1)`` has their columns put in this order
+    first (``half_split``).  It is the plain statement (a CPU, the
+    actors' one-token step); over a whole window on a TPU the same
+    rotation rides the operand's one pass to the attention kernel
+    (``_turn_pass``), since XLA makes five passes of this one."""
     d = x.shape[-1]
-    freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angle = positions[..., None].astype(jnp.float32) * freq  # (..., T, D/2)
+    rope = d if rope is None else rope
+    plain, half = d - rope, rope // 2
+    cos, sin = (table[..., None, :]
+                for table in _turns(positions, theta, rope))
     x32 = x.astype(jnp.float32)
-    if interleave:
-        cos = jnp.repeat(jnp.cos(angle), 2, -1)[..., None, :]
-        sin = jnp.repeat(jnp.sin(angle), 2, -1)[..., None, :]
-        pairs = x32.reshape(x.shape[:-1] + (d // 2, 2))
-        partner = jnp.stack([-pairs[..., 1], pairs[..., 0]], -1).reshape(
-            x.shape)
-    else:
-        cos = jnp.concatenate([jnp.cos(angle)] * 2, -1)[..., None, :]
-        sin = jnp.concatenate([jnp.sin(angle)] * 2, -1)[..., None, :]
-        partner = jnp.concatenate(
-            [-x32[..., d // 2:], x32[..., :d // 2]], -1)
-    return (x32 * cos + partner * sin).astype(x.dtype)
+    first, second = x32[..., plain:plain + half], x32[..., plain + half:]
+    turned = [first * cos - second * sin, second * cos + first * sin]
+    return jnp.concatenate(
+        ([x32[..., :plain]] if plain else []) + turned, -1).astype(x.dtype)
+
+
+def half_split(kernel, heads, rope):
+    """``kernel (d, heads * (plain + rope))`` with the last ``rope``
+    columns of each head, PUBLISHED as pairs ``(2i, 2i + 1)``, put in
+    the half-split order (every pair's first number, then every pair's
+    second).  A score is a sum over a query's and a key's rotary
+    numbers: the same order on both leaves every score as it was.
+    Stated as a product with a matrix of noughts and ones, which moves
+    numbers and rounds none (read on the chip, a latent layer forward
+    and backward: 36.3 ms; as a gather of columns 36.8; as strided
+    slices and a concatenation 36.8, and the compiler then allocated
+    every expert layer's buffers at once, 3.5 GB more)."""
+    w = kernel.reshape(kernel.shape[0], heads, -1)
+    width = w.shape[-1]
+    plain = width - rope
+    order = np.r_[:plain, plain:width:2, plain + 1:width:2]
+    pick = np.zeros((width, width), np.float32)
+    pick[order, np.arange(width)] = 1
+    return jnp.einsum(
+        "dhc,ce->dhe", w, jnp.asarray(pick, kernel.dtype),
+        precision=lax.Precision.HIGHEST).reshape(kernel.shape)
 
 
 def _visible(t, s, window):
@@ -295,7 +334,221 @@ def _fused_blocks(T, D, block=None):
     return block, compute
 
 
-def fused_attention(q, k, v, window, block=None, interpret=False):
+class Turn(NamedTuple):
+    """What an operand of attention takes between its projection and the
+    attention itself: an RMSNorm over each head where a scale is handed
+    beside it (``eps`` its epsilon), then the rotation of each head's
+    last ``rope`` numbers (``rotate``; 0: none)."""
+    rope: int = 0
+    theta: float = 0.0
+    eps: float = 0.0
+
+
+def turned(x, turn, gain=None):
+    """``turn`` of ``x (B, T, ..., D)`` in plain XLA, every position at
+    its own place in the window: the statement the kernel pass
+    (``_turn_pass``) is held to."""
+    if gain is not None:
+        x = rms_norm(x, gain, turn.eps)
+    if turn.rope:
+        B, T = x.shape[:2]
+        x = rotate(x.reshape(B, T, -1, x.shape[-1]), jnp.arange(T)[None],
+                   turn.theta, turn.rope).reshape(x.shape)
+    return x
+
+
+def _partner(x, half):
+    """Each rotary number's partner, for a tile of 128 lanes whose first
+    ``2 * half`` are a head's two halves: lane ``i`` takes lane ``i +
+    half`` in the first half, ``i - half`` in the second."""
+    back = pltpu.roll(x, half, x.ndim - 1)
+    if 2 * half == LANES:
+        return back
+    lane = lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    return jnp.where(
+        lane < half, pltpu.roll(x, LANES - half, x.ndim - 1), back)
+
+
+def _by_columns(half, first, plain, rotary):
+    """``rotary()`` in the grid's column blocks from ``first`` on (those
+    that hold rotary numbers), ``plain()`` in those before."""
+    if not half:
+        plain()
+    elif not first:
+        rotary()
+    else:
+        column = pl.program_id(2)
+        pl.when(column < first)(plain)
+        pl.when(column >= first)(rotary)
+
+
+def _turn_forward_body(half, first, eps, x_ref, cos_ref, sin_ref, *refs):
+    """One ``(rows, 128)`` tile of one head: the norm over the head (a
+    head of 128, where ``eps`` is given), then ``x * cos + partner *
+    sin`` against tables that carry the scale and the rotation's signs,
+    in float32 in fast memory; read once, written once."""
+    out_ref = refs[-1]
+    x = x_ref[...].astype(jnp.float32)
+    if eps is not None:
+        x = x * lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) \
+            * refs[0][...]
+
+    def store(y):
+        out_ref[...] = y.astype(out_ref.dtype)
+
+    _by_columns(
+        half, first, lambda: store(x * cos_ref[...]),
+        lambda: store(x * cos_ref[...] + _partner(x, half) * sin_ref[...]))
+
+
+def _turn_backward_body(half, first, eps, ct_ref, cos_ref, sin_ref, *refs):
+    """The forward body's transpose over the same tile: the partner of a
+    partner is the number itself, so the rotation comes back as ``ct *
+    cos + partner(ct * sin)``; then the norm's own transpose from the
+    head as it went in, and this tile's part of the scale's gradient."""
+    ct = ct_ref[...].astype(jnp.float32)
+
+    def store(d):
+        if eps is None:
+            refs[0][...] = d.astype(refs[0].dtype)
+            return
+        x_ref, gain_ref, dx_ref, dgain_ref = refs
+        x = x_ref[...].astype(jnp.float32)
+        r = lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
+        normed, scaled = x * r, d * gain_ref[...]
+        dx_ref[...] = (r * (scaled - normed * jnp.mean(
+            scaled * normed, -1, keepdims=True))).astype(dx_ref.dtype)
+        dgain_ref[...] = jnp.sum(d * normed, 0, keepdims=True)
+
+    _by_columns(
+        half, first, lambda: store(ct * cos_ref[...]),
+        lambda: store(ct * cos_ref[...]
+                      + _partner(ct * sin_ref[...], half)))
+
+
+def _turn_call(body, shape, dtype, rows, operands, flat, parts, interpret):
+    """``body`` over every ``(rows, 128)`` tile of every head of ``(B, H,
+    T, D)``.  ``operands`` are pairs ``(kind, array)``: ``"tile"`` an
+    array of that shape, the attention kernels' layout; ``"flat"`` the
+    same numbers as a projection writes them, ``(B, T, H * D)`` (heads
+    of whole tiles); ``"table"`` ``(T, whole tiles)``; ``"gain"`` ``(1,
+    128)``.  The result is one more array, ``flat`` or not, and, with
+    ``parts``, each tile's own ``(1, 128)`` sums."""
+    B, H, T, D = shape
+    columns = pl.cdiv(D, LANES)
+    # heads innermost: a table's tile is fetched once for all of them
+    specs = {
+        "tile": pl.BlockSpec(
+            (None, None, rows, LANES), lambda b, t, c, h: (b, h, t, c)),
+        "flat": pl.BlockSpec(
+            (None, rows, LANES), lambda b, t, c, h: (b, t, h * columns + c)),
+        "table": pl.BlockSpec((rows, LANES), lambda b, t, c, h: (t, c)),
+        "gain": pl.BlockSpec((1, LANES), lambda b, t, c, h: (0, 0))}
+    out_specs = specs["flat" if flat else "tile"]
+    out_shape = jax.ShapeDtypeStruct(
+        (B, T, H * D) if flat else shape, dtype)
+    if parts:
+        out_specs = (out_specs, pl.BlockSpec(
+            (None, None, None, 1, LANES), lambda b, t, c, h: (b, h, t, 0, 0)))
+        out_shape = (out_shape, jax.ShapeDtypeStruct(
+            (B, H, T // rows, 1, LANES), jnp.float32))
+    return pl.pallas_call(
+        body, grid=(B, T // rows, columns, H),
+        in_specs=[specs[kind] for kind, _ in operands], out_specs=out_specs,
+        out_shape=out_shape, interpret=interpret, name="turn_pass",
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 4),
+    )(*(array for _, array in operands))
+
+
+class _Tiles(NamedTuple):
+    """One pass's geometry: the heads, the lanes of a rotary half (0:
+    no rotation) in the tiles from ``first`` on, the norm's epsilon
+    (None: no norm), the rows a tile, and whether the projection's side
+    is ``flat``."""
+    heads: int
+    half: int
+    first: int
+    eps: Optional[float]
+    rows: int
+    flat: bool
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _turn_tiles(x, cos, sin, gain, z, interpret):
+    B, T = x.shape[0], x.shape[1 if z.flat else 2]
+    shape = (B, z.heads, T, x.shape[-1] // (z.heads if z.flat else 1))
+    operands = [("flat" if z.flat else "tile", x), ("table", cos),
+                ("table", sin)] + ([] if z.eps is None else [("gain", gain)])
+    return _turn_call(
+        partial(_turn_forward_body, z.half, z.first, z.eps), shape, x.dtype,
+        z.rows, operands, False, False, interpret)
+
+
+def _turn_tiles_forward(x, cos, sin, gain, z, interpret):
+    # the head as it went in is kept for the norm's transpose alone
+    return (_turn_tiles(x, cos, sin, gain, z, interpret),
+            (cos, sin, gain, None if z.eps is None else x))
+
+
+def _turn_tiles_backward(z, interpret, kept, ct):
+    cos, sin, gain, x = kept
+    body = partial(_turn_backward_body, z.half, z.first, z.eps)
+    operands = [("tile", ct), ("table", cos), ("table", sin)]
+    tables = (jnp.zeros_like(cos), jnp.zeros_like(sin))
+    if z.eps is None:
+        return (_turn_call(body, ct.shape, ct.dtype, z.rows, operands,
+                           z.flat, False, interpret),) + tables + (None,)
+    operands += [("flat" if z.flat else "tile", x), ("gain", gain)]
+    dx, parts = _turn_call(
+        body, ct.shape, ct.dtype, z.rows, operands, z.flat, True, interpret)
+    return (dx,) + tables + (parts.sum((0, 1, 2)).astype(gain.dtype),)
+
+
+_turn_tiles.defvjp(_turn_tiles_forward, _turn_tiles_backward)
+
+
+def _turn_pass(x, turn, gain, scale, rows, interpret):
+    """``turned`` times ``scale`` of ``x (B, T, H, D)``, a projection's
+    result, as ``(B, H, T, D)``, the attention kernels' layout, in ONE
+    pass of a kernel of this module: each ``(rows, 128)`` tile of a
+    head is read once in ``x``'s dtype, normed, rotated and scaled in
+    float32 in fast memory, and written once; coming back the same
+    pass transposed (``custom_vjp``).  Heads of whole tiles are read
+    where the projection wrote them (and their cotangents written
+    there); a head of 192 goes through XLA's transpose, which costs
+    nothing where the compiler lays the projection's result out
+    head-major (it does at a batch of one).  The kernel takes rotary
+    numbers that are a head's last, within one tile and behind whole
+    tiles, and a normed head of one tile; any other shape, and an
+    operand with nothing to do but a scale, is left to XLA
+    (``turned``)."""
+    B, T, H, D = x.shape
+    plain, half = D - turn.rope, turn.rope // 2
+    if not ((turn.rope or gain is not None) and plain % LANES == 0
+            and turn.rope <= LANES and (gain is None or D == LANES)):
+        x = turned(x, turn, gain)
+        x = x if scale == 1.0 else (x * scale).astype(x.dtype)
+        return x.transpose(0, 2, 1, 3)
+    wide = -(-D // LANES) * LANES
+    cos = jnp.full((T, wide), scale, jnp.float32)
+    sin = jnp.zeros((T, wide), jnp.float32)
+    if half:
+        c, s = (scale * table
+                for table in _turns(jnp.arange(T), turn.theta, turn.rope))
+        cos = cos.at[:, plain:D].set(jnp.concatenate([c, c], -1))
+        sin = sin.at[:, plain:D].set(jnp.concatenate([-s, s], -1))
+    if gain is not None:
+        gain = gain.astype(jnp.float32)[None]
+    flat = D % LANES == 0
+    x = x.reshape(B, T, H * D) if flat else x.transpose(0, 2, 1, 3)
+    return _turn_tiles(x, cos, sin, gain, _Tiles(
+        H, half, plain // LANES, None if gain is None else turn.eps, rows,
+        flat), interpret)
+
+
+def fused_attention(q, k, v, window, block=None, interpret=False,
+                    turns=(Turn(), Turn()), gains=(None, None)):
     """The same attention as ``blocked_attention`` over the same
     ``q (B, T, KV, G, D)``, ``k (B, T, KV, D)`` and ``v (B, T, KV, Dv)``,
     as one kernel a layer: online softmax in float32 in the chip's fast
@@ -303,33 +556,56 @@ def fused_attention(q, k, v, window, block=None, interpret=False):
     hide whole skipped, the probabilities meeting ``v`` in ``v``'s
     dtype, and a backward kernel of its own that makes the scores again
     from q, k and one log-sum-exp a query.  No array of score size is
-    written in either direction.  ``T`` is a multiple of ``block`` (the
-    module's constant unless given), ``block`` of 128, ``D`` and ``Dv``
-    of 64 and 128 at least; ``interpret`` runs the kernel's body as
-    plain JAX (tier-1, on the CPU)."""
+    written in either direction.  ``q`` and ``k`` come as their
+    projections wrote them (a latent net's with their rotary columns
+    already half-split) and take their ``turns`` (and ``gains``) on the
+    way to the kernel's ``(heads, T, D)``, each in one pass
+    (``_turn_pass``; q's carries the scale, which the kernel does not
+    apply).  ``T`` is a multiple of
+    ``block`` (the module's constant unless given), ``block`` of 128,
+    ``D`` and ``Dv`` of 64 and 128 at least; ``interpret`` runs the
+    kernels' bodies as plain JAX (tier-1, on the CPU)."""
     B, T, KV, G, D = q.shape
-    attend = _fused_kernel(T, window, G, *_fused_blocks(T, D, block),
-                           interpret)
-    # the kernel takes its scores unscaled
-    q = (q * (1.0 / math.sqrt(D))).astype(q.dtype)
+    block, compute = _fused_blocks(T, D, block)
+    attend = _fused_kernel(T, window, G, block, compute, interpret)
+    q = _turn_pass(q.reshape(B, T, KV * G, D), turns[0], gains[0],
+                   1.0 / math.sqrt(D), block, interpret)
+    k = _turn_pass(k, turns[1], gains[1], 1.0, block, interpret)
     o = jax.vmap(jax.vmap(attend))(          # over batch and kv head
-        q.transpose(0, 2, 3, 1, 4), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3))             # (B, KV, G, T, D)
-    return o.transpose(0, 3, 1, 2, 4)
+        q.reshape(B, KV, G, T, D), k, v.transpose(0, 2, 1, 3))
+    return o.transpose(0, 3, 1, 2, 4)        # (B, T, KV, G, Dv)
 
 
-def window_attention(q, k, v, window, block):
-    """Attention over a whole window by the path the program can take
-    where it is lowered: the fused kernel on a TPU when the shapes are
-    whole lanes, query blocks of ``block`` in plain XLA everywhere else
-    (a CPU, a head narrower than a lane).  Chosen per lowering
-    platform, so a program compiled for a described chip from a CPU
-    process takes the chip's path."""
-    plain = partial(blocked_attention, window=window, block=block)
+def window_attention(q, k, v, window, block, turns=(Turn(), Turn()),
+                     gains=(None, None)):
+    """Attention over a whole window, ``q`` and ``k`` taking their
+    ``turns`` first, by the path the program can take where it is
+    lowered: kernels on a TPU when the shapes are whole lanes (one pass
+    an operand, then the fused attention), plain XLA everywhere else (a
+    CPU, a head narrower than a lane: ``turned``, then query blocks of
+    ``block``).  Chosen per lowering platform, so a program compiled
+    for a described chip from a CPU process takes the chip's path."""
+    def plain(q, k, v, gains):
+        return blocked_attention(
+            turned(q, turns[0], gains[0]), turned(k, turns[1], gains[1]), v,
+            window, block)
+
+    def fused(q, k, v, gains, interpret=False):
+        return fused_attention(q, k, v, window, None, interpret, turns, gains)
+
     if _fused_blocks(q.shape[1], q.shape[-1]) is None:
-        return plain(q, k, v)
-    return lax.platform_dependent(
-        q, k, v, default=plain, tpu=partial(fused_attention, window=window))
+        return plain(q, k, v, gains)
+    return lax.platform_dependent(q, k, v, gains, default=plain, tpu=fused)
+
+
+class Scale(nn.Module):
+    """An RMSNorm's scale, handed out as it is (the norm itself runs
+    where its operand is turned)."""
+    width: int
+
+    @nn.compact
+    def __call__(self):
+        return self.param("scale", nn.initializers.ones, (self.width,))
 
 
 class Attention(nn.Module):
@@ -345,29 +621,30 @@ class Attention(nn.Module):
         with jax.named_scope(scope):
             lead = a.shape[:-1]
             q = _project(a, z.heads * z.head_dim, "q").reshape(
-                lead + (z.heads, z.head_dim))
+                lead + (z.kv_heads, groups, z.head_dim))
             k = _project(a, z.kv_heads * z.head_dim, "k").reshape(
                 lead + (z.kv_heads, z.head_dim))
             v = _project(a, z.kv_heads * z.head_dim, "v").reshape(
                 lead + (z.kv_heads, z.head_dim))
-            q = RMSNorm(z.eps, name="q_norm")(q)
-            k = RMSNorm(z.eps, name="k_norm")(k)
+            gains = (Scale(z.head_dim, name="q_norm")(),
+                     Scale(z.head_dim, name="k_norm")())
             gate = jax.nn.sigmoid(
                 _project(a, z.heads * z.head_dim, "gate"))
             if cache is None:
-                # a whole window: (B, T, ...)
+                # a whole window: (B, T, ...); norm and rotation ride
+                # each operand's one pass to the attention
                 B, T = lead
-                if window:
-                    positions = jnp.arange(T)[None]
-                    q = rotate(q, positions, z.rope_theta)
-                    k = rotate(k, positions, z.rope_theta)
-                q = q.reshape(B, T, z.kv_heads, groups, z.head_dim)
-                o = window_attention(q, k, v, window, z.attention_block)
+                turn = Turn(z.head_dim if window else 0, z.rope_theta, z.eps)
+                o = window_attention(q, k, v, window, z.attention_block,
+                                     (turn, turn), gains)
                 o = o.reshape(B, T, z.heads * z.head_dim)
             else:
                 # one token a row, through the cache: (N, ...)
+                q = rms_norm(q, gains[0], z.eps)
+                k = rms_norm(k, gains[1], z.eps)
                 if window:
-                    q = rotate(q[:, None], pos[:, None], z.rope_theta)[:, 0]
+                    q = rotate(q.reshape(-1, 1, z.heads, z.head_dim),
+                               pos[:, None], z.rope_theta).reshape(q.shape)
                     k = rotate(k[:, None], pos[:, None], z.rope_theta)[:, 0]
                 keys, values = cache                 # (N, S, KV, D)
                 s = jnp.arange(keys.shape[1])
@@ -376,7 +653,6 @@ class Attention(nn.Module):
                 values = jnp.where(
                     here, v[:, None].astype(values.dtype), values)
                 cache = (keys, values)
-                q = q.reshape(-1, z.kv_heads, groups, z.head_dim)
                 scores = jnp.einsum(
                     "nkgd,nskd->nkgs", q.astype(jnp.float32),
                     keys.astype(jnp.float32)) / math.sqrt(z.head_dim)
@@ -398,9 +674,19 @@ class LatentAttention(nn.Module):
     take no rotation beside ``rope_dim`` that do; its value is
     ``value_dim`` wide.  Full causal attention, no gate.
 
+    The PARAMETERS keep the published convention, names and shapes (a
+    checkpoint and the plain reference fit): ``q_b``'s and ``kv_a``'s
+    rotary columns are pairs ``(2i, 2i + 1)``.  ``__call__`` puts those
+    columns half-split as it reads the two kernels (``half_split``: the
+    same order for queries and the key, so no score moves), and both
+    call shapes then rotate half-split (``rotate``); the actor's cache
+    holds the rotated key in that order.
+
     The whole-window pass makes every head's keys and values from the
-    latent and attends as any layer does (``window_attention``: each
-    head its own key-value head).  The one-token step caches what a
+    latent by TWO products of ``kv_b``'s two column ranges (each writes
+    what the attention reads; nothing is written to be cut apart) and
+    attends as any layer does (``window_attention``: each head its own
+    key-value head; q's rotation rides its pass to the kernel).  The one-token step caches what a
     position IS -- its normed latent and its rotated key, ``latent_kv +
     rope_dim`` numbers -- and attends in the latent: the query is taken
     through the keys' half of ``kv_b`` first and the result through the
@@ -415,33 +701,36 @@ class LatentAttention(nn.Module):
             lead = a.shape[:-1]
             cq = RMSNorm(z.eps, name="q_norm")(
                 _project(a, z.latent_q, "q_a"))
-            q = _project(cq, z.heads * (nope + rope), "q_b").reshape(
-                lead + (z.heads, nope + rope))
-            kv_a = _project(a, z.latent_kv + rope, "kv_a")
+            q_b = Kernel((z.latent_q, z.heads * (nope + rope)), name="q_b")()
+            kv_a = Kernel((a.shape[-1], z.latent_kv + rope), name="kv_a")()
+            if z.rope_interleave:
+                q_b = half_split(q_b, z.heads, rope)
+                kv_a = half_split(kv_a, 1, rope)
+            q = jnp.dot(cq, q_b).reshape(lead + (z.heads, nope + rope))
+            kv_a = jnp.dot(a, kv_a)
             latent = RMSNorm(z.eps, name="kv_norm")(kv_a[..., :z.latent_kv])
             key = kv_a[..., None, z.latent_kv:]        # (..., 1, rope)
             kv_b = Kernel((z.latent_kv, z.heads * (nope + wide)),
-                          name="kv_b")()
-            turn = partial(rotate, theta=z.rope_theta,
-                           interleave=z.rope_interleave)
+                          name="kv_b")().reshape(
+                              z.latent_kv, z.heads, nope + wide)
             if cache is None:
                 # a whole window: (B, T, ...)
                 B, T = lead
-                positions = jnp.arange(T)[None]
-                q = jnp.concatenate(
-                    [q[..., :nope], turn(q[..., nope:], positions)], -1)
-                kv = jnp.dot(latent, kv_b).reshape(
-                    B, T, z.heads, nope + wide)
-                k = jnp.concatenate(
-                    [kv[..., :nope], jnp.broadcast_to(
-                        turn(key, positions), (B, T, z.heads, rope))], -1)
-                o = window_attention(q[:, :, :, None], k, kv[..., nope:],
-                                     0, z.attention_block)
+                k = jnp.concatenate([
+                    jnp.einsum("btc,chd->bthd", latent, kv_b[..., :nope]),
+                    jnp.broadcast_to(
+                        rotate(key, jnp.arange(T)[None], z.rope_theta),
+                        (B, T, z.heads, rope))], -1)
+                v = jnp.einsum("btc,chd->bthd", latent, kv_b[..., nope:])
+                o = window_attention(
+                    q[:, :, :, None], k, v, 0, z.attention_block,
+                    (Turn(rope, z.rope_theta), Turn()))
                 o = o.reshape(B, T, z.heads * wide)
             else:
                 # one token a row, through the cache: (N, ...)
-                q_rope = turn(q[:, None, :, nope:], pos[:, None])[:, 0]
-                key = turn(key[:, None], pos[:, None])[:, 0, 0]
+                q_rope = rotate(
+                    q[:, None, :, nope:], pos[:, None], z.rope_theta)[:, 0]
+                key = rotate(key[:, None], pos[:, None], z.rope_theta)[:, 0, 0]
                 latents, keys = cache        # (N, S, latent_kv), (N, S, rope)
                 s = jnp.arange(latents.shape[1])
                 here = (s[None] == pos[:, None])[..., None]
@@ -450,8 +739,7 @@ class LatentAttention(nn.Module):
                 keys = jnp.where(
                     here, key[:, None].astype(keys.dtype), keys)
                 cache = (latents, keys)
-                kv_b = kv_b.astype(jnp.float32).reshape(
-                    z.latent_kv, z.heads, nope + wide)
+                kv_b = kv_b.astype(jnp.float32)
                 q_latent = jnp.einsum(
                     "nhd,chd->nhc", q[..., :nope].astype(jnp.float32),
                     kv_b[..., :nope])

@@ -3,7 +3,7 @@ at its tiny preset, seeded weights, CPU, float32: the program against
 the plain reference (``benchmarks/reference/joyai_net.py``,
 ``joyai_training.py``), the actor's steps through the latent cache
 against the learner's pass, the share against the uncut layer, the
-interleaved rotation, the module's positions, the fused kernel at the
+rotation on columns put half-split against the parent's layer, the module's positions, the fused kernel at the
 latent heads' widths, the step traced twice, and one epoch of
 ``main.py --train``'s path.
 """
@@ -312,28 +312,159 @@ def test_an_expert_three_quarters_of_a_tile_wide_takes_its_own_tiles(
 
 # -- the rotation ------------------------------------------------------------
 
-def test_the_interleaved_rotation_equals_a_rotation_written_pair_by_pair():
-    x = np.asarray(jax.random.normal(jax.random.PRNGKey(4), (2, 9, 3, 8)))
-    positions = np.arange(9)[None] + np.asarray([[0], [5]])
-    theta = 32e6
+def _pairwise(x, positions, theta):
+    """The published rotation, pair ``(2i, 2i + 1)`` by pair, in numpy."""
+    d = x.shape[-1]
     want = np.zeros_like(x)
-    for b in range(2):
-        for t in range(9):
-            for i in range(4):
-                angle = positions[b, t] * theta ** (-2 * i / 8)
+    for b in range(x.shape[0]):
+        for t in range(x.shape[1]):
+            for i in range(d // 2):
+                angle = positions[b, t] * theta ** (-2 * i / d)
                 c, s = np.cos(angle), np.sin(angle)
                 even, odd = x[b, t, :, 2 * i], x[b, t, :, 2 * i + 1]
                 want[b, t, :, 2 * i] = even * c - odd * s
                 want[b, t, :, 2 * i + 1] = odd * c + even * s
-    got = sn.rotate(jnp.asarray(x), jnp.asarray(positions), theta,
-                    interleave=True)
-    np.testing.assert_allclose(got, want, atol=1e-5)
-    # not the half-split convention's pairs
-    assert float(jnp.abs(got - sn.rotate(
-        jnp.asarray(x), jnp.asarray(positions), theta)).max()) > 0.1
+    return want
+
+
+def test_columns_put_half_split_and_rotated_so_equal_the_pairwise_rotation():
+    """The published convention rotates pairs ``(2i, 2i + 1)`` of what a
+    kernel's columns give; the module puts the COLUMNS in half-split
+    order and rotates halves: the same numbers in another order, so
+    every score (a sum over them) is the published one."""
+    heads, plain, rope, theta = 3, 4, 8, 32e6
+    ka, kw, kk = jax.random.split(jax.random.PRNGKey(4), 3)
+    a = jax.random.normal(ka, (2, 9, 5))
+    kernel = jax.random.normal(kw, (5, heads * (plain + rope)))
+    positions = np.arange(9)[None] + np.asarray([[0], [5]])
+    published = np.asarray(jnp.dot(a, kernel)).reshape(
+        2, 9, heads, plain + rope)
+    want = np.concatenate([published[..., :plain], _pairwise(
+        published[..., plain:], positions, theta)], -1)
+    got = np.asarray(sn.rotate(
+        jnp.dot(a, sn.half_split(kernel, heads, rope)).reshape(
+            published.shape), jnp.asarray(positions), theta, rope))
+    order = np.r_[:plain, plain:plain + rope:2, plain + 1:plain + rope:2]
+    np.testing.assert_allclose(got, want[..., order], atol=1e-5)
+    # not the same numbers in place: the order is what differs
+    assert np.abs(got - want).max() > 0.1
+    key = jax.random.normal(kk, want.shape)
     np.testing.assert_allclose(
-        joyai_net.rope_interleaved(jnp.asarray(x[0]), theta), want[0],
+        (got * np.asarray(key)[..., order]).sum(-1),
+        (want * np.asarray(key)).sum(-1), atol=1e-4)
+    # ... and the plain reference's rotation is the published one
+    np.testing.assert_allclose(
+        joyai_net.rope_interleaved(
+            jnp.asarray(published[0][..., plain:]), theta),
+        want[0][..., plain:], atol=1e-5)
+
+
+def _interleaved(x, positions, theta):
+    """PR 40's rotation of pairs ``(2i, 2i + 1)`` on the activations."""
+    d = x.shape[-1]
+    freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = positions[..., None].astype(jnp.float32) * freq
+    cos = jnp.repeat(jnp.cos(angle), 2, -1)[..., None, :]
+    sin = jnp.repeat(jnp.sin(angle), 2, -1)[..., None, :]
+    pairs = x.reshape(x.shape[:-1] + (d // 2, 2))
+    partner = jnp.stack([-pairs[..., 1], pairs[..., 0]], -1).reshape(x.shape)
+    return x * cos + partner * sin
+
+
+def _parents_latent_attention(z, p, a):
+    """PR 40's whole-window latent attention written out on the
+    parameters AS PUBLISHED: the pairs rotated interleaved on the
+    activations, ONE product of ``kv_b`` cut apart into keys and
+    values, query blocks of plain XLA."""
+    nope, rope, wide = z.head_dim, z.rope_dim, z.value_dim
+    B, T = a.shape[:2]
+    positions = jnp.arange(T)[None]
+    cq = sn.rms_norm(a @ p["q_a"]["kernel"], p["q_norm"]["scale"], z.eps)
+    q = (cq @ p["q_b"]["kernel"]).reshape(B, T, z.heads, nope + rope)
+    kv_a = a @ p["kv_a"]["kernel"]
+    latent = sn.rms_norm(
+        kv_a[..., :z.latent_kv], p["kv_norm"]["scale"], z.eps)
+    key = _interleaved(kv_a[..., None, z.latent_kv:], positions, z.rope_theta)
+    q = jnp.concatenate([q[..., :nope], _interleaved(
+        q[..., nope:], positions, z.rope_theta)], -1)
+    kv = (latent @ p["kv_b"]["kernel"]).reshape(B, T, z.heads, nope + wide)
+    k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+        key, (B, T, z.heads, rope))], -1)
+    o = sn.blocked_attention(
+        q[:, :, :, None], k, kv[..., nope:], 0, z.attention_block)
+    return o.reshape(B, T, z.heads * wide) @ p["o"]["kernel"]
+
+
+# two heads of 128 + 64 against values of 128 over 256 positions: whole
+# lanes, so the chip's path (the kernels' bodies as plain JAX)
+WIDE_HEADS = TINY._replace(
+    heads=2, kv_heads=2, head_dim=128, rope_dim=64, value_dim=128,
+    sequence_length=256, attention_block=128)
+
+
+@pytest.mark.parametrize("sizes,path", [
+    (TINY, "default"), (WIDE_HEADS, "chips_path")], ids=["tiny", "lane_wide"])
+def test_a_window_and_every_leafs_gradient_equal_the_parents_layer(
+        sizes, path, request):
+    """Columns put half-split on the weight side, keys and values from
+    two products, q rotated on its one pass to the kernel: the layer's
+    output over a whole window and the gradient of EVERY leaf, in the
+    published column order, are the parent's."""
+    if path != "default":
+        request.getfixturevalue(path)
+        assert sn._fused_blocks(sizes.sequence_length, 192) is not None
+    layer = sn.LatentAttention(sizes)
+    ka, kp, kw = jax.random.split(jax.random.PRNGKey(11), 3)
+    a = jax.random.normal(ka, (2, sizes.sequence_length, sizes.hidden))
+    params = layer.init(kp, a)["params"]
+    weight = jax.random.normal(kw, a.shape)
+
+    def program(p, a):
+        return layer.apply({"params": p}, a)[0]
+
+    def parent(p, a):
+        return _parents_latent_attention(sizes, p, a)
+
+    def both(fn):
+        return fn(params, a), jax.grad(
+            lambda p, a: (fn(p, a) * weight).sum(), argnums=(0, 1))(params, a)
+
+    (got, got_back), (want, want_back) = both(program), both(parent)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    assert jax.tree.structure(got_back) == jax.tree.structure(want_back)
+    for g, w in zip(jax.tree.leaves(got_back), jax.tree.leaves(want_back)):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(g, w, atol=2e-5 * max(
+            1.0, float(jnp.abs(w).max())))
+
+
+def test_keys_and_values_by_two_products_equal_the_one_product_cut_apart():
+    heads, nope, wide = 3, 8, 4
+    kl, kw = jax.random.split(jax.random.PRNGKey(12))
+    latent = jax.random.normal(kl, (2, 9, 6))
+    kv_b = jax.random.normal(kw, (6, heads * (nope + wide)))
+    kv = jnp.dot(latent, kv_b).reshape(2, 9, heads, nope + wide)
+    by_head = kv_b.reshape(6, heads, nope + wide)
+    np.testing.assert_allclose(jnp.einsum(
+        "btc,chd->bthd", latent, by_head[..., :nope]), kv[..., :nope],
         atol=1e-5)
+    np.testing.assert_allclose(jnp.einsum(
+        "btc,chd->bthd", latent, by_head[..., nope:]), kv[..., nope:],
+        atol=1e-5)
+
+
+def test_the_parameters_keep_their_published_names_and_shapes(model):
+    """A checkpoint and the benchmark's reference still fit: the
+    permutation is of what ``__call__`` reads, not of what is kept."""
+    for layer in TRUNK:
+        name = "layer" if layer == "mtp" else None
+        attn = model.params[layer][name]["attn"] if name \
+            else model.params[layer]["attn"]
+        assert jax.tree.map(lambda a: a.shape, attn) == {
+            "kv_a": {"kernel": (64, 40)}, "kv_b": {"kernel": (32, 128)},
+            "kv_norm": {"scale": (32,)}, "o": {"kernel": (64, 64)},
+            "q_a": {"kernel": (64, 48)}, "q_b": {"kernel": (48, 96)},
+            "q_norm": {"scale": (48,)}}
 
 
 # -- the actor's side -------------------------------------------------------

@@ -742,6 +742,48 @@ def test_step_phases_count_a_kernels_time_for_its_scope_and_as_kernel_ms():
     assert "kernel" not in devtrace.format_phases(plain)
 
 
+# a latent layer as PR 41 compiles it: q's pass to the attention kernel
+# (norm, rotation and scale in one kernel of the module's own), the
+# attention, and the pass transposed coming back; op_names as the
+# compiler wrote them
+_LATENT = "SequencePolicyNet/layer_1/attn/net.attention.latent"
+TURN_HLO = f"""HloModule jit_step, is_scheduled=true
+
+ENTRY %main {{
+  %turn_pass.1 = bf16[1,32,8192,192]{{3,2,1,0:T(8,128)(2,1)}} custom-call(%q, %cos, %sin), custom_call_target="tpu_custom_call", frontend_attributes={{kernel_metadata={{}}}}, metadata={{op_name="jit(step)/jvp(net.forward)/{_LATENT}/cond/branch_0_fun/turn_pass/pallas_call" stack_frame_id=7}}
+  %splash_mqa_fwd_residuals.2 = bf16[32,8192,128] custom-call(%turn_pass.1), custom_call_target="tpu_custom_call", metadata={{op_name="jit(step)/jvp(net.forward)/{_LATENT}/cond/branch_0_fun/vmap(vmap(jit(_splash_attention)))/splash_mqa_fwd_residuals/splash_mqa_fwd_residuals/pallas_call"}}
+  %maximum_bitcast_fusion.3 = bf16[32,8192,192] fusion(%k), kind=kLoop, metadata={{op_name="jit(step)/jvp(net.forward)/{_LATENT}/concatenate"}}
+  ROOT %turn_pass.4 = bf16[1,32,8192,192]{{3,2,1,0:T(8,128)(2,1)}} custom-call(%dq, %cos, %sin), custom_call_target="tpu_custom_call", frontend_attributes={{kernel_metadata={{}}}}, metadata={{op_name="jit(step)/transpose(jvp(net.forward))/SequencePolicyNet/jvp(net.forward)/SequencePolicyNet/checkpoint/layer_1/attn/net.attention.latent/cond/branch_0_fun/turn_pass/pallas_call" stack_frame_id=7}}
+}}
+"""
+
+
+def test_an_operands_pass_counts_as_a_kernel_of_its_layers_scope():
+    names = devtrace.op_names(TURN_HLO)
+    assert [(devtrace.phase_of(names[op]), devtrace.net_scope_of(names[op]))
+            for op in ("turn_pass.1", "turn_pass.4")] == [
+        ("forward", "net.attention.latent"),
+        ("backward", "net.attention.latent")]
+    t = 5_000.0
+    trace = {"planes": [{"name": "/device:TPU:0", "lines": [
+        {"name": "XLA Modules", "events": [("jit_step(1)", t, 1000.0)]},
+        {"name": "XLA Ops", "events": [
+            ("turn_pass.1", t, 100.0),
+            ("splash_mqa_fwd_residuals.2", t + 100, 400.0),
+            ("maximum_bitcast_fusion.3", t + 500, 200.0),
+            ("turn_pass.4", t + 700, 300.0)]}]}],
+        "op_names": names}
+    out = devtrace.step_phases(trace)
+    ns = lambda d: {k: round(v * 1e6) for k, v in d.items()}  # noqa: E731
+    assert ns(out["scopes"]) == {"net.attention.latent": 1000}
+    # the two passes beside the attention kernel, not the XLA fusion
+    assert ns(out["kernel_ms"]) == {"net.attention.latent": 800}
+    assert (ns(out["phases"])["forward"], ns(out["phases"])["backward"]) == (
+        700, 300)
+    assert "kernel[net.attention.latent]:0.001" in \
+        devtrace.format_phases(out)
+
+
 # -- the ledger held to a trace (scripts/inflight_check.py) ---------------
 
 def test_the_ledger_is_held_to_a_trace_on_one_clock():

@@ -311,6 +311,92 @@ def test_the_fused_kernel_equals_blocked_attention_out_and_back(window):
         np.testing.assert_allclose(g, w, atol=1e-5)
 
 
+# -- one pass an operand between its projection and the kernel ---------------
+
+@pytest.mark.parametrize("heads,width,turn,gained", [
+    (3, 128, sn.Turn(128, 10000.0, 1e-5), True),
+    (3, 128, sn.Turn(0, 10000.0, 1e-5), True),
+    (2, 192, sn.Turn(64, 32e6), False)],
+    ids=["window_layer", "full_layer", "latent_query"])
+def test_the_kernel_pass_equals_norm_rotation_and_scale_in_plain_xla(
+        heads, width, turn, gained):
+    """``_turn_pass`` (its bodies run as plain JAX, float32) against
+    ``turned`` times the scale, transposed to the kernels' layout: the
+    result, and through its ``custom_vjp`` the gradients of the operand
+    and of the norm's scale.  Heads of one tile are read as the
+    projection wrote them; a head of 192 is two tiles, the second half
+    full, its rotary numbers in halves of 32 lanes."""
+    B, T, rows, scale = 2, 256, 128, 0.25
+    kx, kg, kw = jax.random.split(jax.random.PRNGKey(21), 3)
+    x = jax.random.normal(kx, (B, T, heads, width))
+    gain = 1 + 0.1 * jax.random.normal(kg, (width,)) if gained else None
+    weight = jax.random.normal(kw, (B, heads, T, width))
+
+    def kernel(x, gain):
+        return sn._turn_pass(x, turn, gain, scale, rows, True)
+
+    def plain(x, gain):
+        return (sn.turned(x, turn, gain) * scale).transpose(0, 2, 1, 3)
+
+    def both(fn):
+        return fn(x, gain), jax.grad(
+            lambda x, gain: (fn(x, gain) * weight).sum(),
+            argnums=(0, 1) if gained else 0)(x, gain)
+
+    (got, got_back), (want, want_back) = both(kernel), both(plain)
+    assert got.shape == (B, heads, T, width)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    for g, w in zip(jax.tree.leaves(got_back), jax.tree.leaves(want_back)):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(g, w, atol=1e-4)
+
+
+# four query heads on two key-value heads of 128, a window of 128 in 256
+# positions: whole lanes, so the chip's path where it is asked for
+WIDE_HEADS = TINY._replace(heads=4, kv_heads=2, head_dim=128, window=128,
+                          sequence_length=256, attention_block=128)
+
+
+@pytest.mark.parametrize("kind", [sn.SLIDING, sn.FULL])
+def test_a_layer_by_the_chips_path_equals_the_default_path(kind, request):
+    """The whole attention layer over a window, norm and rotation on
+    each operand's one pass to the fused kernel (the kernels' bodies as
+    plain JAX), against the same layer in plain XLA: the output and the
+    gradient of every leaf, the two norms' scales among them."""
+    layer = sn.Attention(WIDE_HEADS, kind)
+    ka, kp, kw = jax.random.split(jax.random.PRNGKey(22), 3)
+    a = jax.random.normal(ka, (2, WIDE_HEADS.sequence_length, WIDE_HEADS.hidden))
+    params = layer.init(kp, a)["params"]
+    weight = jax.random.normal(kw, a.shape)
+
+    def both():
+        def out(p, a):
+            return layer.apply({"params": p}, a)[0]
+        return out(params, a), jax.grad(
+            lambda p, a: (out(p, a) * weight).sum(), argnums=(0, 1))(params, a)
+
+    want, want_back = both()
+    request.getfixturevalue("chips_path")
+    got, got_back = both()
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    assert jax.tree.structure(got_back) == jax.tree.structure(want_back)
+    for g, w in zip(jax.tree.leaves(got_back), jax.tree.leaves(want_back)):
+        np.testing.assert_allclose(g, w, atol=2e-5 * max(
+            1.0, float(jnp.abs(w).max())))
+
+
+def test_the_attention_parameters_keep_their_names_and_shapes(model):
+    """The norms' scales live where ``RMSNorm`` kept them, though the
+    norm itself now runs on the operand's way to the kernel."""
+    for layer in LAYERS:
+        assert jax.tree.map(
+            lambda a: a.shape, model.params[layer]["attn"]) == {
+                "gate": {"kernel": (64, 64)}, "k": {"kernel": (64, 32)},
+                "k_norm": {"scale": (16,)}, "o": {"kernel": (64, 64)},
+                "q": {"kernel": (64, 64)}, "q_norm": {"scale": (16,)},
+                "v": {"kernel": (64, 32)}}
+
+
 @pytest.mark.parametrize("distance", [
     FUSED["window"] - 1, FUSED["window"], FUSED["window"] + 1])
 def test_the_kernels_mask_is_the_layers_own_rule_at_the_windows_edge(

@@ -37,6 +37,8 @@ BATCH = 256
 HBM_BYTES = 16 * 2**30          # one v5e chip
 RING_MB = 4096                  # config.py: device_replay_mb default
 MAX_EPISODES = 20000            # runs/hungry_geese/config.yaml
+# GB a layer under the attention scopes outside products and kernels
+ATTENTION_GB = {"grouped_query": 1.65, "latent": 2.47}
 
 
 @pytest.fixture(scope="module")
@@ -358,14 +360,15 @@ def _compile_dp4_step(v5e, f):
     assert _footprint(compiled.memory_analysis()) < HBM_BYTES
 
 
-def _fused_kernels(text):
-    """The step's fused attention kernels, counted by (net scope,
-    phase, kernel): the reducer finds each under its layer's scope."""
+def _fused_kernels(text, kernel="splash_mqa"):
+    """The step's fused attention kernels (or, by name, the passes that
+    hand them their operands), counted by (net scope, phase, kernel):
+    the reducer finds each under its layer's scope."""
     from handyrl_tpu.telemetry import devtrace
 
     kernels = {}
     for name, op_name in devtrace.op_names(text).items():
-        if name.startswith("splash_mqa") and op_name.endswith(
+        if name.startswith(kernel) and op_name.endswith(
                 devtrace.KERNEL_SUFFIX):
             key = (devtrace.net_scope_of(op_name), devtrace.phase_of(op_name),
                    name.split(".")[0])
@@ -393,6 +396,83 @@ def _held_stacks(text, positions, held, width):
     """Arrays of the dense held stack's hidden, ``(positions, held,
     expert width)`` in any dtype, that the step defines."""
     return re.findall(rf"= (\w+\[{positions},{held},{width}\])", text)
+
+
+_SHAPE = re.compile(r"\b(pred|bf16|[suf](\d+))\[([\d,]*)\]")
+_INSTRUCTION = re.compile(
+    r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\((.*?)\)(?:, |$)")
+_FREE = ("parameter", "constant", "tuple", "get-tuple-element", "bitcast")
+
+
+def _arrays(shapes):
+    """``(dtype, elements, bytes)`` of every array a shape text names."""
+    found = []
+    for dtype, bits, dims in _SHAPE.findall(shapes):
+        elements = int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+        size = {"pred": 1, "bf16": 2}.get(dtype) or int(bits) // 8
+        found.append((dtype, elements, elements * size))
+    return found
+
+
+def _outside_products_and_kernels(text, scopes):
+    """The step's top-level instructions under the net scopes ``scopes``
+    that are neither products nor kernels: ``{name: (operands' +
+    results' bytes, result's shape text)}``, a count of what they move
+    through HBM (not a time).  A product is a ``convolution`` / ``dot``
+    or a fusion around one; a kernel a custom call; the closing half of
+    an asynchronous pair counts nothing beside its opening half."""
+    from handyrl_tpu.telemetry import devtrace
+
+    products, body, inside = set(), [], None
+    for line in text.splitlines():
+        if line.startswith("ENTRY "):
+            inside = "ENTRY"
+        elif line.endswith("{") and not line.startswith(" "):
+            inside = line.split()[0].lstrip("%")
+        elif line.rstrip() == "}":
+            inside = None
+        elif inside == "ENTRY":
+            body.append(line)
+        elif inside and re.search(r" (convolution|dot)\(", line):
+            products.add(inside)
+    found = [m.groups() for m in map(_INSTRUCTION.match, body) if m]
+    result = {name: shapes for name, shapes, _, _ in found}
+    op_names = devtrace.op_names(text)
+    outside = {}
+    for (name, shapes, opcode, operands), line in zip(
+            found, (l for l in body if _INSTRUCTION.match(l))):
+        called = re.search(r"calls=%?([\w.\-]+)", line)
+        if (devtrace.net_scope_of(op_names.get(name, "")) not in scopes
+                or opcode in _FREE + ("custom-call", "convolution", "dot")
+                or opcode.endswith("-done")
+                or called and called.group(1) in products):
+            continue
+        moved = sum(size for _, _, size in _arrays(shapes)) + sum(
+            size for operand in re.findall(r"%([\w.\-]+)", operands)
+            for _, _, size in _arrays(result.get(operand, "")))
+        outside[name] = (moved, shapes)
+    return outside
+
+
+def _attention_passes(text, scopes, layers, ceiling_gb, float32_elements):
+    """Between a projection and the attention kernel an operand goes
+    through HBM once, in the compute dtype: the bytes a layer moves
+    under the attention scopes outside products and kernels stay under
+    ``ceiling_gb`` (10% over what PR 41 read, 2.25 GB a latent layer
+    and 1.50 a grouped-query layer; its parent read 7.74 and 6.04, most
+    of it float32 passes of the rotation and the norm and re-layouts
+    between them), and no such instruction writes a float32 array of
+    ``float32_elements`` or more (the smallest the parent wrote so: a
+    latent layer's rotary part of q, a grouped-query layer's q)."""
+    outside = _outside_products_and_kernels(text, scopes)
+    a_layer = sum(moved for moved, _ in outside.values()) / layers / 1e9
+    assert a_layer < ceiling_gb, (a_layer, sorted(
+        outside.items(), key=lambda item: -item[1][0])[:8])
+    wide = {name: shapes for name, (_, shapes) in outside.items()
+            if any(dtype == "f32" and elements >= float32_elements
+                   for dtype, elements, _ in _arrays(shapes))}
+    assert not wide, wide
+    return a_layer
 
 
 def _compile_sequence_step(v5e, f):
@@ -454,7 +534,15 @@ def _compile_sequence_step(v5e, f):
         ("net.moe.experts", "backward", "gmm"): 24,
         ("net.moe.experts", "backward", "tgmm"): 12}, _grouped_kernels(text)
     assert not _held_stacks(text, positions, 16, 1024)
-    assert text.count('custom_call_target="tpu_custom_call"') == 10 + 48
+    # q and k each take norm and rotation in ONE pass of a kernel on the
+    # way to the attention: going forward, in the layer's
+    # rematerialisation, and transposed coming back
+    assert _fused_kernels(text, "turn_pass") == {
+        (scope, phase, "turn_pass"): 2 * layers * passes
+        for scope, layers in (("net.attention.window", 4),
+                              ("net.attention.full", 1))
+        for phase, passes in (("forward", 1), ("backward", 2))}
+    assert text.count('custom_call_target="tpu_custom_call"') == 10 + 48 + 30
     # batch 2, 4 key-value heads of 8 query heads: a block of scores
     # was f32[2,4,8,512,Tk], queries by keys (a log-sum-exp is one a
     # query, written 128 lanes wide)
@@ -462,6 +550,12 @@ def _compile_sequence_step(v5e, f):
         r"= (f32\[2,4,8,(\d+),(\d+)\])", text)
         if int(found[1]) >= 512 and int(found[2]) >= 512]
     assert not scores, scores[:4]
+    # q and k go from projection to kernel in one pass each (norm and
+    # rotation inside it); what is left outside: dq's partial sums, the
+    # log-sum-exp's row sums, o's way out of the kernel's layout
+    _attention_passes(
+        text, ("net.attention.window", "net.attention.full"), 5,
+        ATTENTION_GB["grouped_query"], 2 * 4096 * 32 * 128)
 
 
 def _compile_latent_step(v5e, f):
@@ -515,7 +609,11 @@ def _compile_latent_step(v5e, f):
         ("net.moe.experts", "backward", "gmm"): 25,
         ("net.moe.experts", "backward", "tgmm"): 15}, _grouped_kernels(text)
     assert not _held_stacks(text, positions, 16, 768)
-    assert text.count('custom_call_target="tpu_custom_call"') == 12 + 55
+    # q alone takes a pass of its own (k is assembled from two arrays)
+    assert _fused_kernels(text, "turn_pass") == {
+        ("net.attention.latent", "forward", "turn_pass"): 6,
+        ("net.attention.latent", "backward", "turn_pass"): 12}
+    assert text.count('custom_call_target="tpu_custom_call"') == 12 + 55 + 18
     assert any("/mtp/layer/attn/net.attention.latent/" in op_name
                for op_name in devtrace.op_names(text).values())
     # batch 1, 32 heads each its own key-value head: a block of scores
@@ -527,6 +625,11 @@ def _compile_latent_step(v5e, f):
     # the module's own operations have their scope
     assert any(devtrace.net_scope_of(op_name) == "net.mtp"
                for op_name in devtrace.op_names(text).values())
+    # six latent attentions: q in one pass (its rotation inside), v
+    # written once by its own product; left outside: dq's partial sums,
+    # k's assembly, o's way out, the kernels' columns put half-split
+    _attention_passes(text, ("net.attention.latent",), 6,
+                      ATTENTION_GB["latent"], 8192 * 32 * 64)
 
 
 @pytest.mark.parametrize("program,geometry", [
