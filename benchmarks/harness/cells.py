@@ -46,10 +46,13 @@ class Cell:
         self.per_layer = [m for m in manifest["per_layer"] if mine(m)]
 
     def program_args(self):
-        """The dict ``main.py`` would read from config.yaml: the
-        configuration's three sections as its file has them.  A traffic
-        mix changes none of them: what differs from the source is in
-        the configuration's ``changed``."""
+        """The configuration's three sections as its file has them: the
+        dict ``main.py`` would read from config.yaml, but for
+        ``train_args.base_lr`` where a configuration states it, which
+        the program has no key for (``harness/rate.py`` keeps it from
+        the ``Learner``; the plain reference reads it).  A traffic mix
+        changes none of them: what differs from the source is in the
+        configuration's ``changed``."""
         args = {key: json.loads(json.dumps(self.config[key]))
                 for key in ("env_args", "train_args", "worker_args")}
         train = args["train_args"]

@@ -158,12 +158,11 @@ def reference_setup(config, train):
     return training, net, not train["turn_based_training"]
 
 
-def reference_follow(config, train, primed, capacity, initial_params,
-                     steps=3, lowp=None):
-    """The plain reference through the first ``steps`` fused steps of a
-    ring primed with ``primed`` (slot i holds ``primed[i]``): its own
-    draw, its own gather from the episodes, its own loss, gradient and
-    Adam.  ``lowp`` computes it in a lower precision: the control."""
+def reference_batches(config, train, primed, capacity, steps=3):
+    """``(training, net, batches)``: the plain reference's own draw and
+    its own gather from the episodes, for the first ``steps`` fused
+    steps of a ring primed with ``primed`` (slot i holds
+    ``primed[i]``)."""
     training, net, one_seat = reference_setup(config, train)
     columns = [training.episode_columns(ep) for ep in primed]
     lengths = np.zeros(capacity + 1, np.int32)
@@ -177,6 +176,16 @@ def reference_follow(config, train, primed, capacity, initial_params,
         batches.append(training.gather(
             columns, slots, starts, seat, train["forward_steps"],
             train["burn_in_steps"], one_seat))
+    return training, net, batches
+
+
+def reference_follow(config, train, primed, capacity, initial_params,
+                     steps=3, lowp=None):
+    """The plain reference through those steps: its own loss, gradient
+    and Adam over its own batches.  ``lowp`` computes it in a lower
+    precision: the control."""
+    training, net, batches = reference_batches(
+        config, train, primed, capacity, steps)
     return training.follow(net, initial_params, batches, train, lowp)
 
 

@@ -72,7 +72,8 @@ def follow(net, params, batches, cfg, lowp=None):
     """``trinity_training.follow`` over this module's ``loss``: the
     gradient summed over the batch's sequences, Adam's moments made
     again each step from the earlier steps' gradients."""
-    lr = BASE_LR * cfg["batch_size"] * cfg["forward_steps"]
+    lr = (cfg.get("base_lr", BASE_LR)
+          * cfg["batch_size"] * cfg["forward_steps"])
     grad = jax.jit(jax.value_and_grad(
         lambda p, row: loss(net, p, row, cfg, lowp), has_aux=True))
     add = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b),

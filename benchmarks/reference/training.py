@@ -312,7 +312,8 @@ def follow(net, params, batches, cfg, lowp=None):
     per step the size of the loss's parts (|policy| + value + return +
     the entropy bonus at most): the total is a difference of those and
     can pass through zero, so a gap in it is measured against this."""
-    lr = BASE_LR * cfg["batch_size"] * cfg["forward_steps"]
+    lr = (cfg.get("base_lr", BASE_LR)
+          * cfg["batch_size"] * cfg["forward_steps"])
     grad = jax.jit(jax.value_and_grad(
         lambda p, b: loss(net, p, b, cfg, lowp), has_aux=True))
     step = jax.jit(adam_step)

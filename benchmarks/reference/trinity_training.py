@@ -170,7 +170,8 @@ def follow(net, params, batches, cfg, lowp=None):
     sequences and Adam's moments made again each step from the earlier
     steps' gradients, which wait on the host (the module's memory
     plan)."""
-    lr = BASE_LR * cfg["batch_size"] * cfg["forward_steps"]
+    lr = (cfg.get("base_lr", BASE_LR)
+          * cfg["batch_size"] * cfg["forward_steps"])
     grad = jax.jit(jax.value_and_grad(
         lambda p, row: loss(net, p, row, cfg, lowp), has_aux=True))
     add = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b),

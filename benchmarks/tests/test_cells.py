@@ -32,7 +32,9 @@ def test_cell_runs_end_to_end_in_rehearsal(cell):
     lines = proc.stdout.strip().splitlines()
     result = json.loads(lines[-1])
     assert list(result) == ["correct", "attempted", "failed", "metrics",
-                            "device", "check"]
+                            "device", "median_leaf_change", "check"]
+    moved = result["median_leaf_change"]    # both sides trained at one rate
+    assert 0.9 < moved["program"] / moved["reference"] < 1.1
     limits = Cell(load_manifest(), cell).config["check_limits"]
     assert {k: v["limit"] for k, v in result["check"].items()} == limits
     assert proc.stderr.strip().splitlines()[-len(result["check"]):] == [

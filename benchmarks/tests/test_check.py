@@ -62,6 +62,13 @@ def test_the_control_comes_out_not_correct(geese):
     assert numbers["grad_diff"] > limits["grad_diff"]
 
 
+def test_the_default_training_side_follows_a_stated_rate(
+        geese_inputs, follows_the_stated_rate):
+    """``train_args.base_lr`` absent is HandyRL's 3e-8 a frame, as
+    before the key existed; stated, the change scales with it."""
+    follows_the_stated_rate(*geese_inputs)
+
+
 def test_the_stated_precision_and_the_reference_itself_pass(geese):
     initial, follow, limits = geese
     for lowp in (None, "bf16"):

@@ -230,6 +230,13 @@ def test_the_fp8_control_fails(tiny_cell):
     assert not correct, lines
 
 
+def test_the_reference_follows_the_stated_rate(
+        tiny_cell, follows_the_stated_rate):
+    from benchmarks import control
+
+    follows_the_stated_rate(*control.inputs(tiny_cell, 2**31 + 5))
+
+
 def test_every_reader_the_cell_lists_has_its_file(tiny_cell):
     from benchmarks import run
 

@@ -136,8 +136,14 @@ def _rehearse(tiny_config, *flags):
     return json.loads(lines[-1]), lines
 
 
-def test_the_cells_files_end_correct_in_rehearsal(tiny_config):
-    result, lines = _rehearse(tiny_config)
+@pytest.fixture(scope="module")
+def stated_run(tiny_config):
+    """One rehearsal of the cell's files as they are: the rate stated."""
+    return _rehearse(tiny_config)
+
+
+def test_the_cells_files_end_correct_in_rehearsal(stated_run):
+    result, lines = stated_run
     assert result["correct"] is True, [l for l in lines if "check " in l]
     assert result["failed"] == 0 < result["attempted"]
     assert {"setup_s", "learner_frames_per_s"} <= set(result["metrics"])
@@ -151,6 +157,50 @@ def test_the_cells_files_end_correct_in_rehearsal(tiny_config):
                       "compile", "warm"]
     # float32 on one backend: the program IS the reference to rounding
     assert max(v["value"] for v in result["check"].values()) < 1e-3
+
+
+@pytest.fixture(scope="module")
+def moved_at(tiny_config, tmp_path_factory):
+    """``moved_at(base_lr)``: a rehearsal of a copy of the tiny
+    configuration stating that rate (None: stating none), which has to
+    end correct; the median leaf's change on both sides.  One rehearsal
+    a rate."""
+    import functools
+
+    @functools.cache
+    def run(base_lr):
+        config = yaml.safe_load(tiny_config.read_text())
+        config["train_args"].pop("base_lr")
+        if base_lr is not None:
+            config["train_args"]["base_lr"] = base_lr
+        path = tmp_path_factory.mktemp("rate") / "trinity_tiny.yaml"
+        path.write_text(yaml.safe_dump(config))
+        result, lines = _rehearse(path)
+        assert result["correct"] is True, [l for l in lines if "check " in l]
+        assert result["check"]["update_gap"]["value"] < 1e-2
+        moved = result["median_leaf_change"]
+        return moved["program"], moved["reference"]
+
+    return run
+
+
+@pytest.mark.parametrize("factor", [None, 0.1])
+def test_a_stated_rate_reaches_the_program_and_the_reference(
+        moved_at, factor):
+    """A configuration that states no rate (None) trains on BOTH sides
+    at HandyRL's 3e-8 a frame, as every configuration did before the
+    key existed; one that states a tenth of it moves both sides a tenth
+    as far; in each the two sides stay as close as the cell's limit
+    asks.  Were the key read on one side only, ``update_gap`` would
+    read 9 (or 0.9)."""
+    from benchmarks.reference.training import BASE_LR
+
+    whole = moved_at(BASE_LR)
+    other = moved_at(None if factor is None else BASE_LR * factor)
+    for moved, unit in zip(other, whole):
+        assert moved / unit == pytest.approx(factor or 1.0, rel=0.1)
+    if factor is None:
+        assert other == whole
 
 
 def test_a_step_that_computes_every_pick_comes_out_not_correct(tiny_config):
@@ -201,6 +251,25 @@ def test_the_fp8_control_fails(tiny_cell):
     numbers = control.control_numbers(tiny_cell, 2**31 + 5, "fp8", capacity=64)
     correct, lines = check.verdict(numbers, tiny_cell.config["check_limits"])
     assert not correct, lines
+
+
+def test_half_of_the_batch_left_out_fails_the_loss_number(tiny_cell):
+    """What ``loss_gap`` is held against since the stated rate took the
+    fp8 control's reading of it under three times the sound runs'."""
+    from benchmarks import control
+    from benchmarks.harness import check
+
+    numbers = control.half_batch_numbers(tiny_cell, 2**31 + 5, capacity=64)
+    limits = tiny_cell.config["check_limits"]
+    assert numbers["loss_gap"] > 10 * limits["loss_gap"]
+    assert not check.verdict(numbers, limits)[0]
+
+
+def test_the_reference_follows_the_stated_rate(
+        tiny_cell, follows_the_stated_rate):
+    from benchmarks import control
+
+    follows_the_stated_rate(*control.inputs(tiny_cell, 2**31 + 5))
 
 
 def test_every_reader_the_cell_lists_has_its_file(tiny_cell):
