@@ -7,7 +7,10 @@ off-policy corrections (Monte-Carlo, TD(lambda), V-Trace, UPGO).
 
 Design stance (TPU-first, not a port):
   * the learner is a single jitted ``update_step`` — RL targets are
-    reverse ``lax.scan``s, the RNN time loop is a ``lax.scan``, and all
+    backward recursions of composable maps, walked by a reverse
+    ``lax.scan`` where the time axis is short and composed log-depth
+    (``lax.associative_scan``) where it is long, since a walked moment
+    costs its own latency; the RNN time loop is a ``lax.scan``, and all
     multi-player/turn masking is static-shape mask algebra;
   * device parallelism is a ``jax.sharding.Mesh`` with data-parallel
     batch sharding and XLA-inserted ICI collectives (the reference uses

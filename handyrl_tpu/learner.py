@@ -66,6 +66,7 @@ from .parallel.mesh import OrderedLaunch
 from .utils.compile_cache import metadata_in_key
 from .utils.profiling import SectionTimers, TraceWindow
 from .ops.losses import SEQUENCE_COUNTERS, LossConfig
+from .ops.targets import noting as noting_targets
 from .ops.update import (
     DEFAULT_LR,
     make_optimizer,
@@ -475,8 +476,13 @@ class Trainer:
 
         self.costmodel = CostModel(
             PerfConfig.from_config(self.args.get("perf") or {}))
-        self.retrace_guard.on_compile = self.costmodel.on_compile
+        self.retrace_guard.on_compile = self._on_step_compile
         self._step_label = "update_step"  # the active step program
+        # the schedule the step's value targets took at its latest
+        # lowering and the time axis's length that chose it
+        # (ops.targets): {"form": "sequential" | "log_depth", "length"};
+        # None until a harvest has traced a step that computes targets
+        self.targets_scan = None
         self.transfer_guard = (
             HostTransferGuard()
             if self.args.get("host_transfer_guard", True) else None)
@@ -1149,6 +1155,15 @@ class Trainer:
             mesh=self.train_mesh, params=self.params,
             fsdp=self.train_fsdp, seed=self.args.get("seed", 0))
 
+    def _on_step_compile(self, label, fn, args, kwargs):
+        """A step program at a new signature (``RetraceGuard``'s hook):
+        the cost model harvests its lowering, and what the targets'
+        recursion noted while that lowering traced it is kept."""
+        with noting_targets() as notes:
+            self.costmodel.on_compile(label, fn, args, kwargs)
+        if notes:
+            self.targets_scan = notes[-1]
+
     def _step_hlo_text(self):
         """HLO text of the compiled step program, from the cost model's
         harvest: where a device trace's op events find their
@@ -1160,7 +1175,7 @@ class Trainer:
         on the live params and ring under a private profiler session,
         reduced by ``telemetry.devtrace.step_phases`` to ``{steps,
         step_ms, phases: {gather, forward, targets, backward, optimizer,
-        unscoped}, scopes, kernel_ms, counters}`` (ms per step;
+        unscoped}, scopes, kernel_ms, counters, targets_scan}`` (ms per step;
         ``scopes``: the net's own named scopes, forward and transpose
         together; ``kernel_ms``: what of them ran in hand-written
         kernels; ``counters``: what the profiled steps counted beside
@@ -1218,6 +1233,7 @@ class Trainer:
         profile["counters"] = {
             k: float((max if k.endswith("_max") else np.mean)(
                 [c[k] for c in counted])) for k in counted[0]}
+        profile["targets_scan"] = self.targets_scan
         return profile
 
     def _epoch_loop_anakin(self):
@@ -1442,6 +1458,7 @@ class Trainer:
         # transfers are the per-epoch delta and must not grow with
         # the step count
         self.last_metrics["retrace_count"] = self.retrace_guard.compiles
+        self.last_metrics["targets_scan"] = self.targets_scan
         if self.transfer_guard is not None:
             self.last_metrics["host_transfers"] = \
                 self.transfer_guard.snapshot()

@@ -226,6 +226,12 @@ def test_the_reset_lowers_nothing_across_three_epochs(
     assert lowered == []
     assert trainer.retrace_guard.compiles == compiles
     assert trainer.last_metrics["retrace_count"] == compiles
+    # ... and the one lowering recorded how the value targets' recursion
+    # was scheduled and at what length of the time axis (ops/targets.py)
+    scan = {"form": "sequential",
+            "length": trainer.args["forward_steps"] - 1}
+    assert trainer.targets_scan == scan
+    assert trainer.last_metrics["targets_scan"] == scan
     trainer.shutdown()
 
 

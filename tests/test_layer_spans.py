@@ -896,6 +896,19 @@ def test_step_profile_is_none_not_an_exception_without_a_tpu(
     assert "step profile" not in capsys.readouterr().out
     assert not [d for d in os.listdir(tmp_path)
                 if d.startswith("hrl-step-profile-")]
+    # what a chip's trace would be reduced to also says how the step's
+    # value targets were scheduled, from the step's own compile record
+    from handyrl_tpu.telemetry import devtrace
+
+    phases = {"steps": 2, "step_ms": 1.0, "scopes": {}, "kernel_ms": {},
+              "phases": dict.fromkeys(devtrace.PHASES, 0.0)}
+    trainer._step_profile = None
+    with monkeypatch.context() as patched:
+        patched.setattr(devtrace, "load", lambda path, hlo: {})
+        patched.setattr(devtrace, "step_phases", lambda trace: dict(phases))
+        assert trainer.step_profile(steps=2)["targets_scan"] == {
+            "form": "sequential", "length": train["forward_steps"] - 1}
+    capsys.readouterr()
     # from another thread while the trainer's own runs: refused
     trainer._step_profile = None
     trainer._run_thread = threading.Thread(target=lambda: None)

@@ -9,6 +9,7 @@ sequences and algebraic identities.
 import numpy as np
 import pytest
 
+from handyrl_tpu.ops import targets as targets_module
 from handyrl_tpu.ops import (
     compute_target,
     impact,
@@ -227,3 +228,186 @@ def test_targets_jit_and_grad():
 
     g = jax.grad(loss)(jnp.asarray(values))
     assert np.all(np.isfinite(np.asarray(g)))
+
+
+# -- one recursion, two schedules ---------------------------------------
+#
+# The recursion is walked one moment at a time where the time axis is
+# short and composed log-depth where it is long (ops/targets.py).  Both
+# forms are held to each other and to the numpy recurrences above at
+# every length; which one runs is decided by the static length alone.
+
+CONSTANT = targets_module.LOG_DEPTH_ABOVE
+FORMS = {"sequential": 10 ** 9, "log_depth": 0}
+# float32, ~13 levels of composition, targets a few units large
+TOL = dict(rtol=1e-5, atol=1e-5)
+
+
+def _inputs(seed, b, t, p):
+    """Values in [-1, 1], 70% of moments observed (the rest switch
+    lambda to 1), importance ratios clipped at non-unit ceilings."""
+    rng = np.random.default_rng(seed)
+    shape = (b, t, p, 1)
+
+    def unit():
+        return rng.uniform(-1, 1, size=shape).astype(np.float32)
+
+    raw = rng.uniform(0, 2, size=shape).astype(np.float32)
+    return {"values": unit(), "returns": unit(), "rewards": unit(),
+            "masks": (rng.uniform(size=shape) < 0.7).astype(np.float32),
+            "rhos": np.clip(raw, 0.0, 1.3), "cs": np.clip(raw, 0.0, 1.1)}
+
+
+def _both_forms(monkeypatch, algorithm, x, lmb, gamma):
+    out = {}
+    for form, constant in FORMS.items():
+        monkeypatch.setattr(targets_module, "LOG_DEPTH_ABOVE", constant)
+        with targets_module.noting() as notes:
+            out[form] = compute_target(
+                algorithm, x["values"], x["returns"], x["rewards"], lmb,
+                gamma, x["rhos"], x["cs"], x["masks"])
+        assert notes == [{"form": form,
+                          "length": x["values"].shape[1] - 1}]
+    return out
+
+
+def _reference(algorithm, x, lmb, gamma):
+    lambda_ = lmb + (1.0 - lmb) * (1.0 - x["masks"])
+    common = (x["values"], x["returns"], x["rewards"], lambda_, gamma)
+    if algorithm == "TD":
+        return _np_td(*common)
+    if algorithm == "UPGO":
+        return _np_upgo(*common)
+    return _np_vtrace(*common, x["rhos"], x["cs"])[0]
+
+
+@pytest.mark.parametrize("gamma", [1.0, 0.8])
+@pytest.mark.parametrize("rows", [(1, 1), (2, 1), (2, 4)],
+                         ids=["rows1", "rows2", "rows8"])
+@pytest.mark.parametrize("t", [2, 3, 8, CONSTANT, CONSTANT + 1, 4096, 8192])
+@pytest.mark.parametrize("algorithm", ["TD", "UPGO", "VTRACE", "IMPACT"])
+def test_log_depth_form_is_the_sequential_one(
+        monkeypatch, algorithm, t, rows, gamma):
+    x = _inputs(t * 31 + rows[0] * rows[1], rows[0], t, rows[1])
+    out = _both_forms(monkeypatch, algorithm, x, 0.95, gamma)
+    expect = _reference(algorithm, x, 0.95, gamma)
+    for form, (targets, advantages) in out.items():
+        assert np.all(np.isfinite(targets)), form
+        np.testing.assert_allclose(targets, expect, err_msg=form, **TOL)
+    for ours, theirs in zip(out["log_depth"], out["sequential"]):
+        np.testing.assert_allclose(ours, theirs, **TOL)
+
+
+@pytest.mark.parametrize("algorithm", ["TD", "UPGO", "VTRACE"])
+def test_zero_times_minus_infinity_trap(monkeypatch, algorithm):
+    """gamma 0.8 x lambda 0.95 underflows the running product ``a`` to
+    0 within a few hundred moments.  An affine recursion stated as
+    UPGO's triple with ``c = -inf`` would then read ``0 * -inf = nan``:
+    TD and V-Trace carry pairs, only UPGO the third term, and every
+    target stays finite and equal to the walked one."""
+    x = _inputs(7, 2, 8192, 1)
+    x["masks"] = np.ones_like(x["masks"])
+    out = _both_forms(monkeypatch, algorithm, x, 0.95, 0.8)
+    assert np.all(np.isfinite(out["log_depth"][0]))
+    np.testing.assert_allclose(out["log_depth"][0], out["sequential"][0],
+                               **TOL)
+    import jax.numpy as jnp
+
+    later = (jnp.zeros(()), jnp.ones(()), jnp.full((), -jnp.inf))
+    earlier = (jnp.zeros(()), jnp.ones(()), jnp.full((), -jnp.inf))
+    assert np.isnan(targets_module._compose(later, earlier)[2])
+    assert len(targets_module._compose(later[:2], earlier[:2])) == 2
+
+
+@pytest.mark.parametrize("algorithm", ["TD", "UPGO", "VTRACE"])
+def test_grad_through_both_forms_agrees(monkeypatch, algorithm):
+    import jax
+    import jax.numpy as jnp
+
+    x = _inputs(11, 2, 130, 2)
+
+    def loss(values):
+        targets, advantages = compute_target(
+            algorithm, values, x["returns"], x["rewards"], 0.7, 0.9,
+            x["rhos"], x["cs"], x["masks"])
+        return jnp.sum(advantages ** 2) + jnp.sum(targets)
+
+    grads = {}
+    for form, constant in FORMS.items():
+        monkeypatch.setattr(targets_module, "LOG_DEPTH_ABOVE", constant)
+        grads[form] = np.asarray(jax.jit(jax.grad(loss))(
+            jnp.asarray(x["values"])))
+        assert np.all(np.isfinite(grads[form]))
+    np.testing.assert_allclose(grads["log_depth"], grads["sequential"],
+                               rtol=1e-4, atol=1e-5)
+
+
+# -- the choice itself ----------------------------------------------------
+
+def _parent_td(values, returns, rewards, lambda_, gamma):
+    """``temporal_difference`` as it stood before the recursion had a
+    second schedule (commit d9c9b14), to the letter."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    rewards = jnp.zeros_like(values) if rewards is None else rewards
+
+    def step(g_next, x):
+        v_next, r, lam = x
+        g = r + gamma * ((1.0 - lam) * v_next + lam * g_next)
+        return g, g
+
+    init = returns[:, -1]
+    xs = jax.tree.map(lambda x: jnp.moveaxis(x, 1, 0),
+                      (values[:, 1:], rewards[:, :-1], lambda_[:, 1:]))
+    _, ys = lax.scan(step, init, xs, reverse=True)
+    targets = jnp.concatenate([jnp.moveaxis(ys, 0, 1), init[:, None]],
+                              axis=1)
+    return targets, targets - values
+
+
+def _lowered(fn, t, rows=(2, 1)):
+    import jax
+    import jax.numpy as jnp
+
+    def named(values, returns, rewards, masks, rhos, cs):
+        with jax.named_scope("loss.targets"):
+            return fn(values, returns, rewards, masks, rhos, cs)
+
+    shape = jax.ShapeDtypeStruct((rows[0], t, rows[1], 1), jnp.float32)
+    return jax.jit(named).lower(*[shape] * 6).as_text()
+
+
+@pytest.mark.parametrize("algorithm", ["TD", "UPGO", "VTRACE", "IMPACT"])
+def test_the_length_of_the_time_axis_picks_the_form(monkeypatch, algorithm):
+    """No key, preset or environment variable: at 8,192 moments the
+    lowered text holds no ``while``; at a board game's 8 it is the
+    text of the walked form alone, whatever the constant."""
+    def target(values, returns, rewards, masks, rhos, cs):
+        return compute_target(algorithm, values, returns, rewards, 0.95,
+                              1.0, rhos, cs, masks)
+
+    assert "stablehlo.while" not in _lowered(target, 8192)
+    assert "stablehlo.while" not in _lowered(target, CONSTANT + 2)
+    at_constant = _lowered(target, CONSTANT + 1)     # T - 1 == CONSTANT
+    short = _lowered(target, 8)
+    assert short.count("stablehlo.while") == 1
+    assert at_constant.count("stablehlo.while") == 1
+    monkeypatch.setattr(targets_module, "LOG_DEPTH_ABOVE", 10 ** 9)
+    assert _lowered(target, 8) == short
+    assert _lowered(target, CONSTANT + 1) == at_constant
+    assert _lowered(target, 8192).count("stablehlo.while") == 1
+
+
+def test_a_short_axis_lowers_to_the_text_it_always_had():
+    """At a board game's 8 moments TD(lambda) lowers to the text of the
+    function as it stood before the second schedule, byte for byte."""
+    def ours(values, returns, rewards, masks, rhos, cs):
+        return temporal_difference(values, returns, rewards, masks, 0.8)
+
+    def parents(values, returns, rewards, masks, rhos, cs):
+        return _parent_td(values, returns, rewards, masks, 0.8)
+
+    for rows in ((256, 4), (128, 2)):
+        assert _lowered(ours, 8, rows) == _lowered(parents, 8, rows)

@@ -213,8 +213,9 @@ _DEFINED = re.compile(
     r"\s*(?:ROOT )?%?([\w.\-]+) = \w+\[(\d+)[,\]]\S* ([\w\-]+)\(")
 
 
-def _compile_replay_step(v5e, f):
-    """The device-replay fused step (draw + gather + update)."""
+def _lower_replay_step(v5e, f):
+    """The device-replay fused step (draw + gather + update), lowered
+    for the first described chip by shapes alone."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -226,10 +227,17 @@ def _compile_replay_step(v5e, f):
     step = make_replay_update_step(
         replay, f["model"], f["loss_cfg"], f["optimizer"],
         "bfloat16", batch_size=f["batch"])
-    compiled = step.lower(
+    return step.lower(
         *_on((f["params"], f["opt_state"], f["buffers"],
               (jax.ShapeDtypeStruct((3,), jnp.int32), epoch_sums(replay))),
-             chip)).compile()
+             chip))
+
+
+def _compile_replay_step(v5e, f):
+    """The device-replay fused step: its footprint and how it reads
+    the ring."""
+    replay = f["replay"]
+    compiled = _lower_replay_step(v5e, f).compile()
     mem = compiled.memory_analysis()
     # the ring is all but ~6 MB (params + Adam moments) of the arguments
     assert abs(mem.argument_size_in_bytes - f["estimate"]) \
@@ -475,6 +483,16 @@ def _attention_passes(text, scopes, layers, ceiling_gb, float32_elements):
     return a_layer
 
 
+def _walked_targets(text):
+    """The step's ``while`` loops under ``loss.targets``: the value
+    targets' recursion walked one moment at a time (ops/targets.py)."""
+    from handyrl_tpu.telemetry import devtrace
+
+    return [name for name, op_name in devtrace.op_names(text).items()
+            if devtrace.phase_of(op_name) == "targets"
+            and op_name.endswith("/while")]
+
+
 def _compile_sequence_step(v5e, f):
     """The fused step over whole 4,096-token windows: 16 B a parameter
     of train state beside the step's temporaries must fit the chip, the
@@ -482,23 +500,12 @@ def _compile_sequence_step(v5e, f):
     the fused kernel in every layer: no float32 block of scores is
     defined anywhere in the step."""
     import jax
-    import jax.numpy as jnp
-    from jax.sharding import SingleDeviceSharding
 
-    from handyrl_tpu.staging import epoch_sums, make_replay_update_step
-
-    chip = SingleDeviceSharding(v5e[0])
     replay = f["replay"]
     assert f["buffers"]["obs"] is None          # the token rides `steps`
     assert f["buffers"]["steps"].shape == (1024 * 4096 + 4096, 8)
     assert (replay.run_round, replay.max_run) == (4096, 4)
-    step = make_replay_update_step(
-        replay, f["model"], f["loss_cfg"], f["optimizer"],
-        "bfloat16", batch_size=f["batch"])
-    compiled = step.lower(
-        *_on((f["params"], f["opt_state"], f["buffers"],
-              (jax.ShapeDtypeStruct((3,), jnp.int32), epoch_sums(replay))),
-             chip)).compile()
+    compiled = _lower_replay_step(v5e, f).compile()
     mem = compiled.memory_analysis()
     n_params = sum(int(np.prod(leaf.shape))
                    for leaf in jax.tree.leaves(f["params"]))
@@ -510,6 +517,7 @@ def _compile_sequence_step(v5e, f):
     # 13.17 GB with the dense held stack
     assert _footprint(mem) < 13.8e9, _footprint(mem)
     text = compiled.as_text()
+    assert not _walked_targets(text)
     positions, vocab = 2 * 4096, 25024
     whole = [m.group(1) for m in map(_DEFINED.match, text.splitlines())
              if m and int(m.group(2)) == positions
@@ -566,24 +574,14 @@ def _compile_latent_step(v5e, f):
     among them) run as the fused kernel at query-key heads of 192
     against value heads of 128, under the latent scope."""
     import jax
-    import jax.numpy as jnp
-    from jax.sharding import SingleDeviceSharding
 
-    from handyrl_tpu.staging import epoch_sums, make_replay_update_step
     from handyrl_tpu.telemetry import devtrace
 
-    chip = SingleDeviceSharding(v5e[0])
     replay = f["replay"]
     assert f["buffers"]["obs"] is None          # the token rides `steps`
     assert f["buffers"]["steps"].shape == (512 * 8192 + 8192, 8)
     assert (replay.run_round, replay.max_run) == (8192, 2)
-    step = make_replay_update_step(
-        replay, f["model"], f["loss_cfg"], f["optimizer"],
-        "bfloat16", batch_size=f["batch"])
-    compiled = step.lower(
-        *_on((f["params"], f["opt_state"], f["buffers"],
-              (jax.ShapeDtypeStruct((3,), jnp.int32), epoch_sums(replay))),
-             chip)).compile()
+    compiled = _lower_replay_step(v5e, f).compile()
     mem = compiled.memory_analysis()
     n_params = sum(int(np.prod(leaf.shape))
                    for leaf in jax.tree.leaves(f["params"]))
@@ -592,6 +590,7 @@ def _compile_latent_step(v5e, f):
     # 13.08 GB (arguments 8.3, temporaries 4.8)
     assert _footprint(mem) < 13.3e9, _footprint(mem)
     text = compiled.as_text()
+    assert not _walked_targets(text)
     positions, vocab = 8192, 16160
     whole = [m.group(1) for m in map(_DEFINED.match, text.splitlines())
              if m and int(m.group(2)) == positions
@@ -630,6 +629,24 @@ def _compile_latent_step(v5e, f):
     # k's assembly, o's way out, the kernels' columns put half-split
     _attention_passes(text, ("net.attention.latent",), 6,
                       ATTENTION_GB["latent"], 8192 * 32 * 64)
+
+
+@pytest.mark.parametrize("geometry", ["flagship", "geister"])
+def test_a_board_step_is_the_program_it_was(
+        geometry, v5e, request, monkeypatch):
+    """``geese32`` and ``geister_drc`` train on 8 moments: their fused
+    step walks its value targets as it always did, and its text is the
+    text lowered with the constant above every length (the program as
+    it stood before the targets had a second schedule)."""
+    from handyrl_tpu.ops import targets
+
+    f = request.getfixturevalue(geometry)
+    with targets.noting() as notes:
+        text = _lower_replay_step(v5e, f).as_text()
+    assert notes and all(
+        note == {"form": "sequential", "length": 7} for note in notes)
+    monkeypatch.setattr(targets, "LOG_DEPTH_ABOVE", 10 ** 9)
+    assert _lower_replay_step(v5e, f).as_text() == text
 
 
 @pytest.mark.parametrize("program,geometry", [
