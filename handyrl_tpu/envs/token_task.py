@@ -17,8 +17,9 @@ can be checked.
 PRESETS``) and with it the episode lengths below (``LENGTHS``): the
 sparse-expert net ``trinity_mini_ep8`` plays episodes of up to 4,096
 positions (about 2,200 on average), the latent-attention net
-``joyai_flash_ep16`` of up to 8,192 (about 4,400); ``tiny`` and
-``tiny_latent`` are the two at test size, up to 32.
+``joyai_flash_ep16`` of up to 8,192 (about 4,400), the delta-rule
+hybrid ``olmo_hybrid_tp2`` the sparse-expert net's lengths; ``tiny``,
+``tiny_latent`` and ``tiny_hybrid`` are the three at test size, up to 32.
 """
 
 import math
@@ -38,8 +39,12 @@ LENGTHS = {
                          "sigma": 0.7, "least": 64},
     "joyai_flash_ep16": {"prompt": (256, 2048), "median": 3072,
                          "sigma": 0.7, "least": 128},
+    "olmo_hybrid_tp2": {"prompt": (128, 1024), "median": 1536,
+                        "sigma": 0.7, "least": 64},
     "tiny": {"prompt": (3, 8), "median": 12, "sigma": 0.7, "least": 4},
     "tiny_latent": {"prompt": (3, 8), "median": 12, "sigma": 0.7,
+                    "least": 4},
+    "tiny_hybrid": {"prompt": (3, 8), "median": 12, "sigma": 0.7,
                     "least": 4},
 }
 

@@ -25,6 +25,20 @@ DeepSeek-V3 family (JoyAI-LLM-Flash's ``config.json``; ASSUMED items in
 ``benchmarks/configs/joyai_flash_ep16.yaml``, the plain reference
 ``benchmarks/reference/joyai_net.py``).
 
+A third form, chosen the same way: layers of ``LINEAR`` attention, a
+gated delta-rule recurrence along the window (``DeltaMixer``: a matrix
+state a head, short causal convolutions, a gated norm), beside full
+attention layers in one net; every layer dense; branches normed coming
+out only; the attention's q/k norm over the whole projection and no
+output gate; and each mixer holding the chip's share of the heads
+(``heads_held``), as an expert layer holds its share of the experts:
+what the absent heads would add to a branch is left out, and no code
+stands in for the other chip or its all-reduce.  Those equations follow
+Gated DeltaNet (arXiv:2412.06464) in the Olmo family's block
+(Olmo-Hybrid-7B's ``config.json``; ASSUMED items in
+``benchmarks/configs/olmo_hybrid_tp2.yaml``, the plain reference
+``benchmarks/reference/olmo_hybrid_net.py``).
+
 The expert layer is TOLD which experts it holds (``first_expert``,
 ``experts_held``): it routes over all ``experts``, normalises the
 weights over every selected expert, held or not, and computes its own
@@ -52,14 +66,17 @@ Two call shapes, one set of parameters:
   * ``module(token (N,), hidden)`` -- the actor's one-token step through
     a cache carried as the seat's ``hidden`` (``init_hidden``: every
     key-value head's keys and values, or a latent layer's latent and
-    rotated key): dense logits for that position, the cache advanced by
-    one.  The next-next-token module is not run: an actor drafts nothing.
+    rotated key, or a delta layer's state and last convolution inputs,
+    each kind of layer its own entries): dense logits for that position,
+    the cache advanced by one.  The next-next-token module is not run:
+    an actor drafts nothing.
 
 ``sequence_length`` (the cache's positions, the longest episode) is how
 the module declares itself a sequence net: ``TPUModel.is_sequence``.
 """
 
 import math
+from collections import Counter
 from functools import lru_cache, partial
 from typing import NamedTuple, Optional, Tuple
 
@@ -75,6 +92,7 @@ from ..ops.losses import FactoredPolicy, rows_on
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 LATENT = "latent_attention"
+LINEAR = "linear_attention"
 
 
 class Sizes(NamedTuple):
@@ -108,6 +126,17 @@ class Sizes(NamedTuple):
     post_norms: bool = True        # an RMSNorm after each branch too
     embed_scale: bool = True       # h = E[tokens] * sqrt(hidden)
     nextn_modules: int = 0         # next-next-token modules (0 or 1)
+    # what a net with ``LINEAR`` layers declares beside
+    delta_key_dim: int = 0         # a delta head's key (and query) width
+    delta_value_dim: int = 0       # its value width
+    conv_taps: int = 0             # taps of the short causal convolutions
+    delta_chunk: int = 64          # positions a chunk of the recurrence
+    # the chip's share of every mixer's heads (0: all of them): the
+    # weights ARE the share's
+    heads_held: int = 0
+    pre_norms: bool = True         # an RMSNorm before each branch
+    attention_gate: bool = True    # o = o * sigmoid(a Wg)
+    qk_norm_whole: bool = False    # q/k normed over the projection, not a head
 
 
 PRESETS = {
@@ -136,7 +165,29 @@ PRESETS = {
         latent_q=1536, latent_kv=512, rope_dim=64, value_dim=128,
         rope_interleave=True, post_norms=False, embed_scale=False,
         nextn_modules=1),
+    # Olmo-Hybrid-7B (allenai, olmo_hybrid) at published widths: one
+    # chip's share of two -- heads 0-14 of every mixer's 30, rows
+    # 0-12,543 of the vocabulary, one period of three delta layers and
+    # one full attention, every MLP whole
+    "olmo_hybrid_tp2": Sizes(
+        vocab=12544, hidden=3840, layer_types=(LINEAR,) * 3 + (FULL,),
+        dense_layers=4, heads=30, kv_heads=30, head_dim=128,
+        dense_width=11008, expert_width=0, experts=0, experts_held=0,
+        first_expert=0, experts_per_token=0, shared_experts=0,
+        route_scale=0.0, window=0, sequence_length=4096, eps=1e-6,
+        embed_scale=False, delta_key_dim=96, delta_value_dim=192,
+        conv_taps=4, heads_held=15, pre_norms=False, attention_gate=False,
+        qk_norm_whole=True),
     # the same modules at test size (tier-1, CPU)
+    "tiny_hybrid": Sizes(
+        vocab=64, hidden=64, layer_types=(LINEAR, FULL, LINEAR),
+        dense_layers=3, heads=4, kv_heads=4, head_dim=16, dense_width=128,
+        expert_width=0, experts=0, experts_held=0, first_expert=0,
+        experts_per_token=0, shared_experts=0, route_scale=0.0, window=0,
+        sequence_length=32, eps=1e-6, attention_block=16,
+        embed_scale=False, delta_key_dim=8, delta_value_dim=16,
+        conv_taps=4, delta_chunk=8, heads_held=2, pre_norms=False,
+        attention_gate=False, qk_norm_whole=True),
     "tiny_latent": Sizes(
         vocab=64, hidden=64, layer_types=(LATENT,) * 3, dense_layers=1,
         heads=4, kv_heads=4, head_dim=16, dense_width=128,
@@ -184,8 +235,11 @@ class Kernel(nn.Module):
         return self.param("kernel", init, self.shape)
 
 
-def _project(x, features, name):
-    return jnp.dot(x, Kernel((x.shape[-1], features), name=name)())
+def _project(x, features, name, dtype=None):
+    """``x W`` by the kernel ``name``, in ``x``'s dtype or the wider
+    ``dtype`` the sums are handed out in."""
+    return jnp.dot(x, Kernel((x.shape[-1], features), name=name)(),
+                   preferred_element_type=dtype)
 
 
 def _turns(positions, theta, rope):
@@ -283,6 +337,7 @@ def blocked_attention(q, k, v, window, block):
 
 
 LANES = 128                     # a TPU vector register's minor axis
+L2_EPS = 1e-6                   # under a delta head's l2 norm of q and k
 # what a rematerialised layer keeps of its fused attention going
 # forward: the kernel's output and one log-sum-exp a query (68 MB a
 # layer at the published widths), so that the backward pass does not
@@ -608,28 +663,48 @@ class Scale(nn.Module):
         return self.param("scale", nn.initializers.ones, (self.width,))
 
 
+def held_heads(sizes):
+    """``(query heads, key-value heads)`` a mixer holds here: all of
+    them, or the chip's share where the net declares one."""
+    heads = sizes.heads_held or sizes.heads
+    return heads, sizes.kv_heads * heads // sizes.heads
+
+
 class Attention(nn.Module):
+    """Grouped-query attention over the heads held here.  q and k are
+    normed a head at a time (one scale of ``head_dim``), or, where the
+    net declares ``qk_norm_whole``, over the whole projection before
+    the split into heads: the one quantity of this layer that is not a
+    head's own, so a share takes its mean square over the columns it
+    holds (the deployment's all-reduce of one scalar a position is left
+    out with every exchange)."""
     sizes: Sizes
     kind: str
 
     @nn.compact
     def __call__(self, a, cache=None, pos=None):
         z = self.sizes
-        groups = z.heads // z.kv_heads
+        heads, kv_heads = held_heads(z)
+        groups = heads // kv_heads
         window = z.window if self.kind == SLIDING else 0
         scope = "net.attention.window" if window else "net.attention.full"
         with jax.named_scope(scope):
             lead = a.shape[:-1]
-            q = _project(a, z.heads * z.head_dim, "q").reshape(
-                lead + (z.kv_heads, groups, z.head_dim))
-            k = _project(a, z.kv_heads * z.head_dim, "k").reshape(
-                lead + (z.kv_heads, z.head_dim))
-            v = _project(a, z.kv_heads * z.head_dim, "v").reshape(
-                lead + (z.kv_heads, z.head_dim))
-            gains = (Scale(z.head_dim, name="q_norm")(),
-                     Scale(z.head_dim, name="k_norm")())
-            gate = jax.nn.sigmoid(
-                _project(a, z.heads * z.head_dim, "gate"))
+            q = _project(a, heads * z.head_dim, "q")
+            k = _project(a, kv_heads * z.head_dim, "k")
+            v = _project(a, kv_heads * z.head_dim, "v").reshape(
+                lead + (kv_heads, z.head_dim))
+            if z.qk_norm_whole:
+                q = rms_norm(q, Scale(q.shape[-1], name="q_norm")(), z.eps)
+                k = rms_norm(k, Scale(k.shape[-1], name="k_norm")(), z.eps)
+                gains = (None, None)
+            else:
+                gains = (Scale(z.head_dim, name="q_norm")(),
+                         Scale(z.head_dim, name="k_norm")())
+            q = q.reshape(lead + (kv_heads, groups, z.head_dim))
+            k = k.reshape(lead + (kv_heads, z.head_dim))
+            gate = jax.nn.sigmoid(_project(
+                a, heads * z.head_dim, "gate")) if z.attention_gate else None
             if cache is None:
                 # a whole window: (B, T, ...); norm and rotation ride
                 # each operand's one pass to the attention
@@ -637,13 +712,14 @@ class Attention(nn.Module):
                 turn = Turn(z.head_dim if window else 0, z.rope_theta, z.eps)
                 o = window_attention(q, k, v, window, z.attention_block,
                                      (turn, turn), gains)
-                o = o.reshape(B, T, z.heads * z.head_dim)
+                o = o.reshape(B, T, heads * z.head_dim)
             else:
                 # one token a row, through the cache: (N, ...)
-                q = rms_norm(q, gains[0], z.eps)
-                k = rms_norm(k, gains[1], z.eps)
+                if not z.qk_norm_whole:
+                    q = rms_norm(q, gains[0], z.eps)
+                    k = rms_norm(k, gains[1], z.eps)
                 if window:
-                    q = rotate(q.reshape(-1, 1, z.heads, z.head_dim),
+                    q = rotate(q.reshape(-1, 1, heads, z.head_dim),
                                pos[:, None], z.rope_theta).reshape(q.shape)
                     k = rotate(k[:, None], pos[:, None], z.rope_theta)[:, 0]
                 keys, values = cache                 # (N, S, KV, D)
@@ -661,8 +737,8 @@ class Attention(nn.Module):
                 p = jax.nn.softmax(scores, axis=-1)
                 o = jnp.einsum("nkgs,nskd->nkgd", p,
                                values.astype(jnp.float32))
-                o = o.reshape(-1, z.heads * z.head_dim).astype(a.dtype)
-            o = _project(o * gate, z.hidden, "o")
+                o = o.reshape(-1, heads * z.head_dim).astype(a.dtype)
+            o = _project(o if gate is None else o * gate, z.hidden, "o")
         return o, cache
 
 
@@ -758,6 +834,251 @@ class LatentAttention(nn.Module):
                 o = o.reshape(-1, z.heads * wide).astype(a.dtype)
             o = _project(o, z.hidden, "o")
         return o, cache
+
+
+def short_conv(x, kernel, before=None):
+    """The causal depthwise convolution over time of ``x (..., T, C)``
+    with ``kernel (taps, C)``, one filter a channel: ``y_t = sum_j
+    kernel[j] * x_{t - taps + 1 + j}``.  ``before (..., taps - 1, C)``
+    is what came before the first position (an episode's start:
+    zeros)."""
+    taps, T = kernel.shape[0], x.shape[-2]
+    if before is None:
+        before = jnp.zeros(x.shape[:-2] + (taps - 1, x.shape[-1]), x.dtype)
+    padded = jnp.concatenate([before.astype(x.dtype), x], -2)
+    return sum(kernel[j] * padded[..., j:j + T, :] for j in range(taps))
+
+
+def delta_step(state, q, k, v, g, beta):
+    """One position of the gated delta rule, a head at a time:
+    ``state (N, H, dv, dk)`` in float32, ``q, k (N, H, dk)``, ``v (N,
+    H, dv)``, ``g, beta (N, H)``:
+
+        S_t = exp(g_t) S_{t-1} (I - beta_t k_t k_t^T) + beta_t v_t k_t^T
+        o_t = S_t q_t
+
+    -> ``(S_t, o_t (N, H, dv))``.  The statement the chunk-wise pass
+    (``delta_scan``) is held to, and the actors' one-token step."""
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    alpha, beta = jnp.exp(g)[..., None, None], beta[..., None, None]
+    seen = jnp.einsum("nhvk,nhk->nhv", state, k)
+    state = alpha * (state - beta * seen[..., None] * k[..., None, :]) \
+        + beta * v[..., None] * k[..., None, :]
+    return state, jnp.einsum("nhvk,nhk->nhv", state, q)
+
+
+@jax.custom_vjp
+def unit_lower_inverse(lower):
+    """``(I + L)^-1`` of ``lower (..., C, C)``, float32 and nought on
+    and above the diagonal, by forward substitution a row at a time:
+    row ``r`` of the inverse is ``e_r - L[r] X`` over the rows above it,
+    which are final by then.  Multiplied and summed on the vector unit,
+    so float32 to the bit on a chip whose matrix unit would round the
+    operands, and stable where ``L`` is large (keys that repeat,
+    written at ``beta`` near 2: a series in ``L``'s powers cancels to
+    nothing there).  Coming back it is two products
+    (``_inverse_backward``), not a loop transposed."""
+    eye = jnp.eye(lower.shape[-1], dtype=lower.dtype)
+
+    def row(r, inverse):
+        weights = lax.dynamic_index_in_dim(lower, r, -2, keepdims=False)
+        above = (weights[..., None] * inverse).sum(-2, keepdims=True)
+        return lax.dynamic_update_slice_in_dim(
+            inverse, lax.dynamic_slice_in_dim(eye, r, 1) - above, r, -2)
+
+    return lax.fori_loop(1, lower.shape[-1], row,
+                         jnp.broadcast_to(eye, lower.shape))
+
+
+def _inverse_forward(lower):
+    inverse = unit_lower_inverse(lower)
+    return inverse, inverse
+
+
+def _inverse_backward(inverse, cotangent):
+    # d(A^-1) = -A^-1 dA A^-1; what falls on or above the diagonal
+    # belongs to no entry of ``lower``
+    turned = jnp.swapaxes(inverse, -1, -2)
+    product = partial(jnp.matmul, precision=lax.Precision.HIGHEST)
+    return (-jnp.tril(product(product(turned, cotangent), turned), -1),)
+
+
+unit_lower_inverse.defvjp(_inverse_forward, _inverse_backward)
+
+
+def delta_scan(q, k, v, g, beta, chunk):
+    """The gated delta rule (``delta_step``) over whole windows from a
+    state of nought, ``q, k (B, T, H, dk)``, ``v (B, T, H, dv)``,
+    ``g, beta (B, T, H)`` in float32 -> ``o (B, T, H, dv)``, in its
+    chunk-wise parallel form (Gated DeltaNet, arXiv:2412.06464,
+    section 3).  Within a chunk of ``chunk`` positions, with ``G_r``
+    the running sum of ``g`` and ``S`` the state the chunk starts from,
+
+        w_r = beta_r (v_r - exp(G_r) S k_r
+                      - sum_{i<r} exp(G_r - G_i) (k_i . k_r) w_i)
+        S_r = exp(G_r) S + sum_{i<=r} exp(G_r - G_i) w_i k_i^T
+        o_r = exp(G_r) S q_r + sum_{i<=r} exp(G_r - G_i) (k_i . q_r) w_i
+
+    so the ``w`` of a chunk solve ONE unit lower-triangular system,
+    ``(I + L) W = diag(beta) (V - diag(exp G) K S^T)`` with ``L[r, i] =
+    beta_r exp(G_r - G_i) (k_r . k_i)`` below the diagonal.  Its
+    inverse (``unit_lower_inverse``), the products that do not see
+    ``S`` and every decay are made for all chunks at once; a
+    ``lax.scan`` over the chunks then carries ``S`` in float32 through
+    three small products a chunk.  The triangular system, its
+    inverse's two products and every decay are float32, and a decay is
+    only ever ``exp(G_r - G_i)`` for ``i <= r``, never a reciprocal of
+    a cumulative decay (a chunk's ``exp(-G)`` overflows where the
+    decay is strong); the other products' operands are in ``q``'s dtype.
+    The backward pass is this function's derivative.  A window that is
+    no whole number of chunks is padded behind with positions that
+    write nothing (``beta = 0``) and decay nothing (``g = 0``)."""
+    B, T, H, _ = q.shape
+    dtype, f32 = q.dtype, jnp.float32
+    pad = -T % chunk
+    n = (T + pad) // chunk
+
+    def chunks(x):       # (B, T, H, ...) -> (n, B, H, chunk, ...)
+        x = jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
+        x = x.reshape((B, n, chunk) + x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 2, 3)
+
+    q, k, v, g, beta = (chunks(x) for x in (q, k, v, g, beta))
+    G = jnp.cumsum(g.astype(f32), -1)                    # (n, B, H, C)
+    beta = beta.astype(f32)
+    row, col = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None]
+    upto = row >= col                                    # i <= r
+    # exp(G_r - G_i) where i <= r, nought elsewhere; the difference is
+    # masked BEFORE the exponential, whose cotangent would else be
+    # nought times infinity where i > r
+    decay = jnp.where(upto, jnp.exp(jnp.where(
+        upto, G[..., :, None] - G[..., None, :], 0.0)), 0.0)
+    kk = jnp.einsum("...rd,...id->...ri", k, k, preferred_element_type=f32)
+    lower = jnp.where(row > col, beta[..., None] * decay * kk, 0.0)
+    inverse = unit_lower_inverse(lower) * beta[..., None, :]
+    since = jnp.exp(G)                # exp(G_r): decay since the chunk's start
+    solved = partial(jnp.einsum, "...ri,...id->...rd",
+                     precision=lax.Precision.HIGHEST)
+    u = solved(inverse, v.astype(f32))
+    k_in = solved(inverse * since[..., None, :], k.astype(f32)).astype(dtype)
+    scores = (jnp.einsum("...rd,...id->...ri", q, k,
+                         preferred_element_type=f32) * decay).astype(dtype)
+    q_in = (q * since[..., None]).astype(dtype)
+    k_out = (k * jnp.exp(G[..., -1:] - G)[..., None]).astype(dtype)
+    kept = since[..., -1, None, None]                     # exp(G_C)
+
+    def step(state, xs):
+        u, k_in, scores, q_in, k_out, kept = xs
+        s = state.astype(dtype)
+        w = (u - jnp.einsum("bhrk,bhvk->bhrv", k_in, s,
+                            preferred_element_type=f32)).astype(dtype)
+        o = jnp.einsum("bhrk,bhvk->bhrv", q_in, s,
+                       preferred_element_type=f32) \
+            + jnp.einsum("bhri,bhiv->bhrv", scores, w,
+                         preferred_element_type=f32)
+        state = kept * state + jnp.einsum(
+            "bhiv,bhik->bhvk", w, k_out, preferred_element_type=f32)
+        return state, o.astype(dtype)
+
+    state = jnp.zeros((B, H, v.shape[-1], q.shape[-1]), f32)
+    _, o = lax.scan(step, state, (u, k_in, scores, q_in, k_out, kept))
+    o = jnp.moveaxis(jnp.moveaxis(o, 3, 2), 0, 1)        # (B, n, C, H, dv)
+    return o.reshape(B, n * chunk, H, -1)[:, :T]
+
+
+def _decay_rate_init(key, shape, dtype=jnp.float32):
+    """``A_log``: the logarithm of a rate drawn uniformly from (0, 16),
+    as the family's modules draw it."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1e-3, 16.0))
+
+
+def _step_bias_init(key, shape, dtype=jnp.float32):
+    """``dt_bias``: softplus's inverse of a step drawn log-uniformly
+    from (0.001, 0.1), as the family's modules draw it."""
+    dt = jnp.exp(jax.random.uniform(
+        key, shape, dtype, math.log(1e-3), math.log(1e-1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class DeltaMixer(nn.Module):
+    """A gated delta-rule recurrence in attention's place (Gated
+    DeltaNet): per head held a state ``S (dv, dk)`` that each position
+    decays, corrects along its key and reads with its query
+    (``delta_step``).  Queries, keys and values come from three
+    projections through a short causal convolution and a SiLU each;
+    queries and keys are l2-normed a head (queries scaled by
+    ``dk^-0.5``); the write strength ``beta`` and the log-decay ``g``
+    from two projections of one number a head; the result is normed a
+    head (one gain of ``dv``), gated by a SiLU of a sixth projection
+    and projected back.  ``Wo`` has rows for the heads held alone.
+
+    A whole window runs chunk-wise (``delta_scan``) from a state of
+    nought: windows start at an episode's first position.  Positions
+    past an episode's end (token -1) are computed like any other: they
+    follow every real position and reach none, so their ``beta`` and
+    ``g`` are left as the projections give them.  The one-token step
+    carries the state and the convolutions' last ``taps - 1`` inputs."""
+    sizes: Sizes
+
+    @nn.compact
+    def __call__(self, a, cache=None, pos=None, valid=None):
+        z = self.sizes
+        heads, _ = held_heads(z)
+        dk, dv = z.delta_key_dim, z.delta_value_dim
+        widths = (heads * dk, heads * dk, heads * dv)
+        with jax.named_scope("net.delta.project"):
+            x = jnp.concatenate([_project(a, width, name) for name, width
+                                 in zip("qkv", widths)], -1)
+            taps = jnp.concatenate([
+                Kernel((z.conv_taps, width), name=name + "_conv")()
+                for name, width in zip("qkv", widths)], -1)
+            # four taps, the SiLU and the l2 norms in float32: one fused
+            # pass over the projections' result
+            x, taps = x.astype(jnp.float32), taps.astype(jnp.float32)
+            if cache is None:
+                mixed = short_conv(x, taps)
+            else:
+                state, before = cache
+                mixed = short_conv(x[:, None], taps, before)[:, 0]
+                before = jnp.concatenate(
+                    [before[:, 1:], x[:, None].astype(before.dtype)], 1)
+            mixed = jax.nn.silu(mixed)
+            q, k, v = (part.reshape(part.shape[:-1] + (heads, -1))
+                       for part in jnp.split(
+                           mixed, np.cumsum(widths[:2]), -1))
+            q, k = (part * lax.rsqrt(
+                (part * part).sum(-1, keepdims=True) + L2_EPS)
+                for part in (q, k))
+            q, k, v = (part.astype(a.dtype)
+                       for part in (q / math.sqrt(dk), k, v))
+            # in (0, 2): a write may turn its key's direction over (the
+            # negative-eigenvalue side, arXiv:2411.12537)
+            # (the two projections of one number a head hand their sums
+            # out in float32: a log-decay rounded to the compute dtype
+            # is summed over a chunk and raised to a power)
+            beta = 2.0 * jax.nn.sigmoid(_project(a, heads, "b", jnp.float32))
+            rate = self.param("A_log", _decay_rate_init, (heads,))
+            bias = self.param("dt_bias", _step_bias_init, (heads,))
+            g = -jnp.exp(rate.astype(jnp.float32)) * jax.nn.softplus(
+                _project(a, heads, "a", jnp.float32)
+                + bias.astype(jnp.float32))
+            gate = jax.nn.silu(_project(a, heads * dv, "g"))
+        with jax.named_scope("net.delta.scan"):
+            if cache is None:
+                o = delta_scan(q, k, v, g, beta, z.delta_chunk)
+            else:
+                state, o = delta_step(state, q, k, v, g, beta)
+                cache = (state, before)
+        with jax.named_scope("net.delta.out"):
+            o = rms_norm(o.astype(a.dtype), Scale(dv, name="o_norm")(), z.eps)
+            o = o.reshape(gate.shape) * gate
+            o = _project(o, z.hidden, "o")
+        # how much of its state a position keeps, over real positions
+        kept = jnp.exp(g)
+        if valid is None:
+            return o, cache, kept.mean()
+        return o, cache, (kept * valid[..., None]).sum() / jnp.maximum(
+            valid.sum() * heads, 1)
 
 
 def swiglu(x, w1, w3, w2):
@@ -1076,6 +1397,14 @@ class SparseExperts(nn.Module):
 
 
 class Layer(nn.Module):
+    """One decoder layer: a mixer along the window (by ``kind``: a
+    delta-rule recurrence, a latent attention, or grouped-query
+    attention with or without a window) and an MLP (dense or sparse
+    experts), each a residual branch normed going in and coming out as
+    the net declares.  Beside the residual stream and the actor's cache
+    it hands back what the layer counted: ``expert_load`` (positions
+    routed to each held expert) or ``retention`` (a delta layer's mean
+    decay), whichever it has."""
     sizes: Sizes
     kind: str
     dense: bool
@@ -1084,24 +1413,27 @@ class Layer(nn.Module):
     def __call__(self, h, cache=None, pos=None, valid=None):
         z = self.sizes
 
-        def branch(y, name):
-            # the net declares whether a branch is normed coming out
-            return RMSNorm(z.eps, name=name)(y) if z.post_norms else y
+        def normed(y, name, wanted):
+            return RMSNorm(z.eps, name=name)(y) if wanted else y
 
-        a = RMSNorm(z.eps, name="pre_attn_norm")(h)
-        attend = LatentAttention(z, name="attn") if self.kind == LATENT \
-            else Attention(z, self.kind, name="attn")
-        o, cache = attend(a, cache, pos)
-        h = h + branch(o, "post_attn_norm")
-        m = RMSNorm(z.eps, name="pre_mlp_norm")(h)
+        counted = {}
+        a = normed(h, "pre_attn_norm", z.pre_norms)
+        if self.kind == LINEAR:
+            o, cache, counted["retention"] = DeltaMixer(z, name="delta")(
+                a, cache, pos, valid)
+        elif self.kind == LATENT:
+            o, cache = LatentAttention(z, name="attn")(a, cache, pos)
+        else:
+            o, cache = Attention(z, self.kind, name="attn")(a, cache, pos)
+        h = h + normed(o, "post_attn_norm", z.post_norms)
+        m = normed(h, "pre_mlp_norm", z.pre_norms)
         if self.dense:
             with jax.named_scope("net.mlp"):
                 y = SwiGLU(z.dense_width, name="mlp")(m)
-            counts = jnp.zeros((0,), jnp.int32)
         else:
-            y, counts = SparseExperts(z, name="moe")(m, valid)
-        h = h + branch(y, "post_mlp_norm")
-        return h, cache, counts
+            y, counted["expert_load"] = SparseExperts(z, name="moe")(m, valid)
+        h = h + normed(y, "post_mlp_norm", z.post_norms)
+        return h, cache, counted
 
 
 # a layer over a whole window is made again coming back, all but its
@@ -1131,10 +1463,10 @@ class NextNext(nn.Module):
                     table[jnp.maximum(following, 0)])], -1)
             u = _project(joined, z.hidden, "join")
         # the layer's attention and experts lie under their own scopes
-        u, _, counts = RematLayer(z, z.layer_types[-1], False, name="layer")(
+        u, _, counted = RematLayer(z, z.layer_types[-1], False, name="layer")(
             u, None, None, following >= 0)
         with jax.named_scope("net.mtp"):
-            return RMSNorm(z.eps, name="final_norm")(u), counts
+            return RMSNorm(z.eps, name="final_norm")(u), counted
 
 
 class SequencePolicyNet(nn.Module):
@@ -1144,23 +1476,47 @@ class SequencePolicyNet(nn.Module):
     def sequence_length(self):
         return self.sizes.sequence_length
 
-    def _cache(self):
-        """What the actor's cache holds of one position of one layer,
-        by name: each key-value head's key and value, or the latent
-        and the one rotated key that every head's are made from."""
+    def _cache(self, kind):
+        """What the actor's cache holds of one layer of ``kind``, by
+        name: a delta layer's state and the last inputs of its
+        convolutions; else, for each of ``sequence_length`` positions,
+        each key-value head's key and value, or the latent and the one
+        rotated key that every head's are made from."""
         z = self.sizes
-        if z.latent_kv:
-            return {"latent": (z.latent_kv,), "rope": (z.rope_dim,)}
-        return {"k": (z.kv_heads, z.head_dim), "v": (z.kv_heads, z.head_dim)}
+        heads, kv_heads = held_heads(z)
+        if kind == LINEAR:
+            dk, dv = z.delta_key_dim, z.delta_value_dim
+            return {"state": (heads, dv, dk),
+                    "conv": (z.conv_taps - 1, heads * (2 * dk + dv))}
+        positions = (z.sequence_length,)
+        if kind == LATENT:
+            return {"latent": positions + (z.latent_kv,),
+                    "rope": positions + (z.rope_dim,)}
+        return {"k": positions + (kv_heads, z.head_dim),
+                "v": positions + (kv_heads, z.head_dim)}
+
+    def _slots(self):
+        """Each layer's ``(cache entries, place)``: layers whose caches
+        go by the same names are stacked under them, in layer order."""
+        seen, slots = {}, []
+        for kind in self.sizes.layer_types:
+            entries = self._cache(kind)
+            place = seen.get(tuple(entries), 0)
+            seen[tuple(entries)] = place + 1
+            slots.append((entries, place))
+        return slots
 
     def init_hidden(self, batch_shape=()):
         """The actor's cache, empty: the position to write next and
-        every layer's entries (``_cache``)."""
-        z = self.sizes
-        lead = tuple(batch_shape) + (len(z.layer_types), z.sequence_length)
-        hidden = {name: jnp.zeros(lead + shape, jnp.float32)
-                  for name, shape in self._cache().items()}
-        return dict(hidden, pos=jnp.zeros(tuple(batch_shape), jnp.int32))
+        every layer's entries (``_cache``), the layers of one kind
+        stacked behind the batch axes."""
+        lead = tuple(batch_shape)
+        entries = [entry for kind in self.sizes.layer_types
+                   for entry in self._cache(kind).items()]
+        layers = Counter(name for name, _ in entries)
+        hidden = {name: jnp.zeros(lead + (layers[name],) + shape, jnp.float32)
+                  for name, shape in entries}
+        return dict(hidden, pos=jnp.zeros(lead, jnp.int32))
 
     @nn.compact
     def __call__(self, tokens, hidden=None):
@@ -1179,14 +1535,15 @@ class SequencePolicyNet(nn.Module):
             h = h * jnp.asarray(math.sqrt(z.hidden), table.dtype)
         layer = RematLayer if whole else Layer
         pos = None if whole else hidden["pos"]
-        names = tuple(self._cache())
-        caches, counts = [], []
-        for i, kind in enumerate(z.layer_types):
+        slots = self._slots()
+        caches, counted = [], []
+        for i, (kind, (entries, place)) in enumerate(
+                zip(z.layer_types, slots)):
             cache = None if whole else tuple(
-                hidden[name][:, i] for name in names)
+                hidden[name][:, place] for name in entries)
             h, cache, c = layer(z, kind, i < z.dense_layers,
                                 name=f"layer_{i}")(h, cache, pos, valid)
-            counts.append(c)
+            counted.append(c)
             caches.append(cache)
         with jax.named_scope("net.head"):
             feats = RMSNorm(z.eps, name="final_norm")(h)
@@ -1206,20 +1563,31 @@ class SequencePolicyNet(nn.Module):
             window = tokens if whole else tokens[:, None]
             drafted, c = NextNext(z, name="mtp")(
                 h if whole else h[:, None], table, rows_on(window, 1))
-            counts.append(c)
+            counted.append(c)
             if whole:
                 out["mtp"] = FactoredPolicy(drafted, kernel)
         if whole:
-            # positions routed to each held expert, by expert layer
-            out["expert_load"] = jnp.stack(
-                [c for c in counts if c.shape[0]])
-            out["expert_picks"] = (
-                valid.sum() * z.experts_per_token).astype(jnp.float32)
+            # what the layers counted, under the names the step's
+            # counters go by (``ops.losses.sequence_counters``)
+            out["counts"] = counts = {}
+            loads = [c["expert_load"] for c in counted if "expert_load" in c]
+            if loads:
+                # positions routed to each held expert, by expert layer
+                counts["expert_load"] = jnp.stack(loads)
+                counts["expert_picks"] = (
+                    valid.sum() * z.experts_per_token).astype(jnp.float32)
+            kept = [c["retention"] for c in counted if "retention" in c]
+            if kept:
+                # the mean decay a position, over the delta layers
+                counts["delta_retention"] = jnp.stack(kept).mean()
         else:
+            stacked = {}
+            for (entries, _), cache in zip(slots, caches):
+                for name, entry in zip(entries, cache):
+                    stacked.setdefault(name, []).append(entry)
             out["hidden"] = {"pos": pos + 1} | {
-                name: jnp.stack([cache[j] for cache in caches], 1).astype(
-                    hidden[name].dtype)
-                for j, name in enumerate(names)}
+                name: jnp.stack(layers, 1).astype(hidden[name].dtype)
+                for name, layers in stacked.items()}
         return out
 
 

@@ -163,8 +163,8 @@ def forward_prediction(apply_fn: Callable, params, hidden, batch,
                 policy.features[:, :, None] * batch["turn_mask"].astype(
                     policy.features.dtype), policy.kernel),
             "value": out["value"][:, :, None] * batch["observation_mask"],
-            "expert_load": out["expert_load"],
-            "expert_picks": out["expert_picks"],
+            # what the net counted beside its heads, whatever it has
+            "counts": out["counts"],
         }
         if "mtp" in out:
             # a net with a next-next-token module: its second
@@ -514,9 +514,8 @@ def compute_loss(apply_fn: Callable, params, batch, hidden, cfg: LossConfig,
                 outputs, log_selected_t, total_advantages, targets, batch,
                 cfg, entropy=entropy)
     losses["clip_frac"] = clip_frac
-    if "expert_load" in outputs:
-        losses.update(sequence_counters(
-            outputs["expert_load"], outputs["expert_picks"], emasks))
+    if hidden is SEQUENCE:
+        losses.update(sequence_counters(outputs["counts"], emasks))
     if "mtp" in outputs:
         with jax.named_scope("loss.terms"):
             losses["mtp_loss"], losses["mtp_target_share"] = nextn_term(
@@ -530,16 +529,24 @@ def compute_loss(apply_fn: Callable, params, batch, hidden, cfg: LossConfig,
 # step's ``metrics`` and ``Trainer.step_profile`` reads them
 SEQUENCE_COUNTERS = ("expert_load_max", "expert_load_mean",
                      "held_pick_share", "window_fill",
-                     "mtp_loss", "mtp_target_share")
+                     "mtp_loss", "mtp_target_share", "delta_retention")
 
 
-def sequence_counters(expert_load, expert_picks, episode_mask):
-    """``expert_load (expert layers, experts held)``: positions routed
-    to each held expert this step -> the fullest expert's and the mean;
+def sequence_counters(counts, episode_mask):
+    """What a sequence net counted over the window (its ``counts``),
+    beside ``window_fill``, the share of window positions that hold a
+    token.  A net with expert layers counts ``expert_load (expert
+    layers, experts held)``, the positions routed to each held expert
+    this step, read here as the fullest expert's and the mean, and as
     ``held_pick_share``: of a layer's ``expert_picks`` (positions x
-    experts per token), those that fell on held experts;
-    ``window_fill``: the share of window positions that hold a token."""
-    load = expert_load.astype(jnp.float32)
-    return {"expert_load_max": load.max(), "expert_load_mean": load.mean(),
-            "held_pick_share": load.sum(-1).mean() / expert_picks,
-            "window_fill": episode_mask.mean()}
+    experts per token), those that fell on held experts.  Whatever else
+    a net counts goes on under the name the net gave it
+    (``delta_retention``: the mean share of its state that a real
+    position keeps, ``exp(g)``, over held heads and delta layers)."""
+    counters = dict(counts, window_fill=episode_mask.mean())
+    if "expert_load" in counters:
+        load = counters.pop("expert_load").astype(jnp.float32)
+        counters.update(
+            expert_load_max=load.max(), expert_load_mean=load.mean(),
+            held_pick_share=load.sum(-1).mean() / counters.pop("expert_picks"))
+    return counters

@@ -114,7 +114,7 @@ def test_logits_value_and_the_modules_logits_equal_the_plain_reference(
     np.testing.assert_allclose(_logits(out["mtp"])[:, :-1],
                                ref["mtp"][:, :-1], atol=3e-6)
     # four layers hold experts: three of the trunk's and the module's
-    assert out["expert_load"].shape == (
+    assert out["counts"]["expert_load"].shape == (
         len(TINY.layer_types) - TINY.dense_layers + 1, TINY.experts_held)
 
 

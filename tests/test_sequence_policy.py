@@ -91,7 +91,7 @@ def test_logits_and_value_equal_the_plain_reference(model, tiny_geometry):
     ref = trinity_net.forward(model.params, tokens)
     np.testing.assert_allclose(_logits(out), ref["policy"], atol=2e-6)
     np.testing.assert_allclose(out["value"], ref["value"], atol=2e-6)
-    assert out["expert_load"].shape == (2, TINY.experts_held)
+    assert out["counts"]["expert_load"].shape == (2, TINY.experts_held)
 
 
 def _batch(episodes):
@@ -224,13 +224,15 @@ def test_padding_takes_no_expert_and_moves_no_real_position(model):
                                _logits(whole)[:, :real], atol=2e-6)
     np.testing.assert_allclose(cut["value"][:, :real],
                                whole["value"][:, :real], atol=2e-6)
+    cut, whole = cut["counts"], whole["counts"]
     assert float(cut["expert_picks"]) == 2 * real * TINY.experts_per_token
     assert float(whole["expert_picks"]) == tokens.size * TINY.experts_per_token
     assert (cut["expert_load"] <= whole["expert_load"]).all()
     assert int(cut["expert_load"].sum()) < int(whole["expert_load"].sum())
     head = model.module.apply({"params": model.params}, tokens[:, :real],
                               None)
-    np.testing.assert_array_equal(cut["expert_load"], head["expert_load"])
+    np.testing.assert_array_equal(cut["expert_load"],
+                                  head["counts"]["expert_load"])
 
 
 # -- the actor's side -------------------------------------------------------
@@ -582,7 +584,7 @@ def test_a_whole_net_by_the_grouped_body_equals_the_dense_body(request):
     def loss(params):
         out = net.apply(params, tokens, None)
         return (jnp.square(_logits(out)).mean() + out["value"].sum(),
-                out["expert_load"])
+                out["counts"]["expert_load"])
 
     want = jax.value_and_grad(loss, has_aux=True)(params)
     request.getfixturevalue("chips_path")

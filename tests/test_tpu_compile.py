@@ -7,8 +7,7 @@ whether the main path's programs are accepted at the flagship shapes
 (HungryGeese / GeeseNet 32f x 12, batch 256 x 8 steps, bf16 compute,
 uint8 wire, the ring at the capacity the learner picks under the
 default ``device_replay_mb``; the fused step at Geister's recurrent
-geometry and at the sparse-expert sequence net's published widths
-too), whether they fit the chip's 16 GB, whether the ring's
+geometry and at the three sequence nets' published widths too), whether they fit the chip's 16 GB, whether the ring's
 own byte estimate matches what the compiler lays out — the estimate
 sizes the ring, and tile padding is exactly what it exists to get
 right (staging.py docstring) — and whether the step's gather reads the
@@ -193,6 +192,14 @@ def latent_sequence():
     sixteen; ONE window of 8,192 tokens a step, the ring at the
     configuration's 512 slots."""
     return _sequence_fixture("joyai_flash_ep16", 8192, 1, 512)
+
+
+@pytest.fixture(scope="module")
+def hybrid_sequence():
+    """``olmo_hybrid_tp2``: the delta-rule hybrid at published widths,
+    one chip's share of two; ONE window of 4,096 tokens a step, the
+    ring at the configuration's 1,024 slots."""
+    return _sequence_fixture("olmo_hybrid_tp2", 4096, 1, 1024)
 
 
 def _on(tree, sharding):
@@ -631,6 +638,60 @@ def _compile_latent_step(v5e, f):
                       ATTENTION_GB["latent"], 8192 * 32 * 64)
 
 
+def _compile_hybrid_step(v5e, f):
+    """The fused step over ONE whole 4,096-token window of the
+    delta-rule hybrid: the train state of 766 M parameters (16 B each)
+    beside the step's temporaries fits the chip -- the wall the
+    configuration was cut against -- the logits never exist whole, the
+    one full attention runs as the fused kernel at 15 heads each its
+    own key-value head, and every delta layer's recurrence is a loop
+    over chunks under its own scope, forward, rematerialised and
+    coming back."""
+    import jax
+
+    from handyrl_tpu.telemetry import devtrace
+
+    assert f["buffers"]["obs"] is None          # the token rides `steps`
+    assert f["buffers"]["steps"].shape == (1024 * 4096 + 4096, 8)
+    compiled = _lower_replay_step(v5e, f).compile()
+    mem = compiled.memory_analysis()
+    n_params = sum(int(np.prod(leaf.shape))
+                   for leaf in jax.tree.leaves(f["params"]))
+    assert n_params == 766_245_786
+    assert mem.argument_size_in_bytes >= 12 * n_params
+    # 12.61 GB (arguments 9.3, temporaries 3.3)
+    assert _footprint(mem) < 12.9e9, _footprint(mem)
+    text = compiled.as_text()
+    assert not _walked_targets(text)
+    positions, vocab = 4096, 12544
+    whole = [m.group(1) for m in map(_DEFINED.match, text.splitlines())
+             if m and int(m.group(2)) == positions
+             and f",{vocab}]" in m.group(0)]
+    assert not whole, whole
+    assert _fused_kernels(text) == {
+        ("net.attention.full", "forward", "splash_mqa_fwd_residuals"): 1,
+        ("net.attention.full", "backward",
+         "splash_mqa_dkv_no_residuals"): 1}
+    # the q/k norm spans the projection: no pass of the module's own
+    assert not _fused_kernels(text, "turn_pass")
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    op_names = devtrace.op_names(text).values()
+    scopes = {devtrace.net_scope_of(op_name) for op_name in op_names}
+    assert {"net.delta.project", "net.delta.scan", "net.delta.out",
+            "net.attention.full", "net.mlp", "net.head"} <= scopes
+    assert not {"net.moe.experts", "net.attention.window"} & scopes
+    # the scan over chunks: a loop a delta layer in each of the forward
+    # pass, its rematerialisation and the way back, under the scan's
+    # scope and no other
+    loops = {}
+    for op_name in op_names:
+        if op_name.endswith("/while") and "net.delta" in op_name:
+            assert devtrace.net_scope_of(op_name) == "net.delta.scan"
+            phase = devtrace.phase_of(op_name)
+            loops[phase] = loops.get(phase, 0) + 1
+    assert loops["forward"] >= 3 and loops["backward"] >= 3, loops
+
+
 @pytest.mark.parametrize("geometry", ["flagship", "geister"])
 def test_a_board_step_is_the_program_it_was(
         geometry, v5e, request, monkeypatch):
@@ -655,9 +716,10 @@ def test_a_board_step_is_the_program_it_was(
     (_compile_service_forward, "flagship"), (_compile_dp4_step, "flagship"),
     (_compile_sequence_step, "sequence"),
     (_compile_latent_step, "latent_sequence"),
+    (_compile_hybrid_step, "hybrid_sequence"),
 ], ids=["replay_step", "replay_step_geister", "ring_append",
         "service_forward", "dp4_step", "replay_step_sequence",
-        "replay_step_latent"])
+        "replay_step_latent", "replay_step_hybrid"])
 def test_main_path_compiles_for_a_described_v5e(
         program, geometry, v5e, request):
     program(v5e, request.getfixturevalue(geometry))
