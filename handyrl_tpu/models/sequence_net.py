@@ -60,7 +60,8 @@ Two call shapes, one set of parameters:
     in the compute dtype, norm and rotation inside it (``_turn_pass``);
     everywhere else in query blocks of plain XLA (``turned``,
     ``blocked_attention``: the statement the kernels are held to).  Layers are rematerialised (all
-    but the kernel's output); the policy comes back FACTORED
+    but the kernel's output and the widest products with weight
+    matrices, ``KEPT_NAMES``); the policy comes back FACTORED
     (``ops.losses.FactoredPolicy``: trunk features and the head's
     kernel), so that the ``(B * T, vocab)`` logits never exist whole.
   * ``module(token (N,), hidden)`` -- the actor's one-token step through
@@ -85,6 +86,7 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -343,6 +345,15 @@ L2_EPS = 1e-6                   # under a delta head's l2 norm of q and k
 # layer at the published widths), so that the backward pass does not
 # run the forward kernel again
 KEPT = "fused_attention_out"
+# the rule: a rematerialised layer keeps the results of its widest
+# products with weight matrices (a SwiGLU's three; a delta mixer's q, k,
+# v and gate projections and ``Wo``'s result, each in the compute dtype
+# as the product hands it out) and its attention kernel's output;
+# elementwise work, recurrences and every float32 pass over the window
+# are made again.  A name that nothing coming back reads costs nothing:
+# the backward pass's partial evaluation drops it
+KEPT_NAMES = (KEPT, "mlp_gate", "mlp_up", "mlp_out",
+              "delta_q", "delta_k", "delta_v", "delta_gate", "delta_out")
 # the kernel's blocks, read on the chip at 4,096 positions, 4 x 8 heads
 # of 128 (``PERF.md`` section 6, PR 34)
 FUSED_BLOCK = 1024              # queries, and keys fetched, a grid step
@@ -1027,8 +1038,9 @@ class DeltaMixer(nn.Module):
         dk, dv = z.delta_key_dim, z.delta_value_dim
         widths = (heads * dk, heads * dk, heads * dv)
         with jax.named_scope("net.delta.project"):
-            x = jnp.concatenate([_project(a, width, name) for name, width
-                                 in zip("qkv", widths)], -1)
+            x = jnp.concatenate([
+                checkpoint_name(_project(a, width, name), "delta_" + name)
+                for name, width in zip("qkv", widths)], -1)
             taps = jnp.concatenate([
                 Kernel((z.conv_taps, width), name=name + "_conv")()
                 for name, width in zip("qkv", widths)], -1)
@@ -1062,7 +1074,8 @@ class DeltaMixer(nn.Module):
             g = -jnp.exp(rate.astype(jnp.float32)) * jax.nn.softplus(
                 _project(a, heads, "a", jnp.float32)
                 + bias.astype(jnp.float32))
-            gate = jax.nn.silu(_project(a, heads * dv, "g"))
+            gate = jax.nn.silu(checkpoint_name(
+                _project(a, heads * dv, "g"), "delta_gate"))
         with jax.named_scope("net.delta.scan"):
             if cache is None:
                 o = delta_scan(q, k, v, g, beta, z.delta_chunk)
@@ -1072,7 +1085,7 @@ class DeltaMixer(nn.Module):
         with jax.named_scope("net.delta.out"):
             o = rms_norm(o.astype(a.dtype), Scale(dv, name="o_norm")(), z.eps)
             o = o.reshape(gate.shape) * gate
-            o = _project(o, z.hidden, "o")
+            o = checkpoint_name(_project(o, z.hidden, "o"), "delta_out")
         # how much of its state a position keeps, over real positions
         kept = jnp.exp(g)
         if valid is None:
@@ -1082,7 +1095,11 @@ class DeltaMixer(nn.Module):
 
 
 def swiglu(x, w1, w3, w2):
-    return jnp.dot(jax.nn.silu(jnp.dot(x, w1)) * jnp.dot(x, w3), w2)
+    # the three products go by name (``KEPT_NAMES``); the hidden between
+    # them is kept nowhere
+    gate = checkpoint_name(jnp.dot(x, w1), "mlp_gate")
+    up = checkpoint_name(jnp.dot(x, w3), "mlp_up")
+    return checkpoint_name(jnp.dot(jax.nn.silu(gate) * up, w2), "mlp_out")
 
 
 class SwiGLU(nn.Module):
@@ -1436,10 +1453,10 @@ class Layer(nn.Module):
         return h, cache, counted
 
 
-# a layer over a whole window is made again coming back, all but its
-# fused attention's output
+# a layer over a whole window is made again coming back, all but what
+# ``KEPT_NAMES`` names
 RematLayer = nn.remat(
-    Layer, policy=jax.checkpoint_policies.save_only_these_names(KEPT))
+    Layer, policy=jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES))
 
 
 class NextNext(nn.Module):

@@ -620,6 +620,71 @@ def test_the_tiny_presets_and_a_cpu_lowering_take_the_dense_stack(model):
     assert "tpu_custom_call" not in wide
 
 
+# -- what a rematerialised layer keeps -----------------------------------------
+
+def _products(jaxpr, lhs_shape, rhs_shape):
+    """The products ``x W`` of ``lhs_shape`` by ``rhs_shape`` (``x``'s
+    last axis against ``W``'s first) anywhere in ``jaxpr``, the jaxprs
+    its equations hold among them."""
+    found = 0
+    x_w = (((len(lhs_shape) - 1,), (0,)), ((), ()))
+    for eqn in jaxpr.eqns:
+        if (eqn.primitive.name == "dot_general"
+                and eqn.params["dimension_numbers"] == x_w
+                and tuple(v.aval.shape for v in eqn.invars)
+                == (lhs_shape, rhs_shape)):
+            found += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _products(sub, lhs_shape, rhs_shape)
+    return found
+
+
+@pytest.mark.parametrize("preset", ["tiny", "tiny_latent", "tiny_hybrid"])
+def test_a_rematerialised_layer_keeps_its_widest_products(preset, monkeypatch):
+    """Loss and every gradient leaf of a whole-window pass with the
+    named products kept across ``RematLayer`` equal the same net's with
+    no rematerialisation at all, and the gradient multiplies by no
+    kept product's weights a second time: ``x w1`` and ``x w3`` of a
+    dense layer and of a shared expert, a delta mixer's q and k."""
+    z = sn.PRESETS[preset]
+    net = sn.SequencePolicyNet(z)
+    B, T = 2, z.sequence_length
+    tokens = jax.random.randint(
+        jax.random.PRNGKey(2), (B, T), 0, z.vocab).at[1, T - 6:].set(-1)
+    params = net.init(jax.random.PRNGKey(3), tokens, None)
+
+    def loss(params):
+        out = net.apply(params, tokens, None)
+        heads = [out["policy"]] + ([out["mtp"]] if "mtp" in out else [])
+        return sum(jnp.square(p.features @ p.kernel).mean()
+                   for p in heads) + out["value"].sum()
+
+    kept = jax.value_and_grad(loss)(params)
+    jaxpr = jax.make_jaxpr(jax.grad(loss))(params).jaxpr
+    monkeypatch.setattr(sn, "RematLayer", sn.Layer)
+    plain = jax.value_and_grad(loss)(params)
+    np.testing.assert_allclose(kept[0], plain[0], rtol=1e-6)
+    flat, _ = jax.tree_util.tree_flatten_with_path(kept[1])
+    for (path, ours), theirs in zip(flat, jax.tree.leaves(plain[1])):
+        assert float(jnp.abs(theirs).max()) > 0, path
+        np.testing.assert_allclose(
+            ours, theirs, rtol=1e-5, atol=1e-6 * float(jnp.abs(theirs).max()),
+            err_msg=jax.tree_util.keystr(path))
+    # w1 and w3 are one shape: two products a SwiGLU going forward, and
+    # none of that shape coming back (dx and dw contract other axes)
+    dense = _products(jaxpr, (B, T, z.hidden), (z.hidden, z.dense_width))
+    assert dense == 2 * z.dense_layers, dense
+    sparse = len(z.layer_types) - z.dense_layers + z.nextn_modules
+    shared = _products(jaxpr, (B * T, z.hidden),
+                       (z.hidden, z.expert_width * z.shared_experts))
+    assert shared == 2 * sparse, shared
+    if sn.LINEAR in z.layer_types:
+        held, _ = sn.held_heads(z)
+        delta = _products(jaxpr, (B, T, z.hidden),
+                          (z.hidden, held * z.delta_key_dim))
+        assert delta == 2 * z.layer_types.count(sn.LINEAR), delta
+
+
 # -- wire, ring and gather without a mask -----------------------------------
 
 def test_an_all_legal_episode_crosses_wire_ring_and_gather_without_a_mask(
